@@ -86,10 +86,10 @@ def run():
 
     n_pages = 1 + Bd * n_p
     ids = jnp.arange(1, n_pages).reshape(Bd, n_p).astype(jnp.int32)
-    kp = jnp.zeros((n_pages, ps, KVd, dhd), jnp.float32
-                   ).at[ids].set(kc.reshape(Bd, n_p, ps, KVd, dhd))
-    vp = jnp.zeros((n_pages, ps, KVd, dhd), jnp.float32
-                   ).at[ids].set(vc.reshape(Bd, n_p, ps, KVd, dhd))
+    kp = jnp.zeros((KVd, n_pages, ps, dhd), jnp.float32).at[:, ids].set(
+        kc.reshape(Bd, n_p, ps, KVd, dhd).transpose(3, 0, 1, 2, 4))
+    vp = jnp.zeros((KVd, n_pages, ps, dhd), jnp.float32).at[:, ids].set(
+        vc.reshape(Bd, n_p, ps, KVd, dhd).transpose(3, 0, 1, 2, 4))
 
     @jax.jit
     def paged_decode(q, kp, vp, pt, lens):
@@ -117,7 +117,7 @@ def run():
     from repro.core import quant as quant_lib
     kq8, kscale = quant_lib.quantize(kp, axis=-1)
     vq8, vscale = quant_lib.quantize(vp, axis=-1)
-    kscale, vscale = kscale[..., 0], vscale[..., 0]
+    kscale, vscale = kscale.swapaxes(-1, -2), vscale.swapaxes(-1, -2)
 
     @jax.jit
     def paged_decode_q8(q, kp, vp, ks, vs, pt, lens):

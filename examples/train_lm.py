@@ -11,6 +11,7 @@ Default runs a ~20M model for 200 steps so it finishes quickly on this
 import argparse
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, ShapeConfig
@@ -54,7 +55,7 @@ def main():
         jitted, state, batch_fn,
         trainer_lib.TrainerConfig(total_steps=args.steps, ckpt_every=50,
                                   ckpt_dir=args.ckpt_dir))
-    with mesh:
+    with jax.set_mesh(mesh):
         tr.run()
     log = tr.metrics_log
     for m in log[:: max(len(log) // 10, 1)]:

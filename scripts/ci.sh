@@ -52,7 +52,7 @@ PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" SERVING_BENCH_TINY=1 \
 # in the serving bench's obs_off/obs_on rows above.
 echo "== observability =="
 OBS_TMP=$(mktemp -d)
-python -m repro.launch.serve --arch qwen2-7b --requests 4 --slots 2 \
+python -m repro.launch.serve --arch qwen2-7b --size shrink --requests 4 --slots 2 \
   --max-new 4 --cache-kind paged --prefix-cache \
   --metrics-out "$OBS_TMP/metrics.prom" --trace-out "$OBS_TMP/trace.json"
 python - "$OBS_TMP" <<'EOF'

@@ -97,13 +97,13 @@ def run_kernel_check() -> int:
         pt = jnp.asarray(np.arange(1, 9, dtype=np.int32).reshape(2, 4))
         launches.append(("decode/paged_decode_attention",
                          lambda: paged_decode_attention(
-                             arr(2, 1, 2, 8), arr(9, 4, 1, 8),
-                             arr(9, 4, 1, 8), pt,
+                             arr(2, 1, 2, 8), arr(1, 9, 4, 8),
+                             arr(1, 9, 4, 8), pt,
                              jnp.array([5, 9], jnp.int32), interpret=True)))
         launches.append(("decode/paged_decode_attention_int8",
                          lambda: paged_decode_attention_int8(
-                             arr(2, 1, 2, 8), qarr(9, 4, 1, 8),
-                             qarr(9, 4, 1, 8), sarr(9, 4, 1), sarr(9, 4, 1),
+                             arr(2, 1, 2, 8), qarr(1, 9, 4, 8),
+                             qarr(1, 9, 4, 8), sarr(1, 9, 1, 4), sarr(1, 9, 1, 4),
                              pt, jnp.array([5, 9], jnp.int32),
                              interpret=True)))
         launches.append(("decode/chunk_prefill", lambda: chunk_prefill(
@@ -111,20 +111,20 @@ def run_kernel_check() -> int:
             block_k=8, interpret=True)))
         launches.append(("decode/paged_chunk_prefill",
                          lambda: paged_chunk_prefill(
-                             arr(2, 1, 8, 8), arr(9, 4, 1, 8),
-                             arr(9, 4, 1, 8), pt, 4, chunk=4,
+                             arr(2, 1, 8, 8), arr(1, 9, 4, 8),
+                             arr(1, 9, 4, 8), pt, 4, chunk=4,
                              interpret=True)))
         launches.append(("decode/paged_chunk_prefill_int8",
                          lambda: paged_chunk_prefill_int8(
-                             arr(2, 1, 8, 8), qarr(9, 4, 1, 8),
-                             qarr(9, 4, 1, 8), sarr(9, 4, 1), sarr(9, 4, 1),
+                             arr(2, 1, 8, 8), qarr(1, 9, 4, 8),
+                             qarr(1, 9, 4, 8), sarr(1, 9, 1, 4), sarr(1, 9, 1, 4),
                              pt, 4, chunk=4, interpret=True)))
         launches.append(("scan/rglru_scan", lambda: rglru_scan(
             arr(2, 8, 8), arr(2, 8, 8), block_r=8, block_s=4,
             interpret=True)))
         launches.append(("scan/wkv6_scan", lambda: wkv6_scan(
             arr(2, 8, 8), arr(2, 8, 8), arr(2, 8, 8),
-            -jnp.abs(arr(2, 8, 8)), arr(2, 8), chunk=4, interpret=True)))
+            -jnp.abs(arr(2, 8, 8)), arr(2, 8), chunk=8, interpret=True)))
         for name, launch in launches:
             try:
                 jax.block_until_ready(launch())

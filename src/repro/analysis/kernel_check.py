@@ -10,6 +10,9 @@ module validates, at launch time and against the *actual* operands:
 
 * every ``BlockSpec`` block shape divides its array dim (no silent
   partial tiles);
+* every block obeys Mosaic's tiling rule: its last two dims are
+  multiples of (8, 128), or equal the array's dims — interpret mode never
+  applies it, the TPU compiler refuses the kernel without it;
 * every ``index_map`` takes exactly ``grid rank + num_scalar_prefetch``
   arguments and its outputs stay in bounds over the grid (full
   enumeration for small grids, corner sampling beyond
@@ -192,6 +195,15 @@ def _check_spec(errors, *, role, i, spec, shape, grid, probes, exhaustive_pts,
             errors.append(f"{where}: block dim {d} = {b} does not divide "
                           f"array dim {s} — partial tiles read/write out "
                           f"of bounds unless explicitly masked")
+    for d, tile in ((len(block) - 2, 8), (len(block) - 1, 128)):
+        if d < 0:
+            continue
+        b = 1 if block[d] is None else block[d]   # None: a squeezed dim
+        if b % tile and b != shape[d]:
+            errors.append(f"{where}: block dim {d} = {b} is neither a "
+                          f"multiple of {tile} nor the array dim "
+                          f"{shape[d]} — Mosaic tiles the last two block "
+                          f"dims by (8, 128) and refuses the kernel")
 
     index_map = getattr(spec, "index_map", None)
     if index_map is None:

@@ -39,10 +39,11 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Optional
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import quant as quant_lib
 
@@ -61,6 +62,38 @@ class FamousConfig:
     max_heads: int = 0
     max_seq: int = 0
     max_d_model: int = 0
+    # Device mesh the Pallas kernels run under (serving TP): GSPMD cannot
+    # partition a Mosaic call, so each launch is shard_mapped over the
+    # mesh's "model" axis (see _per_device).  None: one device.
+    mesh: Any = None
+
+
+HEADS = P(None, None, "model")   # (B, S, H|KV, dh) activations and caches
+POOL = P("model")                # (KV, n_pages, ...) page and scale pools
+REP = P()                        # page tables, lengths, offsets, x
+
+
+def _per_device(cfg: FamousConfig, fn, in_specs, out_specs, *args):
+    """Launch a Pallas kernel wrapper once per device of ``cfg.mesh``.
+
+    Heads and kv heads split over the "model" axis exactly as the serving
+    TP rules place them, so each device runs the kernel on its own heads
+    and its own slice of the page pool with no collective.  When a head
+    count does not divide the axis (the rules then replicate it), every
+    device runs the whole kernel on replicated operands."""
+    tp = 1 if cfg.mesh is None else cfg.mesh.shape.get("model", 1)
+    if tp == 1:
+        return fn(*args)
+
+    def divides(spec, a):
+        return all(a.shape[d] % tp == 0
+                   for d, ax in enumerate(spec) if ax == "model")
+
+    if not all(divides(s, a) for s, a in zip(in_specs, args)):
+        in_specs = (REP,) * len(args)
+        out_specs = jax.tree_util.tree_map(lambda _: REP, out_specs)
+    return jax.shard_map(fn, mesh=cfg.mesh, in_specs=tuple(in_specs),
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -129,8 +162,14 @@ def qkv_projection(x, wq, wk, wv, bq=None, bk=None, bv=None, *,
                                         tile_d=cfg.tile_d)
     if cfg.impl == "pallas":
         from repro.kernels.qkv import ops as qkv_ops
-        return qkv_ops.qkv_projection(x, wq, wk, wv, bq, bk, bv,
-                                      tile_d=cfg.tile_d, quant=cfg.quant)
+        w = P(None, "model")                      # (D, H|KV, dh) weights
+        b = P("model")                            # (H|KV, dh) biases
+        biases = () if bq is None else (bq, bk, bv)
+        return _per_device(
+            cfg, lambda x, wq, wk, wv, *bs: qkv_ops.qkv_projection(
+                x, wq, wk, wv, *bs, tile_d=cfg.tile_d, quant=cfg.quant),
+            (REP, w, w, w) + (b,) * len(biases), (HEADS,) * 3,
+            x, wq, wk, wv, *biases)
     return qkv_projection_xla(x, wq, wk, wv, bq, bk, bv,
                               quantized=cfg.quant == "int8")
 
@@ -324,9 +363,11 @@ def attention(q, k, v, *, causal=True, window=0, scale=None, q_offset=0,
         # Fully Pallas path (fwd + custom-VJP bwd kernels); tile_q/tile_k are
         # clamped to the sequence lengths inside the wrapper.
         from repro.kernels.attention import ops as attn_ops
-        return attn_ops.mha(q, k, v, causal=causal, window=window, scale=scale,
-                            q_offset=q_offset, block_q=cfg.tile_q,
-                            block_k=cfg.tile_k)
+        return _per_device(
+            cfg, functools.partial(attn_ops.mha, causal=causal, window=window,
+                                   scale=scale, q_offset=q_offset,
+                                   block_q=cfg.tile_q, block_k=cfg.tile_k),
+            (HEADS,) * 3, HEADS, q, k, v)
     return attention_xla(q, k, v, causal=causal, window=window, scale=scale,
                          q_offset=q_offset, block_k=cfg.tile_k)
 
@@ -343,9 +384,10 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window=0, scale=None,
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(dh)
     if cfg.impl == "pallas":
         from repro.kernels.decode import ops as dec_ops
-        return dec_ops.decode_attention(q, k_cache, v_cache, cache_len,
-                                        window=window, scale=scale,
-                                        block_k=cfg.tile_k)
+        return _per_device(
+            cfg, functools.partial(dec_ops.decode_attention, window=window,
+                                   scale=scale, block_k=cfg.tile_k),
+            (HEADS, HEADS, HEADS, REP), HEADS, q, k_cache, v_cache, cache_len)
     k = _broadcast_kv(k_cache, H)
     v = _broadcast_kv(v_cache, H)
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale,
@@ -382,8 +424,10 @@ def verify_attention(q, k_cache, v_cache, cache_len, *, scale=None,
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(dh)
     if cfg.impl == "pallas":
         from repro.kernels.decode import ops as dec_ops
-        return dec_ops.verify_attention(q, k_cache, v_cache, cache_len,
-                                        scale=scale, block_k=cfg.tile_k)
+        return _per_device(
+            cfg, functools.partial(dec_ops.verify_attention, scale=scale,
+                                   block_k=cfg.tile_k),
+            (HEADS, HEADS, HEADS, REP), HEADS, q, k_cache, v_cache, cache_len)
     k = _broadcast_kv(k_cache, H)
     v = _broadcast_kv(v_cache, H)
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale,
@@ -396,32 +440,42 @@ def verify_attention(q, k_cache, v_cache, cache_len, *, scale=None,
     return out.astype(q.dtype)
 
 
+def _paged_kernel(cfg, op, q, k_pages, v_pages, page_table, lens_or_offset,
+                  scale, k_scale, v_scale):
+    """Dispatch one paged Pallas wrapper of kernels/decode/ops.py (fp or
+    int8 pools) per device of ``cfg.mesh``."""
+    from repro.kernels.decode import ops as dec_ops
+    if k_scale is None:
+        fn = functools.partial(getattr(dec_ops, op), scale=scale)
+        return _per_device(cfg, fn, (HEADS, POOL, POOL, REP, REP), HEADS,
+                           q, k_pages, v_pages, page_table, lens_or_offset)
+    fn = functools.partial(getattr(dec_ops, op + "_int8"), scale=scale)
+    return _per_device(cfg, fn, (HEADS,) + (POOL,) * 4 + (REP, REP), HEADS,
+                       q, k_pages, v_pages, k_scale, v_scale, page_table,
+                       lens_or_offset)
+
+
 def paged_verify_attention(q, k_pages, v_pages, page_table, cache_len, *,
                            scale=None, k_scale=None, v_scale=None,
                            cfg: FamousConfig = FamousConfig()):
     """Speculative-verify attention against a *paged* KV cache.
 
     q: (B, W, H, dh) at per-slot positions ``cache_len[b] + j``; pools:
-    (n_pages, page_size, KV, dh); page_table: (B, n_p) int32.  impl=
+    (KV, n_pages, page_size, dh); page_table: (B, n_p) int32.  impl=
     "pallas" flattens (slot, verify position) pairs into rows of the
     scalar-prefetched page-table decode kernel; other impls gather the
     table into a contiguous view and reuse :func:`verify_attention`.
 
-    With ``k_scale``/``v_scale`` (fp32 (n_pages, page_size, KV) pools) the
+    With ``k_scale``/``v_scale`` (fp32 (KV, n_pages, 1, page_size) pools) the
     K/V pools are int8 and dequantized in-kernel (pallas) or via the
     dequantizing gather (other impls) — the ``kv_dtype="int8"`` path.
     """
     dh = q.shape[-1]
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(dh)
     if cfg.impl == "pallas":
-        from repro.kernels.decode import ops as dec_ops
-        if k_scale is not None:
-            return dec_ops.paged_verify_attention_int8(
-                q, k_pages, v_pages, k_scale, v_scale, page_table,
-                cache_len, scale=scale)
-        return dec_ops.paged_verify_attention(q, k_pages, v_pages,
-                                              page_table, cache_len,
-                                              scale=scale)
+        return _paged_kernel(cfg, "paged_verify_attention", q, k_pages, v_pages,
+                             page_table, cache_len, scale, k_scale,
+                             v_scale)
     from repro.kernels.decode.ref import gather_pages, gather_pages_int8
     if k_scale is not None:
         k = gather_pages_int8(k_pages, k_scale, page_table)
@@ -477,8 +531,10 @@ def chunked_prefill_attention(q, k_cache, v_cache, q_offset, *, scale=None,
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(dh)
     if cfg.impl == "pallas":
         from repro.kernels.decode import ops as dec_ops
-        return dec_ops.chunk_prefill_attention(q, k_cache, v_cache, q_offset,
-                                               scale=scale, block_k=cfg.tile_k)
+        return _per_device(
+            cfg, functools.partial(dec_ops.chunk_prefill_attention,
+                                   scale=scale, block_k=cfg.tile_k),
+            (HEADS, HEADS, HEADS, REP), HEADS, q, k_cache, v_cache, q_offset)
     k = _broadcast_kv(k_cache, H)
     v = _broadcast_kv(v_cache, H)
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale,
@@ -497,7 +553,7 @@ def paged_chunked_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
     """Chunked-prefill attention against a *paged* KV cache.
 
     q: (B, C, H, dh) at positions ``[q_offset, q_offset + C)``; pools:
-    (n_pages, page_size, KV, dh); page_table: (B, n_p) int32.  The chunk's
+    (KV, n_pages, page_size, dh); page_table: (B, n_p) int32.  The chunk's
     K/V must already be scattered into the slot's pages.  impl="pallas"
     reuses the scalar-prefetched page-table BlockSpec machinery of
     ``paged_decode_attention``; other impls gather the table into a
@@ -507,14 +563,9 @@ def paged_chunked_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
     B, C, H, dh = q.shape
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(dh)
     if cfg.impl == "pallas":
-        from repro.kernels.decode import ops as dec_ops
-        if k_scale is not None:
-            return dec_ops.paged_chunk_prefill_attention_int8(
-                q, k_pages, v_pages, k_scale, v_scale, page_table,
-                q_offset, scale=scale)
-        return dec_ops.paged_chunk_prefill_attention(q, k_pages, v_pages,
-                                                     page_table, q_offset,
-                                                     scale=scale)
+        return _paged_kernel(cfg, "paged_chunk_prefill_attention", q, k_pages, v_pages,
+                             page_table, q_offset, scale, k_scale,
+                             v_scale)
     from repro.kernels.decode.ref import gather_pages, gather_pages_int8
     if k_scale is not None:
         k = gather_pages_int8(k_pages, k_scale, page_table)
@@ -530,7 +581,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, cache_len, *,
                            cfg: FamousConfig = FamousConfig()):
     """One-token attention against a *paged* KV cache.
 
-    q: (B, 1, H, dh); pools: (n_pages, page_size, KV, dh) shared by every
+    q: (B, 1, H, dh); pools: (KV, n_pages, page_size, dh) shared by every
     sequence; page_table: (B, n_p) int32 page ids per slot; cache_len: (B,)
     int32 valid entries (the new token's k/v already written to its page).
 
@@ -543,14 +594,9 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, cache_len, *,
     B, _, H, dh = q.shape
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(dh)
     if cfg.impl == "pallas":
-        from repro.kernels.decode import ops as dec_ops
-        if k_scale is not None:
-            return dec_ops.paged_decode_attention_int8(
-                q, k_pages, v_pages, k_scale, v_scale, page_table,
-                cache_len, scale=scale)
-        return dec_ops.paged_decode_attention(q, k_pages, v_pages,
-                                              page_table, cache_len,
-                                              scale=scale)
+        return _paged_kernel(cfg, "paged_decode_attention", q, k_pages, v_pages,
+                             page_table, cache_len, scale, k_scale,
+                             v_scale)
     from repro.kernels.decode.ref import gather_pages, gather_pages_int8
     if k_scale is not None:
         k = gather_pages_int8(k_pages, k_scale, page_table)

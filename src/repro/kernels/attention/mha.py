@@ -95,7 +95,7 @@ def _mha_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
     def _flush():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, ...] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0, :] = (m_ref[...] + jnp.log(l))[:, 0]
+        lse_ref[0] = m_ref[...] + jnp.log(l)
 
 
 def mha_forward(q, k, v, *, causal: bool = True, window: int = 0,
@@ -103,8 +103,9 @@ def mha_forward(q, k, v, *, causal: bool = True, window: int = 0,
                 block_q: int = 512, block_k: int = 512,
                 interpret: bool = False, return_lse: bool = False):
     """q: (BH, Sq, dh); k, v: (BKV, Skv, dh) with BH = BKV * group.
-    Returns (BH, Sq, dh), plus the f32 row log-sum-exp (BH, Sq) when
-    ``return_lse`` (the flash backward residual)."""
+    Returns (BH, Sq, dh), plus the f32 row log-sum-exp (BH, Sq, 1) when
+    ``return_lse`` (the flash backward residual; a trailing unit dim keeps
+    its (block_q, 1) blocks legal under Mosaic's tiling rule)."""
     BH, Sq, dh = q.shape
     BKV, Skv, _ = k.shape
     group = BH // BKV
@@ -131,11 +132,11 @@ def mha_forward(q, k, v, *, causal: bool = True, window: int = 0,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, dh), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, block_q), lambda bh, iq, ik: (bh, iq)),
+            pl.BlockSpec((1, block_q, 1), lambda bh, iq, ik: (bh, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sq, dh), q.dtype),
-            jax.ShapeDtypeStruct((BH, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((BH, Sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pc.VMEM((block_q, dh), jnp.float32),   # acc
@@ -168,8 +169,8 @@ def _mha_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0].astype(jnp.float32)                  # (bk, dh)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)                # (bq, dh)
-    lse = lse_ref[0][:, None]                         # (bq, 1)
-    delta = delta_ref[0][:, None]                     # (bq, 1)
+    lse = lse_ref[0]                                  # (bq, 1)
+    delta = delta_ref[0]                              # (bq, 1)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -207,8 +208,8 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0].astype(jnp.float32)                  # (bk, dh)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)                # (bq, dh)
-    lse = lse_ref[0][:, None]                         # (bq, 1)
-    delta = delta_ref[0][:, None]                     # (bq, 1)
+    lse = lse_ref[0]                                  # (bq, 1)
+    delta = delta_ref[0]                              # (bq, 1)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -234,8 +235,8 @@ def mha_backward(q, k, v, out, lse, dout, *, causal: bool = True,
                  q_offset: int = 0, block_q: int = 512, block_k: int = 512,
                  interpret: bool = False):
     """Flash backward.  q/dout/out: (BH, Sq, dh); k, v: (BKV, Skv, dh);
-    lse: (BH, Sq) f32.  Returns f32 (dq (BH, Sq, dh), dk, dv (BKV, Skv, dh))
-    with the GQA head-group reduction already applied."""
+    lse: (BH, Sq, 1) f32.  Returns f32 (dq (BH, Sq, dh), dk, dv
+    (BKV, Skv, dh)) with the GQA head-group reduction already applied."""
     BH, Sq, dh = q.shape
     BKV, Skv, _ = k.shape
     group = BH // BKV
@@ -248,7 +249,7 @@ def mha_backward(q, k, v, out, lse, dout, *, causal: bool = True,
     # D_i = Σ_d dO_i·O_i — the softmax-normalisation correction, computed
     # once outside the kernels (cheap elementwise; one pass over O/dO).
     delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)                                    # (BH, Sq)
+                    axis=-1, keepdims=True)                     # (BH, Sq, 1)
     lse = lse.astype(jnp.float32)
 
     common = dict(scale=float(scale), causal=causal, window=window,
@@ -257,7 +258,7 @@ def mha_backward(q, k, v, out, lse, dout, *, causal: bool = True,
     q_spec = pl.BlockSpec((1, block_q, dh), lambda bh, iq, ik: (bh, iq, 0))
     kv_spec = pl.BlockSpec(
         (1, block_k, dh), lambda bh, iq, ik, group=group: (bh // group, ik, 0))
-    row_spec = pl.BlockSpec((1, block_q), lambda bh, iq, ik: (bh, iq))
+    row_spec = pl.BlockSpec((1, block_q, 1), lambda bh, iq, ik: (bh, iq, 0))
 
     dq = pc.pallas_call(
         functools.partial(_mha_bwd_dq_kernel, num_k_blocks=nk, **common),
@@ -276,7 +277,8 @@ def mha_backward(q, k, v, out, lse, dout, *, causal: bool = True,
     q_spec_t = pl.BlockSpec((1, block_q, dh), lambda bh, ik, iq: (bh, iq, 0))
     kv_spec_t = pl.BlockSpec(
         (1, block_k, dh), lambda bh, ik, iq, group=group: (bh // group, ik, 0))
-    row_spec_t = pl.BlockSpec((1, block_q), lambda bh, ik, iq: (bh, iq))
+    row_spec_t = pl.BlockSpec((1, block_q, 1),
+                              lambda bh, ik, iq: (bh, iq, 0))
     dkv_spec = pl.BlockSpec((1, block_k, dh), lambda bh, ik, iq: (bh, ik, 0))
 
     dk, dv = pc.pallas_call(

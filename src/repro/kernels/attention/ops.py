@@ -15,11 +15,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import pallas_compat as pc
 from repro.kernels.attention import mha as mha_kernel
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _to_flat(x):  # (B, S, H, dh) -> (B*H, S, dh)
@@ -77,7 +74,7 @@ def mha(q, k, v, *, causal=True, window=0, scale=None, q_offset=0,
     dk/dv inside the kernel wrapper."""
     B, Sq, H, dh = q.shape
     Skv = k.shape[1]
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = pc.interpret_default() if interpret is None else interpret
     # resolve data-independent knobs here so the custom-VJP nondiff args are
     # concrete (the backward kernels reuse the exact forward tiling + scale)
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(dh)

@@ -31,8 +31,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import pallas_compat as pc
-
-NEG_INF = -1e30
+from repro.kernels.decode.decode_attn import NEG_INF, online_softmax_step
 
 
 def _chunk_prefill_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
@@ -56,16 +55,7 @@ def _chunk_prefill_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
     k_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     c = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) % chunk
     ok = k_pos <= off + c                              # causal incl. own chunk
-    s = jnp.where(ok, s, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+    online_softmax_step(s, ok, v, acc_ref, m_ref, l_ref)
 
     @pl.when(ik == n_k - 1)
     def _flush():
@@ -109,9 +99,13 @@ def chunk_prefill(q, k_cache, v_cache, q_offset, *, chunk: int,
     )(off, q, k_cache, v_cache)
 
 
-def _paged_chunk_prefill_kernel(off_ref, pt_ref, q_ref, k_ref, v_ref, o_ref,
-                                acc_ref, m_ref, l_ref, *, scale: float,
-                                page_size: int, n_p: int, chunk: int):
+def _paged_chunk_prefill_kernel(off_ref, pt_ref, q_ref, k_ref, v_ref, *refs,
+                                scale: float, page_size: int, n_p: int,
+                                chunk: int, quantized: bool):
+    if quantized:
+        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
+    else:
+        o_ref, acc_ref, m_ref, l_ref = refs
     ip = pl.program_id(2)
 
     @pl.when(ip == 0)
@@ -121,8 +115,8 @@ def _paged_chunk_prefill_kernel(off_ref, pt_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     q = q_ref[0, 0].astype(jnp.float32) * scale       # (group*C, dh)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)         # (page_size, dh)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)               # (page_size, dh)
+    v = v_ref[0, 0].astype(jnp.float32)
     off = off_ref[0]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -130,16 +124,13 @@ def _paged_chunk_prefill_kernel(off_ref, pt_ref, q_ref, k_ref, v_ref, o_ref,
     k_pos = ip * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     c = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) % chunk
     ok = k_pos <= off + c
-    s = jnp.where(ok, s, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+    if quantized:
+        # dequantize in VMEM (see decode_attn._paged_decode_kernel)
+        s = s * ks_ref[0, 0]
+        online_softmax_step(s, ok, v, acc_ref, m_ref, l_ref,
+                             v_scale=vs_ref[0, 0])
+    else:
+        online_softmax_step(s, ok, v, acc_ref, m_ref, l_ref)
 
     @pl.when(ip == n_p - 1)
     def _flush():
@@ -147,133 +138,28 @@ def _paged_chunk_prefill_kernel(off_ref, pt_ref, q_ref, k_ref, v_ref, o_ref,
                             / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
-def paged_chunk_prefill(q, k_pages, v_pages, page_table, q_offset, *,
-                        chunk: int, scale: float | None = None,
-                        interpret: bool = False):
-    """Page-table-indexed chunked-prefill attention.
-
-    q: (B, KV, group*C, dh) with row = g*C + c; pools: (n_pages, page_size,
-    KV, dh); page_table: (B, n_p) int32; q_offset: () int32 runtime scalar.
-    Returns (B, KV, group*C, dh).  The page table and offset are
-    scalar-prefetched — the K/V BlockSpec index_maps read
-    ``page_table[b, ip]`` to aim each page DMA, so the grid program never
-    changes shape when prompts grow or chunks advance.
-    """
+def _paged_chunk_prefill_call(q, k_pages, v_pages, scales, page_table,
+                              q_offset, chunk, scale, interpret):
     B, KV, rows, dh = q.shape
-    page_size = k_pages.shape[1]
+    page_size = k_pages.shape[2]
     n_p = page_table.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(dh)
     assert rows % chunk == 0, (rows, chunk)
     off = jnp.asarray(q_offset, jnp.int32).reshape(1)
-    kernel = functools.partial(_paged_chunk_prefill_kernel, scale=float(scale),
-                               page_size=page_size, n_p=n_p, chunk=chunk)
-    grid_spec = pc.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,           # q_offset, page_table
-        grid=(B, KV, n_p),
-        in_specs=[
-            pl.BlockSpec((1, 1, rows, dh),
-                         lambda b, g, ip, off, pt: (b, g, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, dh),
-                         lambda b, g, ip, off, pt: (pt[b, ip], 0, g, 0)),
-            pl.BlockSpec((1, page_size, 1, dh),
-                         lambda b, g, ip, off, pt: (pt[b, ip], 0, g, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rows, dh),
-                               lambda b, g, ip, off, pt: (b, g, 0, 0)),
-        scratch_shapes=[
-            pc.VMEM((rows, dh), jnp.float32),
-            pc.VMEM((rows, 1), jnp.float32),
-            pc.VMEM((rows, 1), jnp.float32),
-        ],
-    )
-    return pc.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, rows, dh), q.dtype),
-        compiler_params=pc.compiler_params("parallel", "parallel",
-                                           "arbitrary"),
-        interpret=interpret,
-    )(off, page_table.astype(jnp.int32), q, k_pages, v_pages)
-
-
-def _paged_chunk_prefill_kernel_int8(off_ref, pt_ref, q_ref, k_ref, v_ref,
-                                     ks_ref, vs_ref, o_ref, acc_ref, m_ref,
-                                     l_ref, *, scale: float, page_size: int,
-                                     n_p: int, chunk: int):
-    ip = pl.program_id(2)
-
-    @pl.when(ip == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32) * scale       # (group*C, dh)
-    # dequantize in VMEM (see decode_attn._paged_decode_kernel_int8)
-    ks = ks_ref[0, :, :].astype(jnp.float32)          # (page_size, 1)
-    vs = vs_ref[0, :, :].astype(jnp.float32)
-    k = k_ref[0, :, 0, :].astype(jnp.float32) * ks    # (page_size, dh)
-    v = v_ref[0, :, 0, :].astype(jnp.float32) * vs
-    off = off_ref[0]
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    k_pos = ip * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    c = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) % chunk
-    ok = k_pos <= off + c
-    s = jnp.where(ok, s, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-
-    @pl.when(ip == n_p - 1)
-    def _flush():
-        o_ref[0, 0, ...] = (acc_ref[...]
-                            / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
-
-
-def paged_chunk_prefill_int8(q, k_pages, v_pages, k_scale, v_scale,
-                             page_table, q_offset, *, chunk: int,
-                             scale: float | None = None,
-                             interpret: bool = False):
-    """Int8 paged chunked-prefill attention with in-kernel dequantization.
-
-    Same contract as ``paged_chunk_prefill`` except the K/V pools are int8
-    and carry fp32 per-token-per-kv-head scale pools of shape
-    (n_pages, page_size, KV); the scale blocks ride the same page-table
-    index_map and widen the int8 page to fp32 only in VMEM.
-    """
-    B, KV, rows, dh = q.shape
-    page_size = k_pages.shape[1]
-    n_p = page_table.shape[1]
-    assert k_pages.dtype == jnp.int8 and v_pages.dtype == jnp.int8
-    scale = scale if scale is not None else 1.0 / math.sqrt(dh)
-    assert rows % chunk == 0, (rows, chunk)
-    off = jnp.asarray(q_offset, jnp.int32).reshape(1)
-    kernel = functools.partial(_paged_chunk_prefill_kernel_int8,
+    kernel = functools.partial(_paged_chunk_prefill_kernel,
                                scale=float(scale), page_size=page_size,
-                               n_p=n_p, chunk=chunk)
+                               n_p=n_p, chunk=chunk, quantized=bool(scales))
+    page_spec = pl.BlockSpec((1, 1, page_size, dh),
+                             lambda b, g, ip, off, pt: (g, pt[b, ip], 0, 0))
+    scale_spec = pl.BlockSpec((1, 1, 1, page_size),
+                              lambda b, g, ip, off, pt: (g, pt[b, ip], 0, 0))
     grid_spec = pc.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,           # q_offset, page_table
         grid=(B, KV, n_p),
         in_specs=[
             pl.BlockSpec((1, 1, rows, dh),
                          lambda b, g, ip, off, pt: (b, g, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, dh),
-                         lambda b, g, ip, off, pt: (pt[b, ip], 0, g, 0)),
-            pl.BlockSpec((1, page_size, 1, dh),
-                         lambda b, g, ip, off, pt: (pt[b, ip], 0, g, 0)),
-            pl.BlockSpec((1, page_size, 1),
-                         lambda b, g, ip, off, pt: (pt[b, ip], 0, g)),
-            pl.BlockSpec((1, page_size, 1),
-                         lambda b, g, ip, off, pt: (pt[b, ip], 0, g)),
-        ],
+            page_spec, page_spec] + [scale_spec] * len(scales),
         out_specs=pl.BlockSpec((1, 1, rows, dh),
                                lambda b, g, ip, off, pt: (b, g, 0, 0)),
         scratch_shapes=[
@@ -290,4 +176,37 @@ def paged_chunk_prefill_int8(q, k_pages, v_pages, k_scale, v_scale,
                                            "arbitrary"),
         interpret=interpret,
     )(off, page_table.astype(jnp.int32), q, k_pages, v_pages,
-      k_scale.astype(jnp.float32), v_scale.astype(jnp.float32))
+      *(s.astype(jnp.float32) for s in scales))
+
+
+def paged_chunk_prefill(q, k_pages, v_pages, page_table, q_offset, *,
+                        chunk: int, scale: float | None = None,
+                        interpret: bool = False):
+    """Page-table-indexed chunked-prefill attention.
+
+    q: (B, KV, group*C, dh) with row = g*C + c; pools: (KV, n_pages,
+    page_size, dh); page_table: (B, n_p) int32; q_offset: () int32 runtime
+    scalar.  Returns (B, KV, group*C, dh).  The page table and offset are
+    scalar-prefetched — the K/V BlockSpec index_maps read
+    ``page_table[b, ip]`` to aim each page DMA, so the grid program never
+    changes shape when prompts grow or chunks advance.
+    """
+    return _paged_chunk_prefill_call(q, k_pages, v_pages, (), page_table,
+                                     q_offset, chunk, scale, interpret)
+
+
+def paged_chunk_prefill_int8(q, k_pages, v_pages, k_scale, v_scale,
+                             page_table, q_offset, *, chunk: int,
+                             scale: float | None = None,
+                             interpret: bool = False):
+    """Int8 paged chunked-prefill attention with in-kernel dequantization.
+
+    Same contract as ``paged_chunk_prefill`` except the K/V pools are int8
+    and carry fp32 per-token-per-kv-head scale pools of shape
+    (KV, n_pages, 1, page_size); the scale rows ride the same page-table
+    index_map and are applied to the score and probability tiles in VMEM.
+    """
+    assert k_pages.dtype == jnp.int8 and v_pages.dtype == jnp.int8
+    return _paged_chunk_prefill_call(q, k_pages, v_pages, (k_scale, v_scale),
+                                     page_table, q_offset, chunk, scale,
+                                     interpret)

@@ -17,12 +17,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import pallas_compat as pc
 from repro.kernels.decode import chunk_prefill as chunk_kernels
 from repro.kernels.decode import decode_attn
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("window", "scale", "block_k",
@@ -34,7 +31,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window=0, scale=None,
     B, _, H, dh = q.shape
     Skv, KV = k_cache.shape[1], k_cache.shape[2]
     group = H // KV
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = pc.interpret_default() if interpret is None else interpret
     # (B, 1, H, dh) -> (B, KV, group, dh) -> kv-major (KV*B, group, dh)
     qf = (q[:, 0].reshape(B, KV, group, dh).transpose(1, 0, 2, 3)
           .reshape(KV * B, group, dh))
@@ -51,13 +48,13 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window=0, scale=None,
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode_attention(q, k_pages, v_pages, page_table, cache_len, *,
                            scale=None, interpret=None):
-    """q: (B, 1, H, dh); pools: (n_pages, page_size, KV, dh);
+    """q: (B, 1, H, dh); pools: (KV, n_pages, page_size, dh);
     page_table: (B, n_p) int32; cache_len: (B,) int32.
     Returns (B, 1, H, dh)."""
     B, _, H, dh = q.shape
-    KV = k_pages.shape[2]
+    KV = k_pages.shape[0]
     group = H // KV
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = pc.interpret_default() if interpret is None else interpret
     qf = q[:, 0].reshape(B, KV, group, dh)
     out = decode_attn.paged_decode_attention(qf, k_pages, v_pages,
                                              page_table, cache_len,
@@ -70,11 +67,11 @@ def paged_decode_attention_int8(q, k_pages, v_pages, k_scale, v_scale,
                                 page_table, cache_len, *, scale=None,
                                 interpret=None):
     """Int8 variant of :func:`paged_decode_attention`: pools are int8 with
-    fp32 (n_pages, page_size, KV) scale pools, dequantized in-kernel."""
+    fp32 (KV, n_pages, 1, page_size) scale pools, dequantized in-kernel."""
     B, _, H, dh = q.shape
-    KV = k_pages.shape[2]
+    KV = k_pages.shape[0]
     group = H // KV
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = pc.interpret_default() if interpret is None else interpret
     qf = q[:, 0].reshape(B, KV, group, dh)
     out = decode_attn.paged_decode_attention_int8(
         qf, k_pages, v_pages, k_scale, v_scale, page_table, cache_len,
@@ -98,7 +95,7 @@ def verify_attention(q, k_cache, v_cache, cache_len, *, scale=None,
     B, W, H, dh = q.shape
     Skv, KV = k_cache.shape[1], k_cache.shape[2]
     group = H // KV
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = pc.interpret_default() if interpret is None else interpret
     # (B, W, H, dh) -> (B, W, KV, group, dh) -> kv-major (KV*B*W, group, dh)
     qf = (q.reshape(B, W, KV, group, dh).transpose(2, 0, 1, 3, 4)
           .reshape(KV * B * W, group, dh))
@@ -122,16 +119,16 @@ def verify_attention(q, k_cache, v_cache, cache_len, *, scale=None,
 def paged_verify_attention(q, k_pages, v_pages, page_table, cache_len, *,
                            scale=None, interpret=None):
     """Paged speculative verify: q: (B, W, H, dh); pools:
-    (n_pages, page_size, KV, dh); page_table: (B, n_p) int32; cache_len:
+    (KV, n_pages, page_size, dh); page_table: (B, n_p) int32; cache_len:
     (B,) int32.  Returns (B, W, H, dh).  Same flattening as
     :func:`verify_attention`, on the scalar-prefetched page-table kernel —
     only the page *table* is repeated per verify position (a few ints per
     row), never the pool itself."""
     B, W, H, dh = q.shape
-    ps, KV = k_pages.shape[1], k_pages.shape[2]
+    KV, ps = k_pages.shape[0], k_pages.shape[2]
     n_p = page_table.shape[1]
     group = H // KV
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = pc.interpret_default() if interpret is None else interpret
     qf = q.reshape(B * W, KV, group, dh)
     pt = jnp.broadcast_to(page_table[:, None], (B, W, n_p)).reshape(B * W, n_p)
     lens = jnp.minimum(cache_len[:, None] + jnp.arange(W, dtype=jnp.int32)
@@ -148,10 +145,10 @@ def paged_verify_attention_int8(q, k_pages, v_pages, k_scale, v_scale,
     """Int8 variant of :func:`paged_verify_attention` — same page-table
     broadcast, riding the int8 paged decode kernel."""
     B, W, H, dh = q.shape
-    ps, KV = k_pages.shape[1], k_pages.shape[2]
+    KV, ps = k_pages.shape[0], k_pages.shape[2]
     n_p = page_table.shape[1]
     group = H // KV
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = pc.interpret_default() if interpret is None else interpret
     qf = q.reshape(B * W, KV, group, dh)
     pt = jnp.broadcast_to(page_table[:, None], (B, W, n_p)).reshape(B * W, n_p)
     lens = jnp.minimum(cache_len[:, None] + jnp.arange(W, dtype=jnp.int32)
@@ -171,7 +168,7 @@ def chunk_prefill_attention(q, k_cache, v_cache, q_offset, *, scale=None,
     B, C, H, dh = q.shape
     Skv, KV = k_cache.shape[1], k_cache.shape[2]
     group = H // KV
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = pc.interpret_default() if interpret is None else interpret
     # (B, C, H, dh) -> (KV, B, group, C, dh) -> kv-major (KV*B, group*C, dh)
     qf = (q.reshape(B, C, KV, group, dh).transpose(2, 0, 3, 1, 4)
           .reshape(KV * B, group * C, dh))
@@ -187,12 +184,12 @@ def chunk_prefill_attention(q, k_cache, v_cache, q_offset, *, scale=None,
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_chunk_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
                                   *, scale=None, interpret=None):
-    """q: (B, C, H, dh); pools: (n_pages, page_size, KV, dh); page_table:
+    """q: (B, C, H, dh); pools: (KV, n_pages, page_size, dh); page_table:
     (B, n_p) int32; q_offset: () int32.  Returns (B, C, H, dh)."""
     B, C, H, dh = q.shape
-    KV = k_pages.shape[2]
+    KV = k_pages.shape[0]
     group = H // KV
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = pc.interpret_default() if interpret is None else interpret
     qf = (q.reshape(B, C, KV, group, dh).transpose(0, 2, 3, 1, 4)
           .reshape(B, KV, group * C, dh))
     out = chunk_kernels.paged_chunk_prefill(qf, k_pages, v_pages, page_table,
@@ -208,9 +205,9 @@ def paged_chunk_prefill_attention_int8(q, k_pages, v_pages, k_scale, v_scale,
                                        interpret=None):
     """Int8 variant of :func:`paged_chunk_prefill_attention`."""
     B, C, H, dh = q.shape
-    KV = k_pages.shape[2]
+    KV = k_pages.shape[0]
     group = H // KV
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = pc.interpret_default() if interpret is None else interpret
     qf = (q.reshape(B, C, KV, group, dh).transpose(0, 2, 3, 1, 4)
           .reshape(B, KV, group * C, dh))
     out = chunk_kernels.paged_chunk_prefill_int8(

@@ -27,26 +27,27 @@ def decode_reference(q, k_cache, v_cache, cache_len, *, window: int = 0,
 
 
 def gather_pages(pages, page_table):
-    """Flatten a page pool into per-sequence contiguous caches.
+    """Flatten a kv-head-major page pool into per-sequence contiguous caches.
 
-    pages: (n_pages, page_size, KV, dh); page_table: (B, n_p) int32.
+    pages: (KV, n_pages, page_size, dh); page_table: (B, n_p) int32.
     Returns (B, n_p * page_size, KV, dh).
     """
-    n_p, ps = page_table.shape[1], pages.shape[1]
-    g = pages[page_table]                     # (B, n_p, ps, KV, dh)
-    return g.reshape(g.shape[0], n_p * ps, *pages.shape[2:])
+    KV, _, ps, dh = pages.shape
+    B, n_p = page_table.shape
+    g = pages[:, page_table]                  # (KV, B, n_p, ps, dh)
+    return g.reshape(KV, B, n_p * ps, dh).transpose(1, 2, 0, 3)
 
 
 def gather_pages_int8(pages, scale_pool, page_table):
     """Dequantizing gather for int8 page pools (XLA oracle path).
 
-    pages: (n_pages, page_size, KV, dh) int8; scale_pool: (n_pages,
-    page_size, KV) fp32 per-token-per-kv-head scales; page_table: (B, n_p).
+    pages: (KV, n_pages, page_size, dh) int8; scale_pool: (KV, n_pages, 1,
+    page_size) fp32 per-token-per-kv-head scales; page_table: (B, n_p).
     Returns fp32 (B, n_p * page_size, KV, dh) — what the Pallas int8
     kernels compute tile-by-tile in VMEM, materialised whole.
     """
     g = gather_pages(pages, page_table).astype(jnp.float32)
-    s = gather_pages(scale_pool[..., None], page_table)
+    s = gather_pages(scale_pool.swapaxes(-1, -2), page_table)  # (B, S, KV, 1)
     return g * s.astype(jnp.float32)
 
 
@@ -85,7 +86,7 @@ def paged_decode_reference(q, k_pages, v_pages, page_table, cache_len, *,
                            scale: float | None = None):
     """Gather-based oracle for the paged kernel.
 
-    q: (B, KV, group, dh); pools: (n_pages, page_size, KV, dh);
+    q: (B, KV, group, dh); pools: (KV, n_pages, page_size, dh);
     page_table: (B, n_p); cache_len: (B,).  Returns (B, KV, group, dh).
     """
     B, KV, group, dh = q.shape

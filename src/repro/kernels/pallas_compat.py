@@ -1,52 +1,63 @@
-"""Pallas TPU API compatibility layer across jax versions, plus the
-contract-checked ``pallas_call`` entry point.
+"""The Pallas TPU names every kernel family uses, plus the contract-checked
+``pallas_call`` entry point and the one interpret-mode policy.
 
-The Pallas TPU surface was renamed between jax 0.4.x and 0.5+:
-
-===========================  =================================
-jax 0.4.x                    jax 0.5+
-===========================  =================================
-``pltpu.TPUCompilerParams``  ``pltpu.CompilerParams``
-``pltpu.TPUMemorySpace``     ``pltpu.MemorySpace``
-===========================  =================================
-
-Every kernel family (attention, qkv, decode, scan) imports the resolved
-names from here instead of reaching into ``pltpu`` directly, so the same
-kernel source runs on either jax line.  ``pltpu.VMEM(shape, dtype)``
-scratch constructors and the ``dimension_semantics`` kwarg spelling are
-stable across both lines and are re-exported for uniformity.
+Every kernel family (attention, qkv, decode, scan) imports
+``CompilerParams``, the memory spaces and the scalar-prefetch grid spec from
+here instead of reaching into ``pltpu`` directly, so the spelling lives in
+one place.
 
 All kernel families also launch through :func:`pallas_call` below rather
 than ``pl.pallas_call`` directly: a drop-in wrapper that, when the
 static-analysis hook is enabled (``REPRO_KERNEL_CHECK=1``, or globally in
-the test suite), validates the launch's BlockSpec/grid/VMEM contract
-against the actual operands before dispatching — see
-:mod:`repro.analysis.kernel_check`.  Disabled (the default), the only
-overhead is one predicate call per launch.
+the test suite), validates the launch's BlockSpec/grid/VMEM contract —
+including Mosaic's (8, 128) block tiling rule — against the actual operands
+before dispatching; see :mod:`repro.analysis.kernel_check`.  Disabled (the
+default), the only overhead is one predicate call per launch.
 """
 from __future__ import annotations
 
+import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# --- compiler params -------------------------------------------------------
-# 0.5+ name first: on those versions TPUCompilerParams still exists but is a
-# deprecated alias that warns.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-# --- memory spaces ---------------------------------------------------------
-MemorySpace = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
-SMEM = MemorySpace.SMEM
-ANY = MemorySpace.ANY
-
+CompilerParams = pltpu.CompilerParams
+SMEM = pltpu.MemorySpace.SMEM
+ANY = pltpu.MemorySpace.ANY
 # VMEM is both a memory space and (called with (shape, dtype)) a scratch-
-# buffer constructor on every supported jax; keep the pltpu object.
+# buffer constructor.
 VMEM = pltpu.VMEM
 
-# Scalar-prefetch grid spec (stable name on both lines): prefetched int32
-# operands land in SMEM before the kernel runs and are visible to BlockSpec
-# index_maps — the mechanism behind page-table-driven K/V gathers.
+# Scalar-prefetch grid spec: prefetched int32 operands land in SMEM before
+# the kernel runs and are visible to BlockSpec index_maps — the mechanism
+# behind page-table-driven K/V gathers.
 PrefetchScalarGridSpec = pltpu.PrefetchScalarGridSpec
+
+
+def interpret_default() -> bool:
+    """Whether a kernel launched with ``interpret=None`` runs in the Pallas
+    interpreter: yes on the CPU backend (tests, host rehearsals), no on the
+    TPU, where every kernel is compiled by Mosaic.  Any other backend has no
+    Mosaic compiler and no business silently interpreting: it raises."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas TPU kernels cannot run on backend {backend!r}")
+
+
+def fit_block(block: int, dim: int, align: int) -> int:
+    """The block length Mosaic accepts nearest above a requested ``block``
+    along a dim of ``dim`` elements: the smallest multiple of ``align`` (8
+    on a block's second-minor dim, 128 on its minor dim) that is
+    ``>= block`` and divides ``dim``, else the whole dim.  Tile sizes are
+    then requests the kernel honours where the chip can."""
+    b = -(-min(block, dim) // align) * align
+    while b < dim:
+        if dim % b == 0:
+            return b
+        b += align
+    return dim
 
 
 def compiler_params(*dimension_semantics: str, **kwargs):

@@ -11,11 +11,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import quant as quant_lib
+from repro.kernels import pallas_compat as pc
 from repro.kernels.qkv import qkv_proj
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("tile_d", "quant", "interpret"))
@@ -23,7 +20,7 @@ def qkv_projection(x, wq, wk, wv, bq=None, bk=None, bv=None, *,
                    tile_d: int = 512, quant: str = "none", interpret=None):
     """x: (B, S, D); w*: (D, H|KV, dh). Returns (q, k, v)."""
     B, S, D = x.shape
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = pc.interpret_default() if interpret is None else interpret
     shapes = [wq.shape[1:], wk.shape[1:], wv.shape[1:]]
     w = jnp.concatenate([wq.reshape(D, -1), wk.reshape(D, -1),
                          wv.reshape(D, -1)], axis=-1)

@@ -55,7 +55,7 @@ def lower_train(cfg, shape, mesh, rules=None, fcfg=None, tcfg=None):
                 for k, v in in_specs.items()}
     metrics_sh = {k: shd.replicated(mesh)
                   for k in ("loss", "grad_norm", "lr_scale")}
-    with mesh, use_rules(rules):
+    with jax.set_mesh(mesh), use_rules(rules):
         jitted = jax.jit(train_step,
                          in_shardings=(state_sh, batch_sh),
                          out_shardings=(state_sh, metrics_sh),
@@ -85,7 +85,7 @@ def lower_serve(cfg, shape, mesh, rules=None, fcfg=None, dtype=jnp.bfloat16):
         return transformer.decode_step(params, tokens, caches, cache_len,
                                        cfg, fcfg)
 
-    with mesh, use_rules(rules):
+    with jax.set_mesh(mesh), use_rules(rules):
         jitted = jax.jit(serve_step,
                          in_shardings=(params_sh, tok_sh, cache_sh, len_sh),
                          out_shardings=((logits_sh, cache_sh)),
@@ -173,7 +173,7 @@ def lower_prefill(cfg, shape, mesh, rules=None, fcfg=None,
     def prefill_step(params, inputs, caches):
         return transformer.prefill(params, inputs, caches, cfg, fcfg)
 
-    with mesh, use_rules(rules):
+    with jax.set_mesh(mesh), use_rules(rules):
         jitted = jax.jit(prefill_step,
                          in_shardings=(params_sh, in_sh, cache_sh),
                          out_shardings=(logits_sh, cache_sh),

@@ -14,23 +14,15 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions: explicit Auto axis types exist
-    only on jax >= 0.5 (0.4.x meshes are implicitly auto-sharded)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis auto-sharded (GSPMD)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def abstract_mesh(shape, axes):
-    """``AbstractMesh`` across jax versions: 0.4.x takes one tuple of
-    (name, size) pairs, 0.5+ takes (axis_sizes, axis_names)."""
+    """Device-free ``AbstractMesh`` of the same axes (dry-run lowering)."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

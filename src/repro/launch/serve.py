@@ -1,9 +1,13 @@
-"""Serving launcher: ``python -m repro.launch.serve --arch <id>``.
+"""Serving launcher: ``python -m repro.launch.serve --arch <id> --size
+{published,shrink}``.
 
-Drives the Scheduler/Runtime continuous-batching engine over a synthetic
-request stream on a reduced config (CPU container); the chunked-prefill /
-decode step functions are the same ones the multi-pod dry-run lowers at
-production shapes.
+:func:`build_engine` is the one place a serving engine is put together —
+config at the requested size, seeded random params, kernel impl and
+dtypes — and both this CLI and ``chip_smoke.py`` call it.  ``--size`` is
+always explicit: ``published`` serves the architecture at its published
+widths, ``shrink`` a reduced same-family config for CPU smoke runs.  The
+platform decides the rest: on a TPU the engine runs the Pallas kernels
+with bf16 params and KV; elsewhere the XLA path in float32.
 """
 from __future__ import annotations
 
@@ -15,15 +19,66 @@ import numpy as np
 
 from repro.configs.base import RunConfig, get_config, shrink
 from repro.core.famous import FamousConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import module, transformer
 from repro.obs.runtime import Observer
 from repro.obs.trace import now
+from repro.parallel import sharding as shardlib
 from repro.serve.engine import Request, ServingEngine
+
+SIZES = ("published", "shrink")
+
+
+def platform_defaults() -> tuple[str, object]:
+    """(kernel impl, param/KV dtype) for the backend JAX runs on: the
+    compiled Pallas kernels in bf16 on a TPU, the XLA path in float32
+    elsewhere (Pallas would only interpret there)."""
+    if jax.default_backend() == "tpu":
+        return "pallas", jnp.bfloat16
+    return "xla", jnp.float32
+
+
+def build_engine(arch: str, *, size: str, seed: int = 0, impl=None,
+                 dtype=None, params=None, mesh=None, observer=None,
+                 **engine_kw) -> ServingEngine:
+    """A :class:`ServingEngine` for ``arch`` at ``size`` ("published" or
+    "shrink").
+
+    ``impl``/``dtype`` default to :func:`platform_defaults`.  ``params``
+    (from another engine's ``.params``) shares weights between engines;
+    otherwise they are drawn from ``seed`` in ``dtype``, directly into their
+    tensor-parallel placement when ``mesh`` is given.  Every other keyword
+    goes to :class:`ServingEngine` unchanged."""
+    assert size in SIZES, size
+    cfg = get_config(arch)
+    if size == "shrink":
+        cfg = shrink(cfg)
+    if cfg.is_encoder_only:
+        raise ValueError(f"{cfg.name} is encoder-only: no decode serving")
+    d_impl, d_dtype = platform_defaults()
+    impl = impl or d_impl
+    dtype = dtype or d_dtype
+    if params is None:
+        spec = transformer.model_spec(cfg)
+        places = None
+        if mesh is not None:
+            places = shardlib.tree_shardings(
+                mesh, transformer.param_axes(cfg),
+                engine_kw.get("sharding_rules") or shardlib.SERVE_TP_RULES,
+                module.param_shapes(spec, dtype))
+        params = module.init_params(spec, jax.random.PRNGKey(seed), dtype,
+                                    shardings=places)
+    return ServingEngine(params, cfg, FamousConfig(impl=impl), dtype=dtype,
+                         mesh=mesh, observer=observer, **engine_kw)
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="famous-bert")
+    ap.add_argument("--size", choices=SIZES, required=True,
+                    help="published: the architecture at its published "
+                         "widths; shrink: a reduced same-family config for "
+                         "CPU smoke runs")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-seq", type=int, default=128)
@@ -108,26 +163,23 @@ def main():
     run = RunConfig(arch=args.arch, tp=tp, dp=dp)
     mesh = run.make_mesh()   # None when tp == dp == 1
 
-    cfg = shrink(get_config(args.arch))
-    if cfg.is_encoder_only:
-        raise SystemExit(f"{cfg.name} is encoder-only: no decode serving")
+    use_compile_cache()
     obs = (Observer(trace=bool(args.trace_out))
            if args.metrics or args.metrics_out or args.trace_out else None)
-    params = module.init_params(transformer.model_spec(cfg),
-                                jax.random.PRNGKey(args.seed), jnp.float32)
-    engine = ServingEngine(params, cfg, FamousConfig(impl="xla"),
-                           mesh=mesh, observer=obs,
-                           n_slots=args.slots, max_seq=args.max_seq,
-                           cache_kind=args.cache_kind,
-                           page_size=args.page_size,
-                           n_pages=args.n_pages or None,
-                           prefill_mode=args.prefill_mode,
-                           chunk=args.chunk,
-                           token_budget=args.token_budget,
-                           prefix_cache=args.prefix_cache,
-                           speculative=args.speculative,
-                           draft_k=args.draft_k,
-                           kv_dtype=args.kv_dtype)
+    engine = build_engine(args.arch, size=args.size, seed=args.seed,
+                          mesh=mesh, observer=obs,
+                          n_slots=args.slots, max_seq=args.max_seq,
+                          cache_kind=args.cache_kind,
+                          page_size=args.page_size,
+                          n_pages=args.n_pages or None,
+                          prefill_mode=args.prefill_mode,
+                          chunk=args.chunk,
+                          token_budget=args.token_budget,
+                          prefix_cache=args.prefix_cache,
+                          speculative=args.speculative,
+                          draft_k=args.draft_k,
+                          kv_dtype=args.kv_dtype)
+    cfg = engine.cfg
     if mesh is not None:
         print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))} over "
               f"{mesh.devices.size} of {jax.device_count()} devices; "

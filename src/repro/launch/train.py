@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from repro.configs.base import SHAPES, SMOKE_SHAPES, ShapeConfig, get_config, shrink
 from repro.core.famous import FamousConfig
 from repro.data import pipeline
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_production_mesh, make_smoke_mesh
 from repro.parallel import sharding as shd
 from repro.train import step as step_lib
@@ -41,7 +42,7 @@ def build(arch: str, shape: ShapeConfig, *, smoke: bool, mesh=None,
     state_sh = shd.tree_shardings(mesh, state_axes, None, state_shapes)
     train_step = step_lib.make_train_step(cfg, fcfg, tcfg)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         state = jax.jit(
             functools.partial(step_lib.init_state, cfg, tcfg),
             out_shardings=state_sh)(jax.random.PRNGKey(seed))
@@ -69,6 +70,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     shape = {**SHAPES, **SMOKE_SHAPES}[args.shape]
     cfg, mesh, state, jitted, batch_fn, state_sh = build(
@@ -81,7 +83,7 @@ def main():
                              state_shardings=state_sh)
     signal.signal(signal.SIGTERM, lambda *_: tr.request_stop())
 
-    with mesh:
+    with jax.set_mesh(mesh):
         tr.run()
     for m in tr.metrics_log[-5:]:
         print({k: round(v, 4) if isinstance(v, float) else v

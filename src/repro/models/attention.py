@@ -56,50 +56,48 @@ def attn_cache_shape(cfg: ModelConfig, batch: int, max_seq: int, window: int,
 ATTN_CACHE_AXES = {"k": ("batch", None, "kv_heads", "head_dim"),
                    "v": ("batch", None, "kv_heads", "head_dim")}
 
-# Paged pools have no slot axis — (n_pages, page_size, kv, dh) — so tensor
-# parallelism shards the kv-head dim; page ids/tables are head-agnostic and
-# the host-side allocator stays single-copy.  int8 pools carry fp32 scale
-# pools (n_pages, page_size, kv) that shard the same way.
-PAGED_ATTN_CACHE_AXES = {"k": (None, None, "kv_heads", "head_dim"),
-                         "v": (None, None, "kv_heads", "head_dim")}
+# Paged pools have no slot axis and are kv-head-major —
+# (kv, n_pages, page_size, dh) — so each (page_size, dh) page is one tile
+# the Pallas kernels can DMA whole, and tensor parallelism shards the
+# leading kv-head dim; page ids/tables are head-agnostic and the host-side
+# allocator stays single-copy.  int8 pools carry fp32 scale pools
+# (kv, n_pages, 1, page_size) — one lane-major scale row per page — that
+# shard the same way.
+PAGED_ATTN_CACHE_AXES = {"k": ("kv_heads", None, None, "head_dim"),
+                         "v": ("kv_heads", None, None, "head_dim")}
 PAGED_ATTN_CACHE_AXES_INT8 = {**PAGED_ATTN_CACHE_AXES,
-                              "k_scale": (None, None, "kv_heads"),
-                              "v_scale": (None, None, "kv_heads")}
+                              "k_scale": ("kv_heads", None, None, None),
+                              "v_scale": ("kv_heads", None, None, None)}
 
 
-def make_paged_attn_cache(cfg: ModelConfig, n_pages: int, page_size: int,
-                          dtype, kv_dtype: str = "fp") -> dict:
+def paged_attn_cache_shape(cfg: ModelConfig, n_pages: int, page_size: int,
+                           dtype, kv_dtype: str = "fp") -> dict:
     """Shared page pool for a global-attention layer: every sequence's K/V
     live in fixed-size pages addressed through a per-slot page table (no
     per-slot batch axis here — the pool is the batch).
 
     ``kv_dtype="int8"`` stores the pools as int8 with parallel fp32
-    ``k_scale``/``v_scale`` pools of shape (n_pages, page_size, kv) — one
-    symmetric scale per (token, kv head), written in the same scatter as
-    the page row so scale rows share the page's id/lifetime by
+    ``k_scale``/``v_scale`` pools of shape (kv, n_pages, 1, page_size) —
+    one symmetric scale per (token, kv head), written in the same scatter
+    as the page row so scale rows share the page's id/lifetime by
     construction (alloc/free/shrink/COW all stay in lockstep for free).
     """
     kv, dh = cfg.num_kv_heads, cfg.head_dim
-    shape = (n_pages, page_size, kv, dh)
     if kv_dtype == "int8":
-        return {"k": jnp.zeros(shape, jnp.int8),
-                "v": jnp.zeros(shape, jnp.int8),
-                "k_scale": jnp.zeros(shape[:3], jnp.float32),
-                "v_scale": jnp.zeros(shape[:3], jnp.float32)}
-    assert kv_dtype == "fp", kv_dtype
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-
-
-def paged_attn_cache_shape(cfg: ModelConfig, n_pages: int, page_size: int,
-                           dtype, kv_dtype: str = "fp") -> dict:
-    kv, dh = cfg.num_kv_heads, cfg.head_dim
-    if kv_dtype == "int8":
-        sds = jax.ShapeDtypeStruct((n_pages, page_size, kv, dh), jnp.int8)
-        ssd = jax.ShapeDtypeStruct((n_pages, page_size, kv), jnp.float32)
+        sds = jax.ShapeDtypeStruct((kv, n_pages, page_size, dh), jnp.int8)
+        ssd = jax.ShapeDtypeStruct((kv, n_pages, 1, page_size), jnp.float32)
         return {"k": sds, "v": sds, "k_scale": ssd, "v_scale": ssd}
     assert kv_dtype == "fp", kv_dtype
-    sds = jax.ShapeDtypeStruct((n_pages, page_size, kv, dh), dtype)
+    sds = jax.ShapeDtypeStruct((kv, n_pages, page_size, dh), dtype)
     return {"k": sds, "v": sds}
+
+
+def make_paged_attn_cache(cfg: ModelConfig, n_pages: int, page_size: int,
+                          dtype, kv_dtype: str = "fp") -> dict:
+    """Zero-filled :func:`paged_attn_cache_shape` pools."""
+    return jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, s.dtype),
+        paged_attn_cache_shape(cfg, n_pages, page_size, dtype, kv_dtype))
 
 
 def _kv_quantize(x):
@@ -110,17 +108,23 @@ def _kv_quantize(x):
 
 
 def _paged_write(cache: dict, pids, offs, k, v) -> dict:
-    """Scatter per-token K/V rows into the page pool at (pids, offs) —
-    quantizing at write time when the pool is int8 (``k_scale`` present)."""
+    """Scatter per-token K/V rows (..., kv, dh) into the kv-head-major page
+    pool at (pids, offs) — quantizing at write time when the pool is int8
+    (``k_scale`` present)."""
+    def put(pool, rows):   # rows (..., kv, dh) -> pool[:, pids, offs]
+        return pool.at[:, pids, offs].set(
+            jnp.moveaxis(rows, -2, 0).astype(pool.dtype))
+
+    def put_scale(pool, s):   # s (..., kv) -> pool[:, pids, 0, offs]
+        return pool.at[:, pids, 0, offs].set(jnp.moveaxis(s, -1, 0))
+
     if "k_scale" in cache:
         kq, ks = _kv_quantize(k)
         vq, vs = _kv_quantize(v)
-        return {"k": cache["k"].at[pids, offs].set(kq),
-                "v": cache["v"].at[pids, offs].set(vq),
-                "k_scale": cache["k_scale"].at[pids, offs].set(ks),
-                "v_scale": cache["v_scale"].at[pids, offs].set(vs)}
-    return {"k": cache["k"].at[pids, offs].set(k.astype(cache["k"].dtype)),
-            "v": cache["v"].at[pids, offs].set(v.astype(cache["v"].dtype))}
+        return {"k": put(cache["k"], kq), "v": put(cache["v"], vq),
+                "k_scale": put_scale(cache["k_scale"], ks),
+                "v_scale": put_scale(cache["v_scale"], vs)}
+    return {"k": put(cache["k"], k), "v": put(cache["v"], v)}
 
 
 def _pool_scales(cache: dict) -> dict:
@@ -250,7 +254,7 @@ def apply_attn_chunk_paged(p: dict, x: jax.Array, cache: dict, page_table,
                            fcfg: famous.FamousConfig):
     """Chunked prefill against the shared page pool.
 
-    x: (1, C, D); cache: {"k","v"} pools (n_pages, page_size, kv, dh);
+    x: (1, C, D); cache: {"k","v"} pools (kv, n_pages, page_size, dh);
     page_table: (n_slots, n_p) int32.  The chunk's K/V scatter into the
     slot's pages (position p -> page ``page_table[slot, p // ps]``, offset
     ``p % ps``); pad positions past the reserved pages land on the null
@@ -259,7 +263,7 @@ def apply_attn_chunk_paged(p: dict, x: jax.Array, cache: dict, page_table,
     C = x.shape[1]
     positions = offset + jnp.arange(C)
     q, k, v = _project(p, x, cfg, fcfg, positions)
-    ps = cache["k"].shape[1]
+    ps = cache["k"].shape[2]
     pt_row = page_table[slot]                          # (n_p,)
     pids = pt_row[positions // ps]
     offs = positions % ps
@@ -328,7 +332,7 @@ def apply_attn_verify_paged(p: dict, x: jax.Array, cache: dict, page_table,
                             cache_len, cfg: ModelConfig,
                             fcfg: famous.FamousConfig):
     """Speculative verify against the shared page pool.  x: (B, W, D);
-    cache: {"k","v"} pools (n_pages, page_size, kv, dh); page_table:
+    cache: {"k","v"} pools (kv, n_pages, page_size, dh); page_table:
     (B, n_p) int32; cache_len: (B,) valid entries BEFORE the first verify
     token.  Position p of slot b scatters into page
     ``page_table[b, p // ps]`` — explicitly redirected to the null page
@@ -341,7 +345,7 @@ def apply_attn_verify_paged(p: dict, x: jax.Array, cache: dict, page_table,
     B, W = x.shape[:2]
     positions = cache_len[:, None] + jnp.arange(W)      # (B, W)
     q, k, v = _project(p, x, cfg, fcfg, positions)
-    ps = cache["k"].shape[1]
+    ps = cache["k"].shape[2]
     n_p = page_table.shape[1]
     blk = positions // ps
     b_idx = jnp.arange(B)[:, None]
@@ -361,7 +365,7 @@ def apply_attn_decode_paged(p: dict, x: jax.Array, cache: dict, page_table,
                             fcfg: famous.FamousConfig):
     """One-token decode against the shared page pool.
 
-    x: (B, 1, D); cache: {"k","v"} pools (n_pages, page_size, kv, dh);
+    x: (B, 1, D); cache: {"k","v"} pools (kv, n_pages, page_size, dh);
     page_table: (B, pages_per_slot) int32; cache_len: (B,) valid entries
     BEFORE this token.  The new token's K/V scatter into page
     ``page_table[b, len // page_size]`` at offset ``len % page_size``.
@@ -373,7 +377,7 @@ def apply_attn_decode_paged(p: dict, x: jax.Array, cache: dict, page_table,
     B = x.shape[0]
     positions = cache_len[:, None]
     q, k, v = _project(p, x, cfg, fcfg, positions)
-    ps = cache["k"].shape[1]
+    ps = cache["k"].shape[2]
     pids = page_table[jnp.arange(B), cache_len // ps]      # (B,)
     offs = cache_len % ps
     cache = _paged_write(cache, pids, offs, k[:, 0], v[:, 0])

@@ -211,7 +211,7 @@ def make_caches(cfg: ModelConfig, batch: int, max_seq: int, dtype,
     cache_kind="contiguous": every attention layer gets a per-slot
     ``(batch, max_seq | window, kv, dh)`` buffer (the seed baseline).
     cache_kind="paged": global-attention layers instead share a
-    ``(n_pages, page_size, kv, dh)`` page pool addressed through the page
+    ``(kv, n_pages, page_size, dh)`` page pool addressed through the page
     table that ``decode_step`` receives at call time; memory then scales
     with live tokens, not ``batch x max_seq`` (see serve/paged.py).
     kv_dtype="int8" (paged only) stores those pools as int8 with fp32
@@ -594,17 +594,17 @@ def _write_slot(dst, src, slot, axis):
 def _scatter_pages(pool, kv_seq, page_ids):
     """Scatter a contiguous (.., max_seq, kv, dh) K/V stripe into pool pages.
 
-    pool: (..., n_pages, page_size, kv, dh); kv_seq: (..., max_seq, kv, dh);
+    pool: (..., kv, n_pages, page_size, dh); kv_seq: (..., max_seq, kv, dh);
     page_ids: (pages_per_slot,) int32, NULL-padded past the slot's live pages
     (the null page absorbs the padded chunks).  max_seq == pages_per_slot *
     page_size by construction (engine asserts max_seq % page_size == 0).
     """
     n_p = page_ids.shape[0]
-    ps = pool.shape[-3]
+    ps = pool.shape[-2]
     lead = kv_seq.shape[:-3]
-    chunks = kv_seq.reshape(lead + (n_p, ps) + kv_seq.shape[-2:])
-    axis = len(lead)
-    idx = (slice(None),) * axis + (page_ids,)
+    kv, dh = kv_seq.shape[-2:]
+    chunks = jnp.moveaxis(kv_seq.reshape(lead + (n_p, ps, kv, dh)), -2, -4)
+    idx = (slice(None),) * (len(lead) + 1) + (page_ids,)
     return pool.at[idx].set(chunks.astype(pool.dtype))
 
 
@@ -617,8 +617,9 @@ def _scatter_paged_kv(dst: dict, src_k, src_v, page_ids) -> dict:
         from repro.core import quant as quant_lib
         kq, ks = quant_lib.quantize(src_k, axis=-1)
         vq, vs = quant_lib.quantize(src_v, axis=-1)
+        # (..., kv, 1, ps) scale rows scatter as a dh=ps page of one row
         scatter_s = lambda pool, s: _scatter_pages(     # noqa: E731
-            pool[..., None], s, page_ids)[..., 0]
+            pool.swapaxes(-1, -2), s, page_ids).swapaxes(-1, -2)
         return {"k": _scatter_pages(dst["k"], kq, page_ids),
                 "v": _scatter_pages(dst["v"], vq, page_ids),
                 "k_scale": scatter_s(dst["k_scale"], ks),

@@ -1,7 +1,7 @@
 """In-graph sharding constraints from logical axis names.
 
 ``constrain(x, axes)`` applies ``with_sharding_constraint`` using the ambient
-mesh (the ``with mesh:`` context the launcher jits under) and the same
+mesh (the ``jax.set_mesh`` context the launcher jits under) and the same
 divisibility-aware rule resolution as parallel/sharding.py.  No-op when no
 mesh is active (CPU smoke tests) so model code stays mesh-agnostic.
 """
@@ -30,22 +30,10 @@ def use_rules(rules):
 
 
 def _ambient_mesh():
-    try:  # explicit-mesh contexts (jax >= 0.7)
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and m.axis_names:
-            return m
-    except Exception:
-        pass
-    try:  # classic `with mesh:` context
-        import warnings
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            m = jax.interpreters.pxla.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
+    """The mesh set by ``jax.set_mesh`` / ``jax.sharding.use_mesh``, or
+    None outside any."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
 
 
 def constrain(x, axes, rules=None):

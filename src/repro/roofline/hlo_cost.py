@@ -48,12 +48,8 @@ _COLL_OPS = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalised across jax versions: 0.4.x
-    returns a one-element list of dicts, 0.5+ the dict itself."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """``compiled.cost_analysis()``, or ``{}`` when the backend gives none."""
+    return compiled.cost_analysis() or {}
 
 
 def _type_bytes(type_str: str) -> int:

@@ -101,10 +101,10 @@ class Request:
 
 
 def _jit_cache_size(fn) -> int:
-    try:
-        return fn._cache_size()
-    except Exception:                      # pragma: no cover - jax-internal API
-        return -1
+    """Executables compiled for one jitted function.  Reads jax's own
+    counter and lets any failure raise: a census that silently returned a
+    placeholder would pass every ``<= N`` check."""
+    return fn._cache_size()
 
 
 class ServingEngine:
@@ -204,12 +204,18 @@ class ServingEngine:
         # GSPMD inserts the only collectives (attention-output + FFN-down
         # all-reduces) inside the steps.  Host-side state (allocator, page
         # tables, cache_len/last_token numpy, scheduler) is device-agnostic:
-        # page ids address whole (page_size, kv, dh) rows whose kv dim is
+        # page ids address whole (kv, page_size, dh) pages whose kv dim is
         # what actually shards, so one copy serves every device.
         self.mesh = mesh
+        # every hot executable donates the caches it is handed, so the
+        # updated caches reuse the input pool's buffers instead of a second
+        # pool per step (the layer scan still stacks its new caches in one
+        # pool-sized temporary)
         self._jit_kw_caches: dict = {}   # jits returning caches only
         self._jit_kw_logits: dict = {}   # jits returning (logits, caches)
         if mesh is not None:
+            # Pallas launches are shard_mapped over the same "model" axis
+            self.fcfg = fcfg = dataclasses.replace(fcfg, mesh=mesh)
             rules = sharding_rules or shardlib.SERVE_TP_RULES
             self.params = jax.device_put(
                 self.params,
@@ -255,20 +261,20 @@ class ServingEngine:
         self._prefill_exec: dict[int, callable] = {}    # monolithic only
         self._prefill_chunk_exec = jax.jit(functools.partial(
             transformer.prefill_chunk, cfg=cfg, fcfg=fcfg),
-            **self._jit_kw_caches)
+            donate_argnums=2, **self._jit_kw_caches)
         self._decode = jax.jit(
             functools.partial(transformer.decode_step, cfg=cfg, fcfg=fcfg),
-            **self._jit_kw_logits)
+            donate_argnums=2, **self._jit_kw_logits)
         # the speculative path REPLACES decode with one fixed-shape verify
         # executable (batch n_slots, width draft_k+1, per-slot runtime
         # offsets): a zero-draft slot verifies as a 1-valid-token decode,
         # so the census stays at three hot executables either way
         self._verify = jax.jit(
             functools.partial(transformer.verify_step, cfg=cfg, fcfg=fcfg),
-            **self._jit_kw_logits)
+            donate_argnums=2, **self._jit_kw_logits)
         self._clear = jax.jit(functools.partial(
             transformer.clear_slot, cfg=cfg, paged=self.paged),
-            **self._jit_kw_caches)
+            donate_argnums=0, **self._jit_kw_caches)
         self._sample = jax.jit(sampling.sample_tokens,
                                static_argnames=("k_cap",))
         self._sample_verify = jax.jit(sampling.verify_tokens,

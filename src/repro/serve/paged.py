@@ -5,7 +5,7 @@ index that lets requests share identical prompt blocks.
 FAMOUS banks its attention operands into fixed-size BRAM tiles so one
 synthesis serves many shapes; the serving analogue is a *paged* KV cache:
 every global-attention layer shares one pool of fixed-size pages
-``(n_pages, page_size, kv_heads, head_dim)`` and each slot owns a list of
+``(kv_heads, n_pages, page_size, head_dim)`` and each slot owns a list of
 page ids (its *page table*) instead of a contiguous ``max_seq`` stripe.
 HBM then scales with live tokens (``sum(ceil(len/page_size))`` pages), not
 with ``n_slots x max_seq``, so a single long-context request can coexist

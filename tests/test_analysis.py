@@ -263,6 +263,30 @@ def test_kernel_check_vmem_budget(monkeypatch):
             args=(np.zeros((16, 8), np.float32),))
 
 
+def test_kernel_check_mosaic_second_minor_tiling():
+    """A size-1 block on the second-minor dim of a larger array (the old
+    token-major paged K/V pools) is refused by Mosaic."""
+    with pytest.raises(KernelContractError, match="multiple of 8 nor"):
+        kernel_check.check_launch(
+            name="bad", grid=(16,),
+            in_specs=[pl.BlockSpec((1, 128), lambda i: (i, 0))],
+            out_specs=pl.BlockSpec((1, 128), lambda i: (i, 0)),
+            out_shape=_sds((16, 128)),
+            args=(np.zeros((16, 128), np.float32),))
+
+
+def test_kernel_check_mosaic_minor_tiling():
+    """A lane dim that is neither a multiple of 128 nor the whole array
+    dim is refused by Mosaic."""
+    with pytest.raises(KernelContractError, match="multiple of 128 nor"):
+        kernel_check.check_launch(
+            name="bad", grid=(4,),
+            in_specs=[pl.BlockSpec((8, 64), lambda i: (0, i))],
+            out_specs=pl.BlockSpec((8, 64), lambda i: (0, i)),
+            out_shape=_sds((8, 256)),
+            args=(np.zeros((8, 256), np.float32),))
+
+
 def test_kernel_check_aggregates_all_violations():
     with pytest.raises(KernelContractError) as ei:
         kernel_check.check_launch(
@@ -335,7 +359,7 @@ def test_existing_kernels_pass_contract_check(rng):
         assert out.shape == (2, 16, 8)
         pt = jnp.asarray(np.arange(1, 9, dtype=np.int32).reshape(2, 4))
         out = paged_decode_attention(
-            arr(2, 1, 2, 8), arr(9, 4, 1, 8), arr(9, 4, 1, 8), pt,
+            arr(2, 1, 2, 8), arr(1, 9, 4, 8), arr(1, 9, 4, 8), pt,
             jnp.array([5, 9], jnp.int32), interpret=True)
         assert out.shape == (2, 1, 2, 8)
 
@@ -352,7 +376,7 @@ def test_paged_kernel_passes_under_jit(rng):
         q, k, v, pt, ln, interpret=True))
     pt = jnp.asarray(np.arange(1, 9, dtype=np.int32).reshape(2, 4))
     with checking(True):
-        out = fn(arr(2, 1, 2, 8), arr(9, 4, 1, 8), arr(9, 4, 1, 8), pt,
+        out = fn(arr(2, 1, 2, 8), arr(1, 9, 4, 8), arr(1, 9, 4, 8), pt,
                  jnp.array([5, 9], jnp.int32))
     assert out.shape == (2, 1, 2, 8)
 

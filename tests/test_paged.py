@@ -67,8 +67,8 @@ def test_paged_kernel_matches_gather_reference():
     ps, n_pages, n_p = 8, 17, 4
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (B, KV, group, dh), jnp.float32)
-    kp = jax.random.normal(ks[1], (n_pages, ps, KV, dh), jnp.float32)
-    vp = jax.random.normal(ks[2], (n_pages, ps, KV, dh), jnp.float32)
+    kp = jax.random.normal(ks[1], (KV, n_pages, ps, dh), jnp.float32)
+    vp = jax.random.normal(ks[2], (KV, n_pages, ps, dh), jnp.float32)
     rng = np.random.default_rng(0)
     pt = jnp.asarray(rng.integers(1, n_pages, size=(B, n_p)), jnp.int32)
     lens = jnp.asarray([5, 23, 32], jnp.int32)
@@ -103,8 +103,8 @@ def test_paged_chunk_prefill_kernel_matches_reference():
     n_pages = 1 + B * n_p
     ks = jax.random.split(jax.random.PRNGKey(4), 3)
     q = jax.random.normal(ks[0], (B, C, H, dh), jnp.float32)
-    kp = jax.random.normal(ks[1], (n_pages, ps, KV, dh), jnp.float32)
-    vp = jax.random.normal(ks[2], (n_pages, ps, KV, dh), jnp.float32)
+    kp = jax.random.normal(ks[1], (KV, n_pages, ps, dh), jnp.float32)
+    vp = jax.random.normal(ks[2], (KV, n_pages, ps, dh), jnp.float32)
     rng = np.random.default_rng(4)
     pt = jnp.asarray(
         rng.permutation(np.arange(1, n_pages)).reshape(B, n_p), jnp.int32)
@@ -121,9 +121,9 @@ def test_paged_chunk_prefill_kernel_matches_reference():
 def _quantized_pools(key, n_pages, ps, KV, dh):
     """An fp pool plus its symmetric per-token-per-head int8 quantization."""
     from repro.core import quant as quant_lib
-    pool = jax.random.normal(key, (n_pages, ps, KV, dh), jnp.float32)
+    pool = jax.random.normal(key, (KV, n_pages, ps, dh), jnp.float32)
     q, s = quant_lib.quantize(pool, axis=-1)
-    return pool, q, s[..., 0].astype(jnp.float32)
+    return pool, q, s.swapaxes(-1, -2).astype(jnp.float32)
 
 
 def test_paged_int8_decode_kernel_matches_oracle():
@@ -186,10 +186,12 @@ def test_paged_matches_contiguous_decode(impl):
     rng = np.random.default_rng(1)
     ids = rng.permutation(np.arange(1, 1 + B * n_p)).reshape(B, n_p)
     n_pages = 1 + B * n_p
-    kp = jnp.zeros((n_pages, ps, KV, dh), jnp.float32)
-    vp = jnp.zeros((n_pages, ps, KV, dh), jnp.float32)
-    kp = kp.at[ids].set(kc.reshape(B, n_p, ps, KV, dh))
-    vp = vp.at[ids].set(vc.reshape(B, n_p, ps, KV, dh))
+    kp = jnp.zeros((KV, n_pages, ps, dh), jnp.float32)
+    vp = jnp.zeros((KV, n_pages, ps, dh), jnp.float32)
+    kp = kp.at[:, ids].set(kc.reshape(B, n_p, ps, KV, dh)
+                           .transpose(3, 0, 1, 2, 4))
+    vp = vp.at[:, ids].set(vc.reshape(B, n_p, ps, KV, dh)
+                           .transpose(3, 0, 1, 2, 4))
     pt = jnp.asarray(ids, jnp.int32)
     fcfg = FamousConfig(impl=impl)
     paged = famous.paged_decode_attention(q, kp, vp, pt, lens, cfg=fcfg)

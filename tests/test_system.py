@@ -26,7 +26,7 @@ def test_end_to_end_training_loss_decreases(tmp_path):
         jitted, state, batch_fn,
         trainer_lib.TrainerConfig(total_steps=20, ckpt_every=10,
                                   ckpt_dir=str(tmp_path / "e2e")))
-    with mesh:
+    with jax.set_mesh(mesh):
         tr.run()
     losses = [m["loss"] for m in tr.metrics_log]
     assert len(losses) == 20
@@ -52,6 +52,6 @@ def test_serve_cli_smoke():
     env = dict(os.environ, PYTHONPATH=SRC)
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--arch", "deepseek-7b",
-         "--requests", "3", "--max-new", "3"],
+         "--size", "shrink", "--requests", "3", "--max-new", "3"],
         capture_output=True, text=True, env=env, timeout=900)
     assert "served 3 requests" in out.stdout, out.stdout + out.stderr[-2000:]

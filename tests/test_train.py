@@ -208,10 +208,6 @@ def test_gradient_compression_error_feedback():
     error feedback drives the *accumulated* bias to ~zero over steps."""
     from jax.sharding import PartitionSpec as P
 
-    shard_map = getattr(jax, "shard_map", None)  # moved to jax.* in 0.5
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
-
     mesh = make_mesh((1,), ("pod",))
     g = {"w": jax.random.normal(jax.random.PRNGKey(0), (64, 64))}
 
@@ -220,8 +216,8 @@ def test_gradient_compression_error_feedback():
         def inner(g):
             out, res = compression.compressed_psum_tree(g, mesh, "pod")
             return out, res
-        return shard_map(inner, mesh=mesh, in_specs=({"w": P()},),
-                         out_specs=({"w": P()}, {"w": P()}))(g)
+        return jax.shard_map(inner, mesh=mesh, in_specs=({"w": P()},),
+                             out_specs=({"w": P()}, {"w": P()}))(g)
 
     out, res = run(g)
     scale = float(jnp.abs(g["w"]).max()) / 127.0
