@@ -17,7 +17,12 @@ refuses to run, and prints no result, unless JAX's first device is a TPU.
     executable census is <= 3 and a warm batch compiles nothing.
 (c) The same model with int8 KV, the prefix cache and speculative decoding
     serves shared-system-prompt requests: every request served, prefix
-    hits > 0, drafts verified > 0.
+    hits > 0, drafts verified > 0, and the same greedy tokens as the same
+    requests served without speculation.
+
+Every Pallas engine's steps that ran (chunked prefill, decode or verify)
+are compiled again at the arguments they ran with, and each executable
+must hold ``tpu_custom_call``: the engine ran the compiled kernels.
 
 ``--four-chips`` runs only the phase (b) Pallas engine tensor-parallel over
 a ("data", "model") = (1, 4) mesh, after the same requests unsharded on one
@@ -37,7 +42,9 @@ import os
 import sys
 
 ARCH = "qwen2-7b"
-N_SLOTS, MAX_SEQ, PAGE, CHUNK = 4, 2048, 16, 32
+# the engine's slots, sequence length, page and chunk are the sizes the
+# kernel catalog checks (repro.kernels.catalog, imported once src/ is on
+# the path)
 N_REQUESTS, NEW_TOKENS = 8, 32
 PROMPT_LENS = (64, 1500)
 # bf16 weights and KV through 28 layers: the two impls round differently
@@ -105,32 +112,60 @@ def requests(seed: int, vocab: int, shared: list | None = None):
             n = int(rng.integers(PROMPT_LENS[0], PROMPT_LENS[1] + 1))
             prompt = rng.integers(0, vocab, n).tolist()
         else:
-            tail = rng.integers(0, vocab, int(rng.integers(16, MAX_SEQ // 8)))
+            tail = rng.integers(0, vocab, int(rng.integers(16, 256)))
             prompt = shared + tail.tolist()
         reqs.append(Request(rid=i, tokens=prompt, max_new=NEW_TOKENS))
     return reqs
 
 
-class FirstTokenLogits:
-    """Stands in for an engine's decode executable and keeps, per request,
-    the logits of its first decode: the next-token distribution at the
-    last prompt position, right after chunked prefill."""
+class Tap:
+    """Stands in for one of an engine's jitted steps.  Lowers the step at
+    the live arguments of its first call, so the step that ran can be
+    compiled again and its HLO read; on the decode step it also keeps, per
+    request, the logits of its first decode: the next-token distribution at
+    the last prompt position, right after chunked prefill."""
 
-    def __init__(self, engine):
-        self.engine, self.fn, self.rows = engine, engine._decode, {}
-        engine._decode = self
+    STEPS = ("_prefill_chunk_exec", "_decode", "_verify")
+
+    def __init__(self, engine, name: str):
+        self.engine, self.name, self.fn = engine, name, getattr(engine, name)
+        self.lowered, self.rows = None, {}
+        setattr(engine, name, self)
 
     def _cache_size(self):            # the engine's census reads this
         return self.fn._cache_size()
 
-    def __call__(self, *args, active, **kw):
+    def __call__(self, *args, **kw):
         import numpy as np
-        logits, caches = self.fn(*args, active=active, **kw)
-        act = np.asarray(active)
-        for i, req in enumerate(self.engine.slot_req):
-            if req is not None and act[i] and not req.out:
-                self.rows[req.rid] = np.asarray(logits[i], np.float32)
-        return logits, caches
+        if self.lowered is None:      # before the call donates the caches
+            self.lowered = self.fn.lower(*args, **kw)
+        out = self.fn(*args, **kw)
+        if self.name == "_decode":
+            act = np.asarray(kw["active"])
+            for i, req in enumerate(self.engine.slot_req):
+                if req is not None and act[i] and not req.out:
+                    self.rows[req.rid] = np.asarray(out[0][i], np.float32)
+        return out
+
+
+def tap(engine) -> dict:
+    return {name: Tap(engine, name) for name in Tap.STEPS}
+
+
+def kernels_compiled(taps: dict, label: str) -> None:
+    """Each step the engine ran, compiled at the arguments it ran with,
+    must launch compiled Pallas kernels, never interpreted ones."""
+    ran = []
+    for t in taps.values():
+        if t.lowered is None:
+            continue
+        text = t.lowered.compile().as_text()
+        n = text.count("tpu_custom_call")
+        assert n > 0, f"{label}: {t.name} launched no compiled Pallas kernel"
+        ran.append(f"{t.name.strip('_')} {n}")
+    assert ran, f"{label}: no step ran"
+    log(f"{label}: tpu_custom_call in every step that ran "
+        f"({', '.join(ran)} occurrences)")
 
 
 def serve(engine, reqs, label: str):
@@ -159,7 +194,7 @@ def census_ok(engine, label: str) -> None:
 def warm_batch(engine, seed: int, label: str) -> None:
     """A fresh batch on the warmed engine must compile nothing."""
     from repro.analysis.retrace_guard import retrace_guard
-    reqs = requests(seed + 1000, engine.cfg.vocab_size)[:N_SLOTS]
+    reqs = requests(seed + 1000, engine.cfg.vocab_size)[:engine.n_slots]
     for r in reqs:
         r.rid, r.tokens = r.rid + 1000, r.tokens[:200]
     with retrace_guard(engine, label=label):
@@ -189,71 +224,78 @@ def token_agreement(a, b) -> str:
     return f"{same}/{sum(len(r.out) for r in b)}"
 
 
+def shared_prompt_requests(seed: int, vocab: int):
+    import numpy as np
+    rng = np.random.default_rng(seed + 7)
+    phrase = rng.integers(0, vocab, 64).tolist()
+    shared = phrase * 8                     # a 512-token system prompt
+    return requests(seed + 7, vocab, shared)
+
+
 # ---------------------------------------------------------------------------
 # phases (b) and (c): the engine
 # ---------------------------------------------------------------------------
 
 def engine_kw(**over):
-    kw = dict(n_slots=N_SLOTS, max_seq=MAX_SEQ, cache_kind="paged",
-              page_size=PAGE, chunk=CHUNK)
+    from repro.kernels import catalog
+    kw = dict(n_slots=catalog.SLOTS, max_seq=catalog.MAX_SEQ,
+              cache_kind="paged", page_size=catalog.PAGE, chunk=catalog.CHUNK)
     kw.update(over)
     return kw
 
 
 def phase_engine(seed: int):
     import jax
-    import jax.numpy as jnp
     from repro.launch.serve import build_engine
 
     dev = jax.devices()[0]
     eng = build_engine(ARCH, size="published", seed=seed, impl="pallas",
-                       dtype=jnp.bfloat16, **engine_kw())
+                       **engine_kw())
     cfg = eng.cfg
     log(f"(b) {cfg.name}: {cfg.num_layers} layers, d_model {cfg.d_model}, "
         f"{cfg.num_heads}H/{cfg.num_kv_heads}KV x {cfg.head_dim}, d_ff "
         f"{cfg.d_ff}, vocab {cfg.vocab_size}; params "
-        f"{sum(x.nbytes for x in jax.tree_util.tree_leaves(eng.params)) / 2 ** 30:.2f} GiB bf16, "
+        f"{sum(x.nbytes for x in jax.tree_util.tree_leaves(eng.params)) / 2 ** 30:.2f} GiB "
+        f"{jax.numpy.dtype(eng.dtype).name}, "
         f"KV pool {eng.cache_bytes_per_device() / 2 ** 20:.0f} MiB")
-    tap = FirstTokenLogits(eng)
+    taps = tap(eng)
     pallas = serve(eng, requests(seed, cfg.vocab_size), "(b) pallas")
-    pallas_logits = dict(tap.rows)
+    pallas_logits = dict(taps["_decode"].rows)
     census_ok(eng, "(b) pallas")
+    kernels_compiled(taps, "(b) pallas")
     warm_batch(eng, seed, "(b) pallas")
     log(f"(b) pallas: peak device memory {peak_gib(dev)}")
     params = eng.params
     release(eng)
-    del eng, tap
+    del eng, taps
 
     eng = build_engine(ARCH, size="published", params=params, impl="xla",
-                       dtype=jnp.bfloat16, **engine_kw())
-    tap = FirstTokenLogits(eng)
+                       **engine_kw())
+    taps = tap(eng)
     xla = serve(eng, requests(seed, cfg.vocab_size), "(b) xla")
     census_ok(eng, "(b) xla")
-    compare_logits(pallas_logits, tap.rows, "(b) pallas vs xla")
+    compare_logits(pallas_logits, taps["_decode"].rows, "(b) pallas vs xla")
     log(f"(b) pallas vs xla: greedy tokens agree "
         f"{token_agreement(pallas, xla)}")
     release(eng)
-    del eng, tap
+    del eng, taps
     log("(b) PASS: published-width qwen2-7b served 8/8 through the Pallas "
         "path, logits within tolerance of XLA, census <= 3, no warm retrace")
     return params
 
 
 def phase_features(seed: int, params) -> None:
-    import jax.numpy as jnp
-    import numpy as np
     from repro.launch.serve import build_engine
 
     eng = build_engine(ARCH, size="published", params=params, impl="pallas",
-                       dtype=jnp.bfloat16,
                        **engine_kw(kv_dtype="int8", prefix_cache=True,
                                    speculative=True))
-    rng = np.random.default_rng(seed + 7)
-    phrase = rng.integers(0, eng.cfg.vocab_size, 64).tolist()
-    shared = phrase * (MAX_SEQ // 256)      # a 512-token system prompt
-    serve(eng, requests(seed + 7, eng.cfg.vocab_size, shared),
-          "(c) int8+prefix+spec")
+    vocab = eng.cfg.vocab_size
+    taps = tap(eng)
+    spec = serve(eng, shared_prompt_requests(seed, vocab),
+                 "(c) int8+prefix+spec")
     census_ok(eng, "(c) int8+prefix+spec")
+    kernels_compiled(taps, "(c) int8+prefix+spec")
     log(f"(c) prefix cache: {eng.prefix_hit_pages} pages / "
         f"{eng.prefix_hit_tokens} tokens reused over "
         f"{eng.prefix_lookups} lookups; speculation: {eng.spec_drafted} "
@@ -262,35 +304,51 @@ def phase_features(seed: int, params) -> None:
     assert eng.prefix_hit_pages > 0, "no prefix-cache hits"
     assert eng.spec_drafted > 0, "no drafts verified"
     release(eng)
+    del eng, taps
+
+    # greedy speculation must not change a token: the same requests once
+    # more on the same params and int8 KV, decoded one token per step
+    eng = build_engine(ARCH, size="published", params=params, impl="pallas",
+                       **engine_kw(kv_dtype="int8", prefix_cache=True))
+    taps = tap(eng)
+    plain = serve(eng, shared_prompt_requests(seed, vocab),
+                  "(c) int8+prefix, no speculation")
+    kernels_compiled(taps, "(c) int8+prefix, no speculation")
+    log(f"(c) speculation vs none: greedy tokens agree "
+        f"{token_agreement(spec, plain)}")
+    assert [r.out for r in spec] == [r.out for r in plain], \
+        "speculative decoding changed greedy tokens"
+    release(eng)
     log("(c) PASS: int8 KV + prefix cache + speculation served every "
-        "request with prefix hits")
+        "request with prefix hits and the tokens of plain decoding")
 
 
 def phase_four_chips(seed: int) -> None:
     import jax
-    import jax.numpy as jnp
     from repro.launch.mesh import make_serving_mesh
     from repro.launch.serve import build_engine
 
     assert len(jax.devices()) >= 4, "--four-chips needs four devices"
     eng = build_engine(ARCH, size="published", seed=seed, impl="pallas",
-                       dtype=jnp.bfloat16, **engine_kw())
-    tap = FirstTokenLogits(eng)
+                       **engine_kw())
+    taps = tap(eng)
     base = serve(eng, requests(seed, eng.cfg.vocab_size), "(4) one chip")
-    base_logits, total_kv = tap.rows, eng.cache_bytes_per_device()
+    base_logits = taps["_decode"].rows
+    total_kv = eng.cache_bytes_per_device()
     total_params = sum(x.nbytes for x in jax.tree_util.tree_leaves(eng.params))
     release(eng)
     eng.params = None
-    del eng, tap
+    del eng, taps
     gc.collect()
 
     mesh = make_serving_mesh(tp=4)
     eng = build_engine(ARCH, size="published", seed=seed, impl="pallas",
-                       dtype=jnp.bfloat16, mesh=mesh, **engine_kw())
-    tap = FirstTokenLogits(eng)
+                       mesh=mesh, **engine_kw())
+    taps = tap(eng)
     tp = serve(eng, requests(seed, eng.cfg.vocab_size), "(4) TP=4")
     census_ok(eng, "(4) TP=4")
-    compare_logits(tap.rows, base_logits, "(4) TP=4 vs one chip")
+    kernels_compiled(taps, "(4) TP=4")
+    compare_logits(taps["_decode"].rows, base_logits, "(4) TP=4 vs one chip")
     log(f"(4) TP=4 vs one chip: greedy tokens agree "
         f"{token_agreement(tp, base)}")
 

@@ -89,8 +89,8 @@ def run_kernel_check() -> int:
             q, k, v, out, lse, arr(2, 16, 8), block_q=8, block_k=8,
             interpret=True)))
         launches.append(("qkv/matmul_tiled", lambda: matmul_tiled(
-            arr(16, 32), arr(32, 16), block_t=8, block_f=8, block_d=16,
-            interpret=True)))
+            arr(16, 256), arr(256, 256), block_t=8, block_f=128,
+            block_d=128, interpret=True)))
         launches.append(("decode/decode_attention", lambda: decode_attention(
             arr(2, 2, 8), arr(2, 16, 8), arr(2, 16, 8),
             jnp.array([5, 9], jnp.int32), block_k=8, interpret=True)))
@@ -120,7 +120,7 @@ def run_kernel_check() -> int:
                              qarr(1, 9, 4, 8), sarr(1, 9, 1, 4), sarr(1, 9, 1, 4),
                              pt, 4, chunk=4, interpret=True)))
         launches.append(("scan/rglru_scan", lambda: rglru_scan(
-            arr(2, 8, 8), arr(2, 8, 8), block_r=8, block_s=4,
+            arr(2, 16, 256), arr(2, 16, 256), block_r=128, block_s=8,
             interpret=True)))
         launches.append(("scan/wkv6_scan", lambda: wkv6_scan(
             arr(2, 8, 8), arr(2, 8, 8), arr(2, 8, 8),

@@ -62,37 +62,46 @@ class FamousConfig:
     max_heads: int = 0
     max_seq: int = 0
     max_d_model: int = 0
-    # Device mesh the Pallas kernels run under (serving TP): GSPMD cannot
-    # partition a Mosaic call, so each launch is shard_mapped over the
-    # mesh's "model" axis (see _per_device).  None: one device.
+    # Device mesh the Pallas kernels run under (serving TP), and the mesh
+    # axis (or axes) holding attention heads and kv heads there, as the
+    # serving engine's sharding rules place them: GSPMD cannot partition a
+    # Mosaic call, so each launch is shard_mapped over that axis (see
+    # _per_device).  mesh None: one device.
     mesh: Any = None
+    head_axis: Any = None
 
 
-HEADS = P(None, None, "model")   # (B, S, H|KV, dh) activations and caches
-POOL = P("model")                # (KV, n_pages, ...) page and scale pools
-REP = P()                        # page tables, lengths, offsets, x
+# Where each kernel wrapper's operands keep their heads (or kv heads): the
+# dim index, or None for operands every device holds whole.
+HEADS = 2        # (B, S, H|KV, dh) activations and contiguous caches
+POOL = 0         # (KV, n_pages, ...) page and scale pools
+REP = None       # page tables, lengths, offsets, x
 
 
-def _per_device(cfg: FamousConfig, fn, in_specs, out_specs, *args):
+def _per_device(cfg: FamousConfig, fn, in_dims, out_dims, *args):
     """Launch a Pallas kernel wrapper once per device of ``cfg.mesh``.
 
-    Heads and kv heads split over the "model" axis exactly as the serving
-    TP rules place them, so each device runs the kernel on its own heads
-    and its own slice of the page pool with no collective.  When a head
-    count does not divide the axis (the rules then replicate it), every
-    device runs the whole kernel on replicated operands."""
-    tp = 1 if cfg.mesh is None else cfg.mesh.shape.get("model", 1)
-    if tp == 1:
+    ``in_dims``/``out_dims`` name each operand's and output's head dim.
+    Those dims split over ``cfg.head_axis``, where the params and caches
+    already live, so each device runs the kernel on its own heads and its
+    own slice of the page pool with no collective.  When a head count does
+    not divide the axis (the rules then replicate it), or the heads are
+    not split at all, every device runs the whole kernel on replicated
+    operands."""
+    mesh, axis = cfg.mesh, cfg.head_axis
+    if mesh is None or mesh.size == 1:
         return fn(*args)
+    names = () if axis is None else axis if isinstance(axis, tuple) else (axis,)
+    tp = math.prod(mesh.shape[a] for a in names)
+    split = tp > 1 and all(d is None or a.shape[d] % tp == 0
+                           for d, a in zip(in_dims, args))
 
-    def divides(spec, a):
-        return all(a.shape[d] % tp == 0
-                   for d, ax in enumerate(spec) if ax == "model")
+    def spec(d):
+        return P() if d is None or not split else P(*(None,) * d, axis)
 
-    if not all(divides(s, a) for s, a in zip(in_specs, args)):
-        in_specs = (REP,) * len(args)
-        out_specs = jax.tree_util.tree_map(lambda _: REP, out_specs)
-    return jax.shard_map(fn, mesh=cfg.mesh, in_specs=tuple(in_specs),
+    out_specs = (tuple(map(spec, out_dims)) if isinstance(out_dims, tuple)
+                 else spec(out_dims))
+    return jax.shard_map(fn, mesh=mesh, in_specs=tuple(map(spec, in_dims)),
                          out_specs=out_specs, check_vma=False)(*args)
 
 
@@ -162,8 +171,7 @@ def qkv_projection(x, wq, wk, wv, bq=None, bk=None, bv=None, *,
                                         tile_d=cfg.tile_d)
     if cfg.impl == "pallas":
         from repro.kernels.qkv import ops as qkv_ops
-        w = P(None, "model")                      # (D, H|KV, dh) weights
-        b = P("model")                            # (H|KV, dh) biases
+        w, b = 1, 0              # (D, H|KV, dh) weights, (H|KV, dh) biases
         biases = () if bq is None else (bq, bk, bv)
         return _per_device(
             cfg, lambda x, wq, wk, wv, *bs: qkv_ops.qkv_projection(

@@ -11,9 +11,10 @@ share the list:
 * ``chip_smoke.py`` runs every case on the chip with seeded operands and
   compares it with its oracle.
 
-Shapes follow the serving engine's use: paged pools of ``slots *
-pages_per_slot`` pages plus the null page, one ``chunk``-token prefill
-chunk, one decode token per slot.
+Shapes are those of the engine ``chip_smoke.py`` serves, which imports
+the sizes below: ``SLOTS`` slots of ``MAX_SEQ`` tokens in ``PAGE``-token
+pages (a pool of ``SLOTS * MAX_SEQ / PAGE`` pages plus the null page), one
+``CHUNK``-token prefill chunk, one decode token per slot, in bf16.
 """
 from __future__ import annotations
 
@@ -30,6 +31,9 @@ from repro.kernels.attention import mha, ref as attn_ref
 from repro.kernels.decode import decode_attn, ops as dec_ops
 from repro.kernels.decode import ref as dec_ref
 from repro.kernels.qkv import qkv_proj
+
+SLOTS, MAX_SEQ, PAGE, CHUNK = 4, 2048, 16, 32
+DTYPE = jnp.bfloat16
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,16 +64,15 @@ def _mha_reference_lse(q, k, v):
     return attn_ref.mha_reference(q, k, v, causal=True), lse
 
 
-def main_path_cases(cfg: ModelConfig, *, slots: int = 8,
-                    pages_per_slot: int = 128, page_size: int = 16,
-                    chunk: int = 32, dtype=jnp.bfloat16) -> list[KernelCase]:
+def main_path_cases(cfg: ModelConfig) -> list[KernelCase]:
     """Every kernel the paged, chunked-prefill serving path (and the
     contiguous and training paths next to it) launches, at ``cfg``'s
-    widths."""
+    widths and the module's serving sizes."""
     KV, H, dh = cfg.num_kv_heads, cfg.num_heads, cfg.head_dim
     group = H // KV
+    slots, page_size, chunk, S = SLOTS, PAGE, CHUNK, MAX_SEQ
+    pages_per_slot = S // page_size
     n_pages = 1 + slots * pages_per_slot
-    S = pages_per_slot * page_size
     D = cfg.d_model
     F = (H + 2 * KV) * dh                     # fused [Wq|Wk|Wv] width
     pool = (KV, n_pages, page_size, dh)
@@ -82,7 +85,7 @@ def main_path_cases(cfg: ModelConfig, *, slots: int = 8,
         return jax.random.randint(key, (n,), 1, S + 1, jnp.int32)
 
     def normal(key, shape):
-        return jax.random.normal(key, shape).astype(dtype)
+        return jax.random.normal(key, shape).astype(DTYPE)
 
     def paged_decode(key):
         ks = jax.random.split(key, 5)

@@ -46,20 +46,6 @@ def interpret_default() -> bool:
     raise RuntimeError(f"Pallas TPU kernels cannot run on backend {backend!r}")
 
 
-def fit_block(block: int, dim: int, align: int) -> int:
-    """The block length Mosaic accepts nearest above a requested ``block``
-    along a dim of ``dim`` elements: the smallest multiple of ``align`` (8
-    on a block's second-minor dim, 128 on its minor dim) that is
-    ``>= block`` and divides ``dim``, else the whole dim.  Tile sizes are
-    then requests the kernel honours where the chip can."""
-    b = -(-min(block, dim) // align) * align
-    while b < dim:
-        if dim % b == 0:
-            return b
-        b += align
-    return dim
-
-
 def compiler_params(*dimension_semantics: str, **kwargs):
     """Build compiler params with the given per-grid-dim semantics.
 

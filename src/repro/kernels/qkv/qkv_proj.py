@@ -62,9 +62,10 @@ def _matmul_call(x, w, block_t, block_f, block_d, out_dtype, interpret):
     """x: (T, D) @ w: (D, F) -> (T, F), reduction-tiled (TS = block_d)."""
     T, D = x.shape
     _, F = w.shape
-    block_t = pc.fit_block(block_t, T, 8)
-    block_f = pc.fit_block(block_f, F, 128)
-    block_d = pc.fit_block(block_d, D, 128)
+    block_t = min(block_t, T)
+    block_f = min(block_f, F)
+    block_d = min(block_d, D)
+    assert T % block_t == 0 and F % block_f == 0 and D % block_d == 0
     n_d = D // block_d
     grid = (T // block_t, F // block_f, n_d)
     return pc.pallas_call(
@@ -119,9 +120,10 @@ def matmul_tiled_int8(xq, wq, sx, sw, *, block_t: int = 256,
     """xq: (T, D) int8, wq: (D, F) int8, sx: (T, 1) f32, sw: (1, F) f32."""
     T, D = xq.shape
     _, F = wq.shape
-    block_t = pc.fit_block(block_t, T, 8)
-    block_f = pc.fit_block(block_f, F, 128)
-    block_d = pc.fit_block(block_d, D, 128)
+    block_t = min(block_t, T)
+    block_f = min(block_f, F)
+    block_d = min(block_d, D)
+    assert T % block_t == 0 and F % block_f == 0 and D % block_d == 0
     n_d = D // block_d
     grid = (T // block_t, F // block_f, n_d)
     return pc.pallas_call(

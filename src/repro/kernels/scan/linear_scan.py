@@ -50,8 +50,9 @@ def rglru_scan(a, b, *, block_r: int = 512, block_s: int = 256,
                interpret: bool = False):
     """a, b: (B, S, R) -> h: (B, S, R) f32 with h_t = a_t h_{t-1} + b_t."""
     B, S, R = a.shape
-    block_r = pc.fit_block(block_r, R, 128)
-    block_s = pc.fit_block(block_s, S, 8)
+    block_r = min(block_r, R)
+    block_s = min(block_s, S)
+    assert S % block_s == 0 and R % block_r == 0
     grid = (B, R // block_r, S // block_s)
     return pc.pallas_call(
         functools.partial(_rglru_kernel, block_s=block_s),

@@ -39,25 +39,25 @@ def platform_defaults() -> tuple[str, object]:
 
 
 def build_engine(arch: str, *, size: str, seed: int = 0, impl=None,
-                 dtype=None, params=None, mesh=None, observer=None,
+                 params=None, mesh=None, observer=None,
                  **engine_kw) -> ServingEngine:
     """A :class:`ServingEngine` for ``arch`` at ``size`` ("published" or
     "shrink").
 
-    ``impl``/``dtype`` default to :func:`platform_defaults`.  ``params``
-    (from another engine's ``.params``) shares weights between engines;
-    otherwise they are drawn from ``seed`` in ``dtype``, directly into their
-    tensor-parallel placement when ``mesh`` is given.  Every other keyword
-    goes to :class:`ServingEngine` unchanged."""
+    The param/KV dtype comes from :func:`platform_defaults`, and so does
+    ``impl`` unless given (the XLA path serves as the Pallas path's
+    reference on the chip).  ``params`` (from another engine's ``.params``)
+    shares weights between engines; otherwise they are drawn from ``seed``,
+    directly into their tensor-parallel placement when ``mesh`` is given.
+    Every other keyword goes to :class:`ServingEngine` unchanged."""
     assert size in SIZES, size
     cfg = get_config(arch)
     if size == "shrink":
         cfg = shrink(cfg)
     if cfg.is_encoder_only:
         raise ValueError(f"{cfg.name} is encoder-only: no decode serving")
-    d_impl, d_dtype = platform_defaults()
+    d_impl, dtype = platform_defaults()
     impl = impl or d_impl
-    dtype = dtype or d_dtype
     if params is None:
         spec = transformer.model_spec(cfg)
         places = None

@@ -214,9 +214,19 @@ class ServingEngine:
         self._jit_kw_caches: dict = {}   # jits returning caches only
         self._jit_kw_logits: dict = {}   # jits returning (logits, caches)
         if mesh is not None:
-            # Pallas launches are shard_mapped over the same "model" axis
-            self.fcfg = fcfg = dataclasses.replace(fcfg, mesh=mesh)
             rules = sharding_rules or shardlib.SERVE_TP_RULES
+            # Pallas launches are shard_mapped over the mesh axis these
+            # rules give the heads; a kernel holds a query head's kv head,
+            # so both head kinds must split the same way
+            heads, kv_heads = (shardlib.spec_for_axes(mesh, (a,), rules)[0]
+                               for a in ("heads", "kv_heads"))
+            if fcfg.impl == "pallas" and heads != kv_heads:
+                raise ValueError(
+                    f"impl='pallas' needs heads and kv_heads on one mesh "
+                    f"axis; the sharding rules give {heads!r} and "
+                    f"{kv_heads!r}")
+            self.fcfg = fcfg = dataclasses.replace(fcfg, mesh=mesh,
+                                                   head_axis=heads)
             self.params = jax.device_put(
                 self.params,
                 shardlib.tree_shardings(mesh, transformer.param_axes(cfg),

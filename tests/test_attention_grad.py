@@ -111,13 +111,15 @@ def test_qkv_matmul_grad_matches_xla():
     """The tiled QKV projection kernel differentiates through itself."""
     from repro.kernels.qkv import qkv_proj
     ks = jax.random.split(jax.random.PRNGKey(4), 3)
-    x = jax.random.normal(ks[0], (64, 128)) * 0.5
-    w = jax.random.normal(ks[1], (128, 64)) * 0.05
-    g = jax.random.normal(ks[2], (64, 64))
+    # every axis spans two blocks, so the forward reduces over D blocks
+    # and the dW pass over T blocks
+    x = jax.random.normal(ks[0], (256, 256)) * 0.5
+    w = jax.random.normal(ks[1], (256, 256)) * 0.05
+    g = jax.random.normal(ks[2], (256, 256))
 
     def loss_k(x, w):
-        return jnp.sum(qkv_proj.matmul_tiled(x, w, block_t=32, block_f=32,
-                                             block_d=64, interpret=True) * g)
+        return jnp.sum(qkv_proj.matmul_tiled(x, w, block_t=128, block_f=128,
+                                             block_d=128, interpret=True) * g)
 
     def loss_x(x, w):
         return jnp.sum((x @ w) * g)

@@ -64,7 +64,7 @@ def test_mha_kernel_window():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("T,D,F,bt,bf,bd", [
-    (128, 256, 192, 64, 64, 64),
+    (128, 256, 384, 64, 128, 128),
     (256, 512, 128, 128, 128, 256),
     (64, 128, 128, 64, 128, 128),
 ])
@@ -97,7 +97,7 @@ def test_matmul_tiled_int8():
 
 @pytest.mark.parametrize("quant_mode", ["none", "int8"])
 def test_qkv_projection_wrapper(quant_mode):
-    B, S, D, H, KV, dh = 2, 32, 128, 4, 2, 16
+    B, S, D, H, KV, dh = 2, 32, 256, 4, 2, 16
     x = _rand(7, (B, S, D), jnp.float32)
     wq = _rand(8, (D, H, dh), jnp.float32, 0.05)
     wk = _rand(9, (D, KV, dh), jnp.float32, 0.05)
@@ -106,7 +106,7 @@ def test_qkv_projection_wrapper(quant_mode):
     bk = _rand(12, (KV, dh), jnp.float32, 0.01)
     bv = _rand(13, (KV, dh), jnp.float32, 0.01)
     q, k, v = qkv_ops.qkv_projection(x, wq, wk, wv, bq, bk, bv,
-                                     tile_d=64, quant=quant_mode)
+                                     tile_d=128, quant=quant_mode)
     qr, kr, vr = qkv_ref.qkv_reference(x, wq, wk, wv, bq, bk, bv)
     tol = 1e-5 if quant_mode == "none" else 0.05
     for a, b in [(q, qr), (k, kr), (v, vr)]:
@@ -143,7 +143,7 @@ def test_decode_kernel(B, H, KV, dh, Skv, bk, window):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("B,S,R,br,bs", [
-    (2, 128, 96, 32, 32),
+    (2, 128, 256, 128, 32),
     (1, 64, 256, 128, 64),
     (3, 96, 32, 32, 32),
 ])

@@ -169,3 +169,39 @@ def test_cache_bytes_per_device_shrink(tp, cache_kind):
     _, base_eng = _run("attn", cache_kind=cache_kind)
     _, eng = _run("attn", make_serving_mesh(tp=tp), cache_kind=cache_kind)
     assert eng.cache_bytes_per_device() * tp == base_eng.cache_bytes_per_device()
+
+
+@pytest.mark.parametrize("kv_rule,want", [("model", "model"), (None, None)])
+def test_pallas_head_axis_follows_rules(kv_rule, want):
+    """The Pallas launches split heads over the mesh axis the engine's
+    sharding rules give them; rules that split heads and kv heads
+    differently are refused for impl="pallas" (runs single-device)."""
+    from repro.parallel.sharding import SERVE_TP_RULES
+    cfg, params = _cfg_params("attn")
+    rules = {**SERVE_TP_RULES, "kv_heads": kv_rule}
+    kw = dict(n_slots=2, max_seq=32, chunk=8, cache_kind="paged",
+              page_size=8, mesh=make_serving_mesh(tp=1),
+              sharding_rules=rules)
+    if want is None:
+        with pytest.raises(ValueError, match="one mesh axis"):
+            ServingEngine(params, cfg, FamousConfig(impl="pallas"), **kw)
+    else:
+        eng = ServingEngine(params, cfg, FamousConfig(impl="pallas"), **kw)
+        assert eng.fcfg.head_axis == want
+
+
+@pytest.mark.parametrize("cache_kind", ["contiguous", "paged"])
+def test_pallas_heads_on_rule_axis(cache_kind):
+    """Rules that put the heads on the "data" axis move the Pallas
+    launches' split there too: still token-identical to one device."""
+    _need(2)
+    from repro.parallel.sharding import SERVE_TP_RULES
+    rules = {**SERVE_TP_RULES, "batch": None, "heads": "data",
+             "kv_heads": "data", "mlp": "data"}
+    base = _baseline("attn", "pallas", cache_kind)
+    outs, eng = _run("attn", make_serving_mesh(tp=1, dp=2), "pallas",
+                     cache_kind, sharding_rules=rules)
+    assert eng.fcfg.head_axis == "data"
+    assert outs == base
+    assert eng.cache_bytes_per_device() * 2 == \
+        _run("attn", cache_kind=cache_kind)[1].cache_bytes_per_device()

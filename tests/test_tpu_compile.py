@@ -1,6 +1,7 @@
 """Ahead-of-time compiles of the serving main path's Pallas kernels for a
-TPU v5e at qwen2-7b's published widths (KV 4, group 7, dh 128; page 16,
-8 slots x 128 pages; bf16 and int8).
+TPU v5e at qwen2-7b's published widths (KV 4, group 7, dh 128) and the
+serving sizes of ``repro.kernels.catalog`` (page 16, 4 slots x 128 pages,
+chunk 32; bf16 and int8).
 
 Interpret-mode tests never apply Mosaic's tiling rules; the TPU compiler
 installed with jax does, for a chip that is described and not attached.
