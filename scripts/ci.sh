@@ -44,34 +44,43 @@ echo "== benchmarks/serving_bench.py smoke (tiny config) =="
 PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" SERVING_BENCH_TINY=1 \
   python benchmarks/serving_bench.py
 
-# --- observability: launcher smoke with metrics + tracing ------------------
-# Serve a small batch with the Observer attached, then validate both
-# export formats: the Chrome trace must hold >0 balanced events with
-# slot/rid attribution, and the Prometheus exposition must pass the
-# format checker (docs/observability.md).  The tok/s overhead gate lives
-# in the serving bench's obs_off/obs_on rows above.
+# --- observability: launcher smoke with metrics + profiler spans ----------
+# Serve a small batch with the span-enabled Observer under a jax.profiler
+# trace, then validate both exports: the Perfetto file must hold one
+# engine.step span per engine step (numbered in order, none overlapping)
+# with every prefill-chunk/decode/sync/retire span inside one, and the
+# Prometheus exposition must pass the format checker
+# (docs/observability.md).  The tok/s overhead gate lives in the serving
+# bench's obs_off/obs_on rows above.
 echo "== observability =="
 OBS_TMP=$(mktemp -d)
 python -m repro.launch.serve --arch qwen2-7b --size shrink --requests 4 --slots 2 \
   --max-new 4 --cache-kind paged --prefix-cache \
-  --metrics-out "$OBS_TMP/metrics.prom" --trace-out "$OBS_TMP/trace.json"
+  --metrics-out "$OBS_TMP/metrics.prom" --trace-out "$OBS_TMP/trace"
 python - "$OBS_TMP" <<'EOF'
-import json, sys
+import glob, gzip, json, sys
 from repro.obs.metrics import validate_prometheus_text
 tmp = sys.argv[1]
-doc = json.load(open(f"{tmp}/trace.json"))
-evs = doc["traceEvents"]
-assert evs, "trace must record events"
-depth = 0
-for e in evs:
-    assert {"name", "ph", "ts", "pid", "tid"} <= set(e), e
-    depth += {"B": 1, "E": -1}.get(e["ph"], 0)
-    assert depth >= 0, "unbalanced trace"
-assert depth == 0, "unclosed phase spans"
-assert any(e["ph"] == "B" for e in evs), "no phase spans recorded"
-n = validate_prometheus_text(open(f"{tmp}/metrics.prom").read())
+text = open(f"{tmp}/metrics.prom").read()
+n = validate_prometheus_text(text)
 assert n > 100, f"suspiciously small exposition ({n} samples)"
-print(f"observability ok: {len(evs)} trace events, {n} exposition samples")
+(path,) = glob.glob(f"{tmp}/trace/**/perfetto_trace.json.gz", recursive=True)
+evs = [e for e in json.load(gzip.open(path))["traceEvents"]
+       if e.get("ph") == "X" and e.get("name", "").startswith("engine.")]
+steps = sorted((e["ts"], e["ts"] + e["dur"], int(e["args"]["step_num"]))
+               for e in evs if e["name"] == "engine.step")
+want = [line for line in text.splitlines()
+        if line.startswith("repro_engine_steps_total ")]
+assert len(steps) == int(float(want[0].split()[1])) > 0, (len(steps), want)
+assert [k for _, _, k in steps] == list(range(len(steps))), "step_num order"
+assert all(a[1] <= b[0] for a, b in zip(steps, steps[1:])), "steps overlap"
+inner = [e for e in evs if e["name"] in ("engine.prefill_chunk",
+         "engine.decode", "engine.sync", "engine.retire")]
+assert inner and all(any(s <= e["ts"] and e["ts"] + e["dur"] <= t
+                         for s, t, _ in steps) for e in inner), \
+    "an engine span outside every engine.step"
+print(f"observability ok: {len(steps)} engine.step spans, {len(evs)} "
+      f"engine spans, {n} exposition samples")
 EOF
 rm -rf "$OBS_TMP"
 

@@ -8,10 +8,14 @@ always explicit: ``published`` serves the architecture at its published
 widths, ``shrink`` a reduced same-family config for CPU smoke runs.  The
 platform decides the rest: on a TPU the engine runs the Pallas kernels
 with bf16 params and KV; elsewhere the XLA path in float32.
+``--trace-out DIR`` captures a ``jax.profiler`` trace of the served run,
+with a Perfetto file holding the engine's ``engine.*`` spans beside the
+device's ops.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +40,16 @@ def platform_defaults() -> tuple[str, object]:
     if jax.default_backend() == "tpu":
         return "pallas", jnp.bfloat16
     return "xla", jnp.float32
+
+
+def profiler_span(name: str, **args):
+    """One span on the ``jax.profiler`` timeline: the engine's step as a
+    step marker (``StepTraceAnnotation``), any other span a
+    ``TraceAnnotation``.  The factory an Observer is given, so that
+    ``repro.obs`` stays jax-free."""
+    if name == "engine.step":
+        return jax.profiler.StepTraceAnnotation(name, **args)
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 def build_engine(arch: str, *, size: str, seed: int = 0, impl=None,
@@ -150,9 +164,11 @@ def main():
     ap.add_argument("--metrics-out", default="", metavar="PATH",
                     help="also write the exposition to PATH (implies "
                          "--metrics)")
-    ap.add_argument("--trace-out", default="", metavar="PATH",
-                    help="record per-step-phase trace events and write "
-                         "Chrome/Perfetto trace_event JSON to PATH "
+    ap.add_argument("--trace-out", default="", metavar="DIR",
+                    help="capture a jax.profiler trace of the served run "
+                         "into DIR, with a Perfetto file "
+                         "(perfetto_trace.json.gz, ui.perfetto.dev) holding "
+                         "the engine.* spans beside the device's ops "
                          "(implies --metrics)")
     args = ap.parse_args()
 
@@ -164,8 +180,12 @@ def main():
     mesh = run.make_mesh()   # None when tp == dp == 1
 
     use_compile_cache()
-    obs = (Observer(trace=bool(args.trace_out))
-           if args.metrics or args.metrics_out or args.trace_out else None)
+    if args.trace_out:
+        obs = Observer(span=profiler_span)
+    elif args.metrics or args.metrics_out:
+        obs = Observer()
+    else:
+        obs = None
     engine = build_engine(args.arch, size=args.size, seed=args.seed,
                           mesh=mesh, observer=obs,
                           n_slots=args.slots, max_seq=args.max_seq,
@@ -206,9 +226,17 @@ def main():
                     temperature=args.temperature, top_k=args.top_k,
                     seed=args.seed + i)
             for i in range(args.requests)]
-    t0 = now()
-    done = engine.run(reqs)
-    dt = now() - t0
+    tracing = contextlib.nullcontext()
+    if args.trace_out:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0     # the engine's spans, not every call
+        tracing = jax.profiler.trace(args.trace_out,
+                                     create_perfetto_trace=True,
+                                     profiler_options=opts)
+    with tracing:
+        t0 = now()
+        done = engine.run(reqs)
+        dt = now() - t0
     tok = sum(len(r.out) for r in done)
     census = engine.compilations
     print(f"served {len(done)} requests, {tok} tokens in {dt:.2f}s "
@@ -238,11 +266,9 @@ def main():
         print(f"  req {r.rid}: prompt[:6]={r.tokens[:6]} -> out={r.out} "
               f"(ttft={ttft:.0f}ms, prefill_toks={f.get('prefill_tokens', 0)},"
               f" preemptions={f.get('preemptions', 0)})")
+    if args.trace_out:
+        print(f"trace: jax.profiler trace -> {args.trace_out}")
     if obs is not None:
-        if args.trace_out:
-            obs.write_trace(args.trace_out)
-            print(f"trace: {len(obs.tracer.events)} events "
-                  f"({obs.tracer.dropped} dropped) -> {args.trace_out}")
         text = obs.prometheus_text()
         if args.metrics_out:
             with open(args.metrics_out, "w", encoding="utf-8") as fh:

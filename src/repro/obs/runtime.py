@@ -4,11 +4,13 @@ FAMOUS's evaluation is per-module accounting — latency and GOPS per
 attention module, tile-level utilisation — and the serving analogue is a
 single seam that surfaces what each layer of the engine is doing:
 
-  * **Runtime** (``ServingEngine``): step phases (prefill-chunk / decode /
-    verify) as trace spans + duration histograms, TTFT/TPOT per retired
-    request, speculation drafted/accepted, the executable census.
-  * **Scheduler**: admissions, queue depth, prefill/decode token counts,
-    preemptions.
+  * **Runtime** (``ServingEngine``): spans around the step and its parts
+    (admit, prefill chunk, decode / verify, the device→host sync, retire),
+    step-phase duration histograms, TTFT and its two parts (queue wait,
+    admission to first token) per retired request, decode launches,
+    speculation drafted/accepted, the executable census.
+  * **Scheduler**: admissions and the tokens they bring, queue depth,
+    prefill/decode token counts, preemptions.
   * **PageAllocator**: page grow/shrink/free/publish/evict, pool
     utilisation, prefix-cache hits/misses and pages saved.
   * **Drafter** (``PromptLookupDrafter``): lookup hit rate and proposed
@@ -17,40 +19,60 @@ single seam that surfaces what each layer of the engine is doing:
 Everything is *host-side and pull-based*: hooks take plain python ints
 already on the host (the engine's one device→host sync per decode step is
 unchanged), counters are dict adds, and reading happens only when someone
-calls :meth:`Observer.snapshot` / :meth:`prometheus_text` /
-:meth:`trace_json`.  The module is contractually jax-free (lint rule
-RA004) so observability can never introduce a device sync.  Measured
-overhead of an enabled Observer is ≤2% tok/s on the serving benchmark's
-``obs_on`` / ``obs_off`` row pair (gated at 5% in CI; see
-docs/observability.md for the catalog and the contract).
+calls :meth:`Observer.snapshot` / :meth:`prometheus_text`.  Spans go
+through a factory the caller injects — ``jax.profiler.TraceAnnotation``,
+so they sit on the profiler's timeline beside the device's ops — and this
+module stays jax-free (lint rule RA004): observability can never
+introduce a device sync.
 
 ``observer=None`` (every constructor's default) resolves to
-:data:`NULL_OBSERVER`, whose hooks are empty methods — the off state
-costs one no-op call per event.
+:data:`NULL_OBSERVER`, whose hooks are empty methods and whose spans are
+one shared no-op context — the off state costs one no-op call per event
+and records no span.
 """
 from __future__ import annotations
 
 import contextlib
 
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import Tracer, now
+from repro.obs.trace import now
 
-# engine step phases the tracer records (docs/observability.md schema)
-PHASES = ("prefill_chunk", "decode", "verify")
+
+class _NullSpan:
+    """A span nothing records: enters as itself, drops its metadata."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return None
+
+    def set_metadata(self, **args) -> None:
+        pass
+
+
+NULL_SPAN = _NullSpan()
 
 
 class Observer:
-    """Metrics + (optional) tracing over one serving engine.
+    """Metrics, and spans when given a span factory, over one engine.
 
-    Construct with ``trace=True`` to also record per-phase trace events;
-    metrics are always collected.  One Observer belongs to one engine —
-    the census registration and step attribution are per-engine state.
+    ``span(name, **args)`` makes the context manager of one span; what it
+    enters as takes ``set_metadata(**args)`` for arguments known only at
+    the span's end.  ``jax.profiler.TraceAnnotation`` is one, and puts the
+    spans on the profiler's timeline; the caller that imports jax passes
+    it (``repro.launch.serve.profiler_span``).  Without a factory no span
+    is recorded; metrics are always collected.  One Observer belongs to
+    one engine (the census source is per-engine).
     """
 
-    def __init__(self, trace: bool = False, trace_limit: int = 200_000):
+    def __init__(self, span=None):
         self.metrics = MetricsRegistry()
-        self.tracer = Tracer(limit=trace_limit) if trace else None
-        self.step = 0                       # engine step, for attribution
+        self._span = span
+        # span arguments that cost work to compute are computed only then
+        self.spans_on = span is not None
         self._census_source = None
         m = self.metrics
         # -- request lifecycle ----------------------------------------------
@@ -67,6 +89,16 @@ class Observer:
         self._tpot = m.histogram(
             "repro_request_tpot_seconds",
             "mean per-token time after the first token, per request")
+        self._queue_wait = m.histogram(
+            "repro_request_queue_wait_seconds",
+            "time from submit to first admission, per request")
+        self._admit_to_first = m.histogram(
+            "repro_request_admit_to_first_seconds",
+            "time from first admission to first emitted token, per request")
+        self._admitted_tokens = m.counter(
+            "repro_admitted_prompt_tokens_total",
+            "sequence tokens brought by each admission (prompt + resumed "
+            "output)")
         # -- engine step ----------------------------------------------------
         self._steps = m.counter("repro_engine_steps_total",
                                 "scheduler plans executed")
@@ -79,6 +111,8 @@ class Observer:
             "repro_slots_occupied", "slots holding a request")
         self._tokens = m.counter("repro_tokens_generated_total",
                                  "decode/verify tokens emitted")
+        self._decode_launches = m.counter(
+            "repro_decode_launches_total", "batched decode/verify launches")
         self._prefill_tokens = m.counter(
             "repro_prefill_tokens_total", "prompt tokens prefilled (chunked)")
         self._preempts = m.counter("repro_preemptions_total",
@@ -136,22 +170,26 @@ class Observer:
         return c
 
     def on_step(self, queue_depth: int, occupied: int) -> None:
-        self.step += 1
         self._steps.inc()
         self._queue_depth.set(queue_depth)
         self._slots_occ.set(occupied)
 
+    def span(self, name: str, **args):
+        """One span on the injected factory's timeline (``NULL_SPAN``
+        without a factory)."""
+        if self._span is None:
+            return NULL_SPAN
+        return self._span(name, **args)
+
     @contextlib.contextmanager
     def phase(self, name: str, **args):
-        """Trace span + duration histogram around one step phase."""
+        """Span ``engine.<name>`` + duration histogram around one step
+        phase."""
         t0 = now()
-        if self.tracer is not None:
-            self.tracer.begin(name, step=self.step, **args)
         try:
-            yield
+            with self.span(f"engine.{name}", **args) as sp:
+                yield sp
         finally:
-            if self.tracer is not None:
-                self.tracer.end(name, step=self.step)
             self._phase_s.observe(now() - t0, phase=name)
 
     def on_enqueue(self, rid) -> None:
@@ -162,9 +200,7 @@ class Observer:
 
     def on_admit(self, rid, slot: int, n_tokens: int, cached: int) -> None:
         self._admitted.inc()
-        if self.tracer is not None:
-            self.tracer.instant("admit", step=self.step, rid=rid, slot=slot,
-                                n_tokens=n_tokens, cached=cached)
+        self._admitted_tokens.inc(n_tokens)
 
     def on_prefix_lookup(self, rid, hit_pages: int, hit_tokens: int) -> None:
         self._prefix.inc(result="hit" if hit_pages else "miss")
@@ -178,25 +214,26 @@ class Observer:
     def on_tokens(self, n: int) -> None:
         self._tokens.inc(n)
 
+    def on_decode_launch(self) -> None:
+        self._decode_launches.inc()
+
     def on_preempt(self, rid, slot: int) -> None:
         self._preempts.inc()
-        if self.tracer is not None:
-            self.tracer.instant("preempt", step=self.step, rid=rid, slot=slot)
 
     def on_retire(self, req, slot: int = -1) -> None:
         """Request leaving the engine (retired, failed, or swept at
-        ``max_steps``): TTFT/TPOT from its clock marks, status counter,
-        and the retire trace instant."""
+        ``max_steps``): status counter, and from its clock marks TTFT,
+        its two parts (queue wait, admission to first token) and TPOT."""
         status = "error" if req.error is not None else "ok"
         self._retired.inc(status=status)
         if req.t_first is not None and req.t_submit is not None:
             self._ttft.observe(req.t_first - req.t_submit)
+            if req.t_admit is not None:
+                self._queue_wait.observe(req.t_admit - req.t_submit)
+                self._admit_to_first.observe(req.t_first - req.t_admit)
             if req.t_done is not None and len(req.out) > 1:
                 self._tpot.observe((req.t_done - req.t_first)
                                    / (len(req.out) - 1))
-        if self.tracer is not None:
-            self.tracer.instant("retire", step=self.step, rid=req.rid,
-                                slot=slot, n_out=len(req.out), status=status)
 
     def on_spec_step(self) -> None:
         self._spec_steps.inc()
@@ -209,9 +246,6 @@ class Observer:
     def on_page_event(self, op: str, slot: int, n: int) -> None:
         if n:
             self._pages.inc(n, op=op)
-            if self.tracer is not None:
-                self.tracer.instant(f"page_{op}", step=self.step, slot=slot,
-                                    pages=n)
 
     def on_pool(self, free: int, cached_free: int) -> None:
         self._pages_free.set(free)
@@ -234,26 +268,20 @@ class Observer:
         self.census()
         return self.metrics.prometheus_text()
 
-    def trace_json(self) -> dict:
-        assert self.tracer is not None, "Observer built with trace=False"
-        return self.tracer.to_json()
-
-    def write_trace(self, path: str) -> None:
-        assert self.tracer is not None, "Observer built with trace=False"
-        self.tracer.write(path)
-
 
 class NullObserver:
-    """The off state: every hook is an empty method, ``phase`` yields a
-    shared no-op context.  Engines call hooks unconditionally; this keeps
-    the disabled cost at one attribute lookup + no-op call per event."""
+    """The off state: every hook is an empty method, and ``phase`` and
+    ``span`` return the shared :data:`NULL_SPAN`.  Engines call hooks
+    unconditionally; this keeps the disabled cost at one attribute lookup
+    + no-op call per event, and records no span."""
 
-    tracer = None
-    step = 0
-    _NULL_CTX = contextlib.nullcontext()
+    spans_on = False
 
     def phase(self, name: str, **args):
-        return self._NULL_CTX
+        return NULL_SPAN
+
+    def span(self, name: str, **args):
+        return NULL_SPAN
 
     def register_census(self, source) -> None: pass
     def census(self) -> dict: return {}
@@ -264,6 +292,7 @@ class NullObserver:
     def on_prefix_lookup(self, rid, hit_pages, hit_tokens) -> None: pass
     def on_prefill_tokens(self, n) -> None: pass
     def on_tokens(self, n) -> None: pass
+    def on_decode_launch(self) -> None: pass
     def on_preempt(self, rid, slot) -> None: pass
     def on_retire(self, req, slot=-1) -> None: pass
     def on_spec_step(self) -> None: pass

@@ -48,8 +48,10 @@ state fall back to cold prefill (``prefix_cache_active`` False).
 Observability is one injectable seam: pass
 ``observer=repro.obs.Observer(...)`` and every layer — engine step
 phases, scheduler queues, page allocator, drafter — reports into its
-metrics registry and (optionally) its Perfetto tracer, host-side only
-and token-identical to the un-observed engine (docs/observability.md).
+metrics registry, and the engine opens its ``engine.*`` spans through the
+Observer's span factory (``jax.profiler.TraceAnnotation``: the profiler's
+timeline), host-side only and token-identical to the un-observed engine
+(docs/observability.md).
 """
 from __future__ import annotations
 
@@ -94,10 +96,22 @@ class Request:
     error: Optional[str] = None  # set when the page pool can never back it
     # wall-clock marks for TTFT/TPOT accounting, set by the engine from the
     # single monotonic clock source (repro.obs.trace.now — the repo's one
-    # time.perf_counter call site, shared with trace timestamps)
+    # time.perf_counter call site).  t_admit is the FIRST admission (a
+    # re-admission after preemption keeps it): TTFT = queue wait
+    # (t_admit - t_submit) + admission to first token (t_first - t_admit)
     t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
     t_first: Optional[float] = None
     t_done: Optional[float] = None
+
+
+def _named(fn, **bound):
+    """``fn`` with ``bound`` fixed, under ``fn``'s name: jit names the
+    executable, and the profiler its launches, ``jit_<name>`` (a bare
+    partial would be ``jit__unknown``)."""
+    f = functools.partial(fn, **bound)
+    f.__name__ = fn.__name__
+    return f
 
 
 def _jit_cache_size(fn) -> int:
@@ -123,8 +137,8 @@ class ServingEngine:
         the one injectable seam every layer (engine, scheduler, page
         allocator, drafter) reports to: TTFT/TPOT histograms, queue
         depth, pool utilisation, prefix/speculation counters, the
-        executable census, and (when built with ``trace=True``) per-step
-        Perfetto trace events.  ``None`` resolves to the no-op
+        executable census, and (when it has a span factory) the engine's
+        ``engine.*`` spans.  ``None`` resolves to the no-op
         :data:`~repro.obs.runtime.NULL_OBSERVER`; an enabled observer
         keeps serving token-identical and adds zero device syncs (all
         hooks take host ints — see docs/observability.md).
@@ -269,21 +283,21 @@ class ServingEngine:
         self._pt_device = None
         # -- the executables ----------------------------------------------
         self._prefill_exec: dict[int, callable] = {}    # monolithic only
-        self._prefill_chunk_exec = jax.jit(functools.partial(
-            transformer.prefill_chunk, cfg=cfg, fcfg=fcfg),
+        self._prefill_chunk_exec = jax.jit(
+            _named(transformer.prefill_chunk, cfg=cfg, fcfg=fcfg),
             donate_argnums=2, **self._jit_kw_caches)
         self._decode = jax.jit(
-            functools.partial(transformer.decode_step, cfg=cfg, fcfg=fcfg),
+            _named(transformer.decode_step, cfg=cfg, fcfg=fcfg),
             donate_argnums=2, **self._jit_kw_logits)
         # the speculative path REPLACES decode with one fixed-shape verify
         # executable (batch n_slots, width draft_k+1, per-slot runtime
         # offsets): a zero-draft slot verifies as a 1-valid-token decode,
         # so the census stays at three hot executables either way
         self._verify = jax.jit(
-            functools.partial(transformer.verify_step, cfg=cfg, fcfg=fcfg),
+            _named(transformer.verify_step, cfg=cfg, fcfg=fcfg),
             donate_argnums=2, **self._jit_kw_logits)
-        self._clear = jax.jit(functools.partial(
-            transformer.clear_slot, cfg=cfg, paged=self.paged),
+        self._clear = jax.jit(
+            _named(transformer.clear_slot, cfg=cfg, paged=self.paged),
             donate_argnums=0, **self._jit_kw_caches)
         self._sample = jax.jit(sampling.sample_tokens,
                                static_argnames=("k_cap",))
@@ -408,6 +422,17 @@ class ServingEngine:
         seq = list(req.tokens) + list(req.out)
         n = len(seq)
         assert 1 <= n <= self.max_seq
+        # the span covers the prefix lookup, page growth and the bind (and
+        # any launch the admission makes)
+        with self.obs.span("engine.admit", rid=req.rid, slot=slot,
+                           n_tokens=n) as sp:
+            sp.set_metadata(cached=self._admit(req, slot, seq))
+        return slot
+
+    def _admit(self, req: Request, slot: int, seq: list) -> int:
+        """:meth:`add_request`'s work; returns the tokens the prefix cache
+        served."""
+        n = len(seq)
         n_cached = 0
         if self.paged:
             if self.prefix_cache_active:
@@ -430,8 +455,11 @@ class ServingEngine:
                 raise
         state = self.sched.bind(slot, req, n, cached=n_cached)
         self._slot_seq[slot] = seq
+        now = _clock()
         if req.t_submit is None:
-            req.t_submit = _clock()
+            req.t_submit = now
+        if req.t_admit is None:
+            req.t_admit = now
         if not self.chunked and state == PREFILL:
             m = n - 1
             plen = min(next_pow2(m), self.max_seq) if self.bucketed else m
@@ -456,7 +484,7 @@ class ServingEngine:
             self.last_token[slot] = seq[-1]
         else:
             self.cache_len[slot] = n_cached
-        return slot
+        return n_cached
 
     # -- preemption / page growth ---------------------------------------------
     def _preempt(self, slot: int) -> None:
@@ -515,39 +543,47 @@ class ServingEngine:
         """Execute one scheduler plan: budgeted prefill chunks, then one
         batched decode across the decoding slots.  Returns the requests
         that finished (or, paged mode, failed) this step."""
-        finished = []
-        self.obs.on_step(
-            queue_depth=len(self.sched.resume) + len(self.sched.pending),
-            occupied=len(self.sched.occupied()))
-        plan = self.sched.plan()
-        # --- prefill chunks (fixed shape; one executable) -------------------
-        if plan.chunks:
-            pt = self._page_table() if self.paged else None
-            for ch in plan.chunks:
+        with self.obs.span("engine.step", step_num=self.sched.step_count):
+            finished = []
+            self.obs.on_step(
+                queue_depth=len(self.sched.resume) + len(self.sched.pending),
+                occupied=len(self.sched.occupied()))
+            plan = self.sched.plan()
+            if plan.chunks:
+                self._prefill_chunks(plan.chunks)
+            active = list(plan.decode_slots)
+            if self.speculative_active:
+                self._decode_speculative(active, finished)
+            else:
+                self._decode_plain(active, finished)
+            self.sched.tick()
+        return finished
+
+    def _prefill_chunks(self, chunks: list) -> None:
+        """The plan's prefill chunks (fixed shape; one executable)."""
+        pt = self._page_table() if self.paged else None
+        for ch in chunks:
+            with self.obs.phase("prefill_chunk", slot=ch.slot,
+                                rid=self.sched.slots[ch.slot].req.rid,
+                                start=ch.start, n=ch.n):
                 seq = self._slot_seq[ch.slot]
                 toks = np.zeros((1, self.chunk), np.int32)
                 toks[0, :ch.n] = seq[ch.start:ch.start + ch.n]
                 kw = {"page_table": pt} if self.paged else {}
-                with self.obs.phase("prefill_chunk", slot=ch.slot,
-                                    rid=self.sched.slots[ch.slot].req.rid,
-                                    start=ch.start, n=ch.n):
-                    self.caches = self._prefill_chunk_exec(
-                        self.params, jnp.asarray(toks), self.caches,
-                        jnp.int32(ch.slot), jnp.int32(ch.start),
-                        jnp.int32(ch.n), **kw)
-                self.cache_len[ch.slot] = ch.start + ch.n
-                if self.sched.on_chunk(ch.slot, ch.n):
-                    # prefill complete: decode restarts at the last token,
-                    # whose K/V entry is then written exactly once at n-1
-                    self.last_token[ch.slot] = seq[-1]
-        # --- decode ----------------------------------------------------------
-        active = list(plan.decode_slots)
-        if self.speculative_active:
-            self._decode_speculative(active, finished)
-        else:
-            self._decode_plain(active, finished)
-        self.sched.tick()
-        return finished
+                self.caches = self._prefill_chunk_exec(
+                    self.params, jnp.asarray(toks), self.caches,
+                    jnp.int32(ch.slot), jnp.int32(ch.start),
+                    jnp.int32(ch.n), **kw)
+            self.cache_len[ch.slot] = ch.start + ch.n
+            if self.sched.on_chunk(ch.slot, ch.n):
+                # prefill complete: decode restarts at the last token,
+                # whose K/V entry is then written exactly once at n-1
+                self.last_token[ch.slot] = seq[-1]
+
+    def _ctx_tokens(self, active: list) -> int:
+        """Cache entries the decoding slots attend over, summed (each
+        slot's cache plus the token it decodes)."""
+        return int(self.cache_len[active].sum()) + len(active)
 
     def _sampling_operands(self, active):
         """Per-slot sampling operands (host numpy, materialized once)."""
@@ -593,15 +629,19 @@ class ServingEngine:
             self._failed.clear()
         if not active:
             return
-        act = np.zeros((self.n_slots,), bool)
-        act[active] = True
-        act_dev = jnp.asarray(act)
-        kw = {"page_table": self._page_table()} if self.paged else {}
         # host numpy slot state is materialized on device here, once per
         # launch, as plain operands of the (warm) decode executable.  The
-        # observer phase wraps dispatch AND the step's one device->host
-        # sync, so the span is the true host-observed decode latency.
-        with self.obs.phase("decode", slots=len(active)):
+        # observer phase wraps the operand upload, dispatch AND the step's
+        # one device->host sync, so the span is the true host-observed
+        # decode latency; it opens only for a launch, after page growth
+        # settled the slots that take part.
+        with self.obs.phase("decode", slots=len(active)) as sp:
+            if self.obs.spans_on:
+                sp.set_metadata(ctx_tokens=self._ctx_tokens(active))
+            act = np.zeros((self.n_slots,), bool)
+            act[active] = True
+            act_dev = jnp.asarray(act)
+            kw = {"page_table": self._page_table()} if self.paged else {}
             logits, self.caches = self._decode(self.params,
                                                jnp.asarray(self.last_token),
                                                self.caches,
@@ -620,18 +660,23 @@ class ServingEngine:
             else:  # all-greedy step (the default): skip the sampler's
                 # top-k threshold + Gumbel draw on the hot path
                 next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            toks = np.asarray(next_tok)   # the step's ONE device->host sync
-        self.cache_len[act] += 1
-        self.last_token[act] = toks[act]
-        self.obs.on_tokens(len(active))
-        now = _clock()
-        for i in active:
-            req = self.sched.slots[i].req
-            req.out.append(int(toks[i]))
-            if req.t_first is None:
-                req.t_first = now
-            self.sched.on_decode_token(i)
-            self._maybe_retire(i, req, now, finished)
+            self.obs.on_decode_launch()
+            with self.obs.span("engine.sync"):
+                toks = np.asarray(next_tok)   # the step's ONE host sync
+        with self.obs.span("engine.retire") as sp:
+            n0 = len(finished)
+            self.cache_len[act] += 1
+            self.last_token[act] = toks[act]
+            self.obs.on_tokens(len(active))
+            now = _clock()
+            for i in active:
+                req = self.sched.slots[i].req
+                req.out.append(int(toks[i]))
+                if req.t_first is None:
+                    req.t_first = now
+                self.sched.on_decode_token(i)
+                self._maybe_retire(i, req, now, finished)
+            sp.set_metadata(n_finished=len(finished) - n0)
 
     # -- speculative decode ---------------------------------------------------
     def _draft_for(self, i: int) -> list:
@@ -690,7 +735,10 @@ class ServingEngine:
                 toks[i, 1:1 + len(d)] = d
         kw = {"page_table": self._page_table()} if self.paged else {}
         with self.obs.phase("verify", slots=len(active),
-                            drafted=sum(len(d) for d in drafts.values())):
+                            drafted=sum(len(d) for d in drafts.values())
+                            ) as sp:
+            if self.obs.spans_on:
+                sp.set_metadata(ctx_tokens=self._ctx_tokens(active))
             logits, self.caches = self._verify(self.params,
                                                jnp.asarray(toks),
                                                self.caches,
@@ -705,7 +753,18 @@ class ServingEngine:
                                            jnp.asarray(idxs), k_cap=k_cap)
             else:
                 cand = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            cand = np.asarray(cand)       # (n_slots, W); the ONE host sync
+            self.obs.on_decode_launch()
+            with self.obs.span("engine.sync"):
+                cand = np.asarray(cand)   # (n_slots, W); the ONE host sync
+        with self.obs.span("engine.retire") as sp:
+            n0 = len(finished)
+            self._accept_drafts(active, drafts, cand, finished)
+            sp.set_metadata(n_finished=len(finished) - n0)
+
+    def _accept_drafts(self, active: list, drafts: dict, cand,
+                       finished: list) -> None:
+        """Each slot's longest verified draft prefix plus the model's
+        bonus/correction token; the rest rolled back by bookkeeping."""
         now = _clock()
         self.spec_steps += 1
         self.obs.on_spec_step()

@@ -26,10 +26,14 @@ import jax.numpy as jnp
 
 
 def fold_seed(seed: int) -> int:
-    """Fold an arbitrary non-negative python int into uint32 range
-    (xor-fold of the high bits — the identity for seeds < 2^32, so
-    existing seeded requests keep their exact token streams)."""
+    """Fold an arbitrary python int into uint32 range (xor-fold of the
+    high bits — the identity for seeds in [0, 2^32), so existing seeded
+    requests keep their exact token streams).  A negative seed folds to
+    the complement of ``-seed - 1``'s fold: its 32-bit two's complement
+    down to -2^32."""
     s = int(seed)
+    if s < 0:
+        return ~fold_seed(~s) & 0xFFFFFFFF
     while s >> 32:
         s = (s >> 32) ^ (s & 0xFFFFFFFF)
     return s

@@ -1,27 +1,29 @@
-"""Observability subsystem: metrics registry, tracer, and the Observer
+"""Observability subsystem: metrics registry, spans, and the Observer
 seam through the serving engine.
 
-Four layers of gate:
+Five layers of gate:
 
   * **Instruments** — the log-bucket histogram's quantiles against a
     numpy reference (within the documented ~12% bucket resolution),
     counter/gauge/label plumbing, and a golden Prometheus text
     exposition checked byte-for-byte plus through the format validator
     (which itself is tested against deliberately malformed dumps).
-  * **Tracer** — Chrome/Perfetto ``trace_event`` schema validity,
-    balanced begin/end nesting, slot/rid attribution on instants, and
-    bounded-buffer overflow accounting.
+  * **Spans** — the engine's ``engine.*`` spans through an injected
+    factory: names, nesting and arguments, and on a real
+    ``jax.profiler`` trace of a CPU run (none without a factory).
   * **Observer-through-engine** — observer-on output token-identical to
     observer-off (observability must never change scheduling or
     sampling), metric coverage on real runs: prefix-cache warm hits,
     speculation counters agreeing with the engine's own ledger, census
-    export, TTFT/TPOT sample counts matching retirements.
+    export, TTFT and its two parts matching retirements.
+  * **Named executables** — the engine's jitted steps lower to
+    ``jit_<function>`` modules, as the profiler names their launches.
   * **Clock unification** — the serving stack has exactly ONE
     ``time.*`` call site (``repro.obs.trace.now``), and every
     ``Request.t_*`` mark falls inside a ``now()``-bracketed run.
 """
 import functools
-import json
+import glob
 import math
 import os
 
@@ -33,11 +35,13 @@ import pytest
 from repro.analysis.retrace_guard import census, retrace_guard
 from repro.configs.base import get_config, shrink
 from repro.core.famous import FamousConfig
+from repro.launch.serve import profiler_span
 from repro.models import module, transformer
 from repro.obs.metrics import (LOG_BUCKETS, Histogram, MetricsRegistry,
                                log_buckets, validate_prometheus_text)
-from repro.obs.runtime import NULL_OBSERVER, NullObserver, Observer
-from repro.obs.trace import Tracer, now
+from repro.obs.runtime import (NULL_OBSERVER, NULL_SPAN, NullObserver,
+                               Observer)
+from repro.obs.trace import now
 from repro.serve.engine import Request, ServingEngine
 
 MAX_SEQ = 32
@@ -211,74 +215,64 @@ def test_registry_idempotent_and_schema_checked():
 
 
 # ---------------------------------------------------------------------------
-# tracer: schema, nesting, attribution, bounded buffer
-# ---------------------------------------------------------------------------
-
-
-def test_tracer_schema_and_nesting():
-    tr = Tracer()
-    tr.begin("decode", step=1, slots=2)
-    tr.instant("admit", rid=7, slot=1)
-    assert not tr.balanced
-    tr.end("decode", step=1)
-    assert tr.balanced
-    doc = json.loads(json.dumps(tr.to_json()))   # JSON-serialisable
-    evs = doc["traceEvents"]
-    assert [e["ph"] for e in evs] == ["B", "i", "E"]
-    for e in evs:
-        assert set(e) >= {"name", "ph", "ts", "pid", "tid", "args"}
-        assert isinstance(e["ts"], float) and e["ts"] >= 0.0
-    assert evs[0]["ts"] <= evs[1]["ts"] <= evs[2]["ts"]
-    assert evs[1]["args"] == {"rid": 7, "slot": 1}   # attribution survives
-    assert evs[1]["s"] == "t"
-    assert doc["otherData"]["dropped"] == 0
-
-
-def test_tracer_bounded_buffer_drops_and_counts():
-    tr = Tracer(limit=3)
-    for i in range(5):
-        tr.instant("x", i=i)
-    assert len(tr.events) == 3 and tr.dropped == 2
-    assert tr.to_json()["otherData"]["dropped"] == 2
-
-
-def test_tracer_write(tmp_path):
-    tr = Tracer()
-    with_observer = Observer(trace=True)
-    assert with_observer.tracer is not None
-    tr.begin("p")
-    tr.end("p")
-    path = tmp_path / "trace.json"
-    tr.write(str(path))
-    assert len(json.loads(path.read_text())["traceEvents"]) == 2
-
-
-# ---------------------------------------------------------------------------
 # observer through the engine
 # ---------------------------------------------------------------------------
 
 
+class SpanLog:
+    """A span factory that keeps each span's name, arguments (those set
+    at its end too), parent and order, as a profiler would."""
+
+    def __init__(self):
+        self.spans = []
+        self._open = []
+
+    def __call__(self, name, **args):
+        return _Span(self, name, args)
+
+
+class _Span:
+    def __init__(self, log, name, args):
+        self.log, self.rec = log, {"name": name, "args": dict(args)}
+
+    def __enter__(self):
+        log = self.log
+        self.rec["parent"] = log.spans[log._open[-1]] if log._open else None
+        log._open.append(len(log.spans))
+        log.spans.append(self.rec)
+        return self
+
+    def set_metadata(self, **args):
+        self.rec["args"].update(args)
+
+    def __exit__(self, *exc):
+        self.log._open.pop()
+        self.rec["closed"] = True
+
+
 @functools.lru_cache(maxsize=None)
 def _paired_runs():
-    """One observer-off and one observer-on engine over the same
-    workload; cached so every assertion below shares the two runs."""
+    """One observer-off and one observer-on (metrics and spans) engine
+    over the same workload; cached so every assertion below shares the
+    two runs."""
     cfg, _ = _cfg_params()
-    obs = Observer(trace=True)
+    spans = SpanLog()
+    obs = Observer(span=spans)
     off = _engine(observer=None, cache_kind="paged", page_size=8)
     on = _engine(observer=obs, cache_kind="paged", page_size=8)
     done_off = sorted(off.run(_requests(cfg)), key=lambda r: r.rid)
     done_on = sorted(on.run(_requests(cfg)), key=lambda r: r.rid)
-    return done_off, done_on, obs, on
+    return done_off, done_on, obs, on, spans
 
 
 def test_observer_on_is_token_identical_to_off():
-    done_off, done_on, _, _ = _paired_runs()
+    done_off, done_on, _, _, _ = _paired_runs()
     assert [r.out for r in done_on] == [r.out for r in done_off]
     assert [r.error for r in done_on] == [r.error for r in done_off]
 
 
 def test_observer_metric_coverage():
-    _, done_on, obs, eng = _paired_runs()
+    _, done_on, obs, eng, _ = _paired_runs()
     m = obs.metrics
     tok = sum(len(r.out) for r in done_on)
     assert m.get("repro_tokens_generated_total").value() == tok
@@ -291,6 +285,10 @@ def test_observer_metric_coverage():
     assert m.get("repro_request_ttft_seconds").count() == len(done_on)
     assert m.get("repro_step_phase_seconds").count(phase="decode") > 0
     assert m.get("repro_step_phase_seconds").count(phase="prefill_chunk") > 0
+    assert m.get("repro_decode_launches_total").value() > 0
+    # no preemption here: one admission per request, its whole prompt
+    assert m.get("repro_admitted_prompt_tokens_total").value() \
+        == sum(len(r.tokens) for r in done_on)
     # paged engine: pages were grown and freed back
     assert m.get("repro_pages_total").value(op="grow") > 0
     assert m.get("repro_pages_total").value(op="free") > 0
@@ -298,28 +296,246 @@ def test_observer_metric_coverage():
     assert validate_prometheus_text(obs.prometheus_text()) > 100
 
 
-def test_observer_trace_attribution_and_balance():
-    _, done_on, obs, _ = _paired_runs()
-    tr = obs.tracer
-    assert tr.balanced and tr.events
-    names = {e["name"] for e in tr.events}
-    assert {"admit", "retire", "decode", "prefill_chunk"} <= names
-    rids = {e["args"]["rid"] for e in tr.events if e["name"] == "retire"}
-    assert rids == {r.rid for r in done_on}
-    admits = [e for e in tr.events if e["name"] == "admit"]
-    assert all(e["args"]["slot"] in (0, 1) for e in admits)
-    # B/E pairs nest: depth never goes negative, ends at zero
-    depth = 0
-    for e in tr.events:
-        depth += {"B": 1, "E": -1}.get(e["ph"], 0)
-        assert depth >= 0
-    assert depth == 0
-    validate_json = json.dumps(obs.trace_json())
-    assert json.loads(validate_json)["traceEvents"]
+def test_observer_spans_name_the_engine_work():
+    """Every step is one ``engine.step`` (numbered), holding its prefill
+    chunks, its decode (which holds the sync) and the retire after it;
+    admissions run between steps; each span carries its arguments."""
+    _, done_on, obs, _, log = _paired_runs()
+    m = obs.metrics
+    by = {}
+    for sp in log.spans:
+        assert sp["closed"], sp["name"]
+        by.setdefault(sp["name"], []).append(sp)
+    assert set(by) == {"engine.step", "engine.admit", "engine.prefill_chunk",
+                       "engine.decode", "engine.sync", "engine.retire"}
+    steps = by["engine.step"]
+    assert [s["args"] for s in steps] == [{"step_num": k}
+                                          for k in range(len(steps))]
+    assert len(steps) == m.get("repro_engine_steps_total").value()
+    assert all(s["parent"] is None for s in steps + by["engine.admit"])
+    for name in ("engine.prefill_chunk", "engine.decode", "engine.retire"):
+        assert all(s["parent"]["name"] == "engine.step" for s in by[name])
+    assert all(s["parent"]["name"] == "engine.decode"
+               for s in by["engine.sync"])
+    assert len(by["engine.sync"]) == len(by["engine.decode"]) \
+        == m.get("repro_decode_launches_total").value()
+    admits = [s["args"] for s in by["engine.admit"]]
+    assert sorted(a["rid"] for a in admits) == [r.rid for r in done_on]
+    assert all(set(a) == {"rid", "slot", "n_tokens", "cached"}
+               and a["slot"] in (0, 1) for a in admits)
+    chunks = [s["args"] for s in by["engine.prefill_chunk"]]
+    assert all(set(a) == {"slot", "rid", "start", "n"} for a in chunks)
+    assert sum(a["n"] for a in chunks) \
+        == m.get("repro_prefill_tokens_total").value()
+    decodes = [s["args"] for s in by["engine.decode"]]
+    assert sum(a["slots"] for a in decodes) \
+        == m.get("repro_tokens_generated_total").value()
+    assert all(a["ctx_tokens"] >= a["slots"] >= 1 for a in decodes)
+    assert sum(s["args"]["n_finished"] for s in by["engine.retire"]) \
+        == len(done_on)
+
+
+def _profiled(observer, tmp_path):
+    """Serve a few requests under a ``jax.profiler`` trace; the host
+    plane's ``engine.*`` events as (name, start, end, stats)."""
+    from jax.profiler import ProfileData
+    cfg, _ = _cfg_params()
+    eng = _engine(observer=observer, cache_kind="paged", page_size=8)
+    eng.run(_requests(cfg, n=2, seed=3))           # compile outside it
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        done = eng.run(_requests(cfg, n=4, seed=4, rid0=10))
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    evs = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+            dict(ev.stats))
+           for plane in ProfileData.from_file(path).planes
+           if plane.name.startswith("/host:")
+           for line in plane.lines for ev in line.events
+           if ev.name.startswith("engine.")]
+    return evs, done
+
+
+def test_profiled_run_holds_the_engine_spans(tmp_path):
+    evs, done = _profiled(Observer(span=profiler_span), tmp_path)
+    steps = sorted((s, e, st) for n, s, e, st in evs if n == "engine.step")
+    assert [st["step_num"] for _, _, st in steps] == \
+        list(range(steps[0][2]["step_num"],
+                   steps[0][2]["step_num"] + len(steps)))
+
+    def parent(s, e, names):
+        return [n for n, s0, e0, _ in evs
+                if n in names and s0 <= s and e <= e0 and (s0, e0) != (s, e)]
+
+    seen = set()
+    for n, s, e, st in evs:
+        seen.add(n)
+        if n in ("engine.prefill_chunk", "engine.decode", "engine.retire"):
+            assert parent(s, e, ("engine.step",)), n
+        elif n == "engine.sync":
+            assert parent(s, e, ("engine.decode",))
+        elif n == "engine.admit":
+            assert not parent(s, e, ("engine.step",))
+            assert set(st) >= {"rid", "slot", "n_tokens", "cached"}
+        if n == "engine.prefill_chunk":
+            assert set(st) >= {"slot", "rid", "start", "n"}
+        if n == "engine.decode":
+            assert st["slots"] >= 1 and st["ctx_tokens"] >= st["slots"]
+        if n == "engine.retire":
+            assert "n_finished" in st
+    assert seen == {"engine.step", "engine.admit", "engine.prefill_chunk",
+                    "engine.decode", "engine.sync", "engine.retire"}
+    admitted = {st["rid"] for n, _, _, st in evs if n == "engine.admit"}
+    assert admitted == {r.rid for r in done}
+
+
+@pytest.mark.parametrize("observer", [None, Observer()],
+                         ids=["no_observer", "metrics_only"])
+def test_profiled_run_without_a_span_factory_holds_no_span(observer,
+                                                           tmp_path):
+    evs, done = _profiled(observer, tmp_path)
+    assert done and evs == []
+
+
+def test_request_marks_split_ttft():
+    """Queue wait (submit -> first admission) and admission -> first
+    token add up to TTFT, per request and in the histograms' sums."""
+    cfg, _ = _cfg_params()
+    obs = Observer()
+    eng = _engine(observer=obs)
+    done = eng.run(_requests(cfg, n=6, seed=31))   # 6 requests, 2 slots
+    for r in done:
+        assert r.t_submit <= r.t_admit <= r.t_first, r.rid
+        assert (r.t_admit - r.t_submit) + (r.t_first - r.t_admit) \
+            == pytest.approx(r.t_first - r.t_submit, abs=1e-12)
+    assert max(r.t_admit - r.t_submit for r in done) > 0   # some queued
+    m = obs.metrics
+    ttft = m.get("repro_request_ttft_seconds")
+    wait = m.get("repro_request_queue_wait_seconds")
+    a2f = m.get("repro_request_admit_to_first_seconds")
+    assert ttft.count() == wait.count() == a2f.count() == len(done)
+    assert wait.sum() + a2f.sum() == pytest.approx(ttft.sum(), rel=1e-9)
+
+
+def test_preempted_request_keeps_its_first_admission():
+    """A sequence preempted mid-generation is admitted again: ``t_admit``
+    keeps the first admission, the ledger's ``admit_step`` moves to the
+    latest, and every admission's tokens are counted."""
+    cfg, _ = _cfg_params()
+    obs = Observer()
+    # 5 allocatable pages of 4: both 7-token prompts admit, decode growth
+    # then forces the younger out
+    eng = _engine(observer=obs, cache_kind="paged", page_size=4, n_pages=6)
+    marks, sizes = {}, []
+    add = eng.add_request
+
+    def spy(req):
+        sizes.append(len(req.tokens) + len(req.out))
+        slot = add(req)
+        marks.setdefault(req.rid, []).append((req.t_admit,
+                                               eng.sched.step_count))
+        return slot
+
+    eng.add_request = spy
+    rng = np.random.default_rng(5)
+    reqs = [Request(rid=i, tokens=list(rng.integers(0, cfg.vocab_size, 7)),
+                    max_new=8) for i in range(2)]
+    done = eng.run(reqs)
+    assert obs.metrics.get("repro_preemptions_total").value() >= 1
+    again = [rid for rid, m in marks.items() if len(m) > 1]
+    assert again
+    for rid in again:
+        (t_first_admit, step0), *later = marks[rid]
+        assert all(t == t_first_admit for t, _ in later)
+        f = eng.sched.fairness(rid)
+        assert f["admit_step"] == later[-1][1] > step0
+    for r in done:
+        assert r.t_submit <= r.t_admit <= r.t_first
+    assert obs.metrics.get("repro_admitted_prompt_tokens_total").value() \
+        == sum(sizes)
+
+
+def test_decode_span_holds_the_launched_slots_under_preemption():
+    """Page growth runs before the decode span opens: a slot preempted
+    by it is not counted in ``slots``, and there is one span (and one
+    phase sample) per launch."""
+    cfg, _ = _cfg_params()
+    log = SpanLog()
+    obs = Observer(span=log)
+    eng = _engine(observer=obs, cache_kind="paged", page_size=4, n_pages=6)
+    rng = np.random.default_rng(5)
+    eng.run([Request(rid=i, tokens=list(rng.integers(0, cfg.vocab_size, 7)),
+                     max_new=8) for i in range(2)])
+    m = obs.metrics
+    assert m.get("repro_preemptions_total").value() >= 1
+    decodes = [s["args"] for s in log.spans if s["name"] == "engine.decode"]
+    assert len(decodes) == m.get("repro_decode_launches_total").value() \
+        == m.get("repro_step_phase_seconds").count(phase="decode")
+    assert sum(a["slots"] for a in decodes) \
+        == m.get("repro_tokens_generated_total").value()
+    assert all(a["ctx_tokens"] >= a["slots"] >= 1 for a in decodes)
+
+
+@pytest.mark.parametrize("metrics", [False, True],
+                         ids=["no_observer", "metrics_only"])
+def test_costly_span_args_wait_for_a_span_factory(metrics):
+    """Without a span factory the engine computes no span argument that
+    costs work (``ctx_tokens``), on the plain and the speculative path."""
+    cfg, _ = _cfg_params()
+    for kw in ({}, {"speculative": True, "draft_k": 2}):
+        eng = _engine(observer=Observer() if metrics else None,
+                      cache_kind="paged", page_size=8, **kw)
+        assert eng.speculative_active == bool(kw)
+
+        def refuse(active):
+            raise AssertionError("ctx_tokens computed with spans off")
+
+        eng._ctx_tokens = refuse
+        assert eng.run(_requests(cfg, n=3, seed=6))
+
+
+@functools.lru_cache(maxsize=None)
+def _lowered_modules():
+    """The module name each engine executable lowers to, at the
+    arguments of its first call."""
+    cfg, _ = _cfg_params()
+    names = {}
+
+    def spy(eng, attr):
+        fn = getattr(eng, attr)
+
+        def call(*a, **k):
+            names.setdefault(attr, fn.lower(*a, **k).as_text().split()[1])
+            return fn(*a, **k)
+
+        setattr(eng, attr, call)
+
+    plain = _engine(cache_kind="paged", page_size=8)
+    spec = _engine(speculative=True, draft_k=2)
+    for attr in ("_prefill_chunk_exec", "_decode", "_clear", "_sample"):
+        spy(plain, attr)
+    spy(spec, "_verify")
+    reqs = _requests(cfg, n=2, seed=41)
+    reqs[0].temperature = 1.0                   # the sampler
+    reqs.append(Request(rid=9, tokens=[5], max_new=2))   # nothing to prefill
+    plain.run(reqs)
+    spec.run(_requests(cfg, n=2, seed=42))
+    return names
+
+
+@pytest.mark.parametrize("attr,module_name", [
+    ("_prefill_chunk_exec", "@jit_prefill_chunk"),
+    ("_decode", "@jit_decode_step"),
+    ("_verify", "@jit_verify_step"),
+    ("_clear", "@jit_clear_slot"),
+    ("_sample", "@jit_sample_tokens"),
+])
+def test_engine_executables_lower_under_their_names(attr, module_name):
+    assert _lowered_modules()[attr] == module_name
 
 
 def test_observer_census_and_retrace_guard_sources():
-    _, _, obs, eng = _paired_runs()
+    _, _, obs, eng, _ = _paired_runs()
     assert obs.census() == {k: int(v) for k, v in eng.compilations.items()}
     # retrace_guard reads the census through the Observer...
     assert census(obs) == census(eng)
@@ -366,6 +582,7 @@ def test_observer_speculation_counters_match_engine_ledger():
     eng.run(reqs)
     m = obs.metrics
     assert m.get("repro_spec_verify_steps_total").value() == eng.spec_steps
+    assert m.get("repro_decode_launches_total").value() == eng.spec_steps
     assert m.get("repro_spec_drafted_total").value() == eng.spec_drafted
     assert m.get("repro_spec_accepted_total").value() == eng.spec_accepted
     assert eng.spec_drafted > 0
@@ -380,14 +597,42 @@ def test_observer_speculation_counters_match_engine_ledger():
 def test_null_observer_is_inert_and_complete():
     # NullObserver mirrors every public hook of Observer (a new hook
     # must be added to both or engines crash with observer=None)
-    hooks = [n for n in dir(Observer) if n.startswith(("on_", "phase"))]
+    hooks = [n for n in dir(Observer) if n.startswith(("on_", "phase",
+                                                        "span"))]
     for n in hooks:
         assert callable(getattr(NullObserver, n, None)), n
     NULL_OBSERVER.on_step(queue_depth=1, occupied=2)
     NULL_OBSERVER.on_tokens(5)
-    with NULL_OBSERVER.phase("decode", slots=1):
-        pass
+    # off, or no span factory: every span is the one shared null span
+    assert NULL_OBSERVER.phase("decode", slots=1) is NULL_SPAN
+    for obs in (NULL_OBSERVER, Observer()):
+        assert obs.span("engine.sync", n=1) is NULL_SPAN
+        with obs.phase("decode", slots=1) as sp:
+            assert sp is NULL_SPAN
+            sp.set_metadata(n=1)
     assert NULL_OBSERVER.census() == {}
+
+
+def test_observer_opens_spans_through_its_factory():
+    log = SpanLog()
+    obs = Observer(span=log)
+    with obs.span("engine.step", step_num=7):
+        with obs.phase("decode", slots=2) as sp:
+            with obs.span("engine.sync"):
+                pass
+            sp.set_metadata(ctx_tokens=9)
+    assert [(s["name"], s["args"]) for s in log.spans] == [
+        ("engine.step", {"step_num": 7}),
+        ("engine.decode", {"slots": 2, "ctx_tokens": 9}),
+        ("engine.sync", {})]
+    assert obs.metrics.get("repro_step_phase_seconds").count(
+        phase="decode") == 1
+
+
+def test_profiler_span_marks_the_step():
+    assert type(profiler_span("engine.step", step_num=1)) is \
+        jax.profiler.StepTraceAnnotation
+    assert type(profiler_span("engine.sync")) is jax.profiler.TraceAnnotation
 
 
 # ---------------------------------------------------------------------------

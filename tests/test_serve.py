@@ -266,6 +266,27 @@ def test_huge_rid_seed_fallback():
     assert done[0].out != done[1].out    # distinct rids -> distinct noise
 
 
+def test_negative_seed_folds_into_uint32():
+    """A negative request id or seed folds to its 32-bit two's complement
+    (down to -2^32) or, beyond that, into range; seeds >= 0 keep their
+    exact streams."""
+    from repro.serve.sampling import fold_seed
+    for s in (0, 1, 2**31, 2**32 - 1):
+        assert fold_seed(s) == s
+    assert fold_seed(-1) == 2**32 - 1 and fold_seed(-5) == 2**32 - 5
+    assert fold_seed(-2**32) == 0
+    for s in (-2**32 - 1, -2**40 - 3, -2**70):
+        assert 0 <= fold_seed(s) < 2**32
+    assert fold_seed(-2**40 - 3) != fold_seed(-2**41 - 3)
+    cfg = shrink(get_config("qwen2-7b"))
+    engine = ServingEngine(_params(cfg), cfg, FCFG, n_slots=2, max_seq=64)
+    done = sorted(engine.run(
+        [Request(rid=-1 - i, tokens=[3, 1, 4, 1, 5], max_new=8,
+                 temperature=2.0) for i in (0, 1)]), key=lambda r: r.rid)
+    assert all(r.error is None and len(r.out) == 8 for r in done)
+    assert done[0].out != done[1].out    # distinct rids -> distinct noise
+
+
 def test_run_max_steps_surfaces_every_request():
     """Exhausting max_steps must return EVERY request — slot-bound
     mid-flight, preempted, and never-admitted alike — with req.error set
