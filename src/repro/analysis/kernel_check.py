@@ -105,6 +105,14 @@ def _mem_space_name(obj) -> str:
     return "" if ms is None else str(getattr(ms, "value", ms)).lower()
 
 
+def _in_vmem(obj) -> bool:
+    """Whether a spec or scratch shape lives in VMEM: VMEM itself or no
+    stated space (the default).  SMEM scalars, semaphores and operands left
+    in ANY (HBM, copied by the kernel's own DMAs) take none."""
+    name = _mem_space_name(obj)
+    return not name or "vmem" in name
+
+
 def _kernel_name(kernel) -> str:
     if isinstance(kernel, functools.partial):
         kernel = kernel.func
@@ -304,13 +312,13 @@ def check_launch(*, name, grid, in_specs, out_specs, out_shape,
         _check_spec(errors, role=role, i=i, spec=spec, shape=tuple(shape),
                     grid=grid, probes=probes, exhaustive_pts=True,
                     require_coverage=is_out)
-        if "smem" not in _mem_space_name(spec):
+        if _in_vmem(spec):
             # input/output blocks are double-buffered by the Pallas
             # pipeline: the live footprint is 2x the block
             vmem += 2 * _block_bytes(getattr(spec, "block_shape", None),
                                      shape, dtype)
     for sc in scratch_shapes or ():
-        if "vmem" in _mem_space_name(sc) or not _mem_space_name(sc):
+        if _in_vmem(sc):
             vmem += _block_bytes(None, getattr(sc, "shape", ()),
                                  getattr(sc, "dtype", np.float32))
 

@@ -13,13 +13,17 @@ Two cache layouts share the online-softmax inner loop:
 
   * ``decode_attention``       — contiguous (BKV, Skv, dh) per-slot caches.
   * ``paged_decode_attention`` — a shared kv-head-major
-    (KV, n_pages, page_size, dh) page pool; a scalar-prefetched per-slot
-    page table drives the K/V BlockSpec index_map, so each key tile is
-    DMA'd straight from its page (no gather materialisation), the TPU
-    analogue of FAMOUS's banked-BRAM tiling.  kv-head-major keeps every
-    block's last two dims a whole (page_size, dh) page, the tiling Mosaic
-    requires; int8 pools carry (KV, n_pages, 1, page_size) scale rows for
-    the same reason.
+    (KV, n_pages, page_size, dh) page pool left in HBM; a scalar-prefetched
+    per-slot page table aims the kernel's own page DMAs (no gather
+    materialisation), the TPU analogue of FAMOUS's banked-BRAM tiling.
+    Grid (B, n_blocks): a step copies one block of ``pages_per_block``
+    pages of a slot, every kv head of a page in one strided DMA, into a
+    double-buffered VMEM buffer, and runs each kv head's (group, block)
+    score tile and PV matmul.  Blocks at or past the slot's length issue
+    no copy and no compute, so the work follows the live cache, not
+    ``max_seq``.  kv-head-major keeps each page a whole (page_size, dh)
+    tile per head; int8 pools carry (KV, n_pages, 1, page_size) scale
+    rows, gathered per slot before the kernel.
 """
 from __future__ import annotations
 
@@ -82,46 +86,124 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
                          / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
-def _paged_decode_kernel(len_ref, pt_ref, q_ref, k_ref, v_ref, *refs,
-                         scale: float, page_size: int, n_p: int,
+# One grid step of the paged decode kernel holds one block of one slot: at
+# most this many tokens, and at most this many bytes of K across the kv
+# heads (as many again of V).  A page is copied for every kv head at once,
+# one strided DMA (4 KiB per head at page 16, dh 128, bf16): copies of one
+# head's page alone are too small to reach HBM bandwidth.  1 MiB is 512
+# tokens of qwen2-7b (4 kv heads) and 128 of deepseek-7b (32); K and V,
+# double-buffered, then hold 4 MiB of VMEM.
+BLOCK_TOKENS = 512
+BLOCK_BYTES = 1024 * 1024
+
+
+def pages_per_block(n_p: int, page_size: int, n_kv: int, dh: int,
+                    dtype) -> int:
+    """Pages one grid step of the paged decode kernel walks: as many as
+    fill :data:`BLOCK_TOKENS` tokens and :data:`BLOCK_BYTES` of K over the
+    ``n_kv`` heads, and no more than the ``n_p`` pages of a slot's table."""
+    page_bytes = n_kv * page_size * dh * jnp.dtype(dtype).itemsize
+    return max(1, min(n_p, BLOCK_TOKENS // page_size,
+                      BLOCK_BYTES // page_bytes))
+
+
+def _live_blocks(length, block_tokens: int, n_blocks):
+    """Blocks a slot of ``length`` tokens walks: at least one, so that
+    every slot writes its output (a dead slot's length is 1)."""
+    return jnp.clip((length + block_tokens - 1) // block_tokens, 1, n_blocks)
+
+
+def _paged_decode_kernel(len_ref, pt_ref, q_ref, k_hbm, v_hbm, *refs,
+                         scale: float, page_size: int, ppb: int, n_p: int,
                          quantized: bool):
+    """One grid step (b, j): block j of slot b — pages ``[j*ppb,
+    (j+1)*ppb)`` of its table, every kv head — copied page by page from
+    the HBM pools into one of two VMEM buffers, then folded into each kv
+    head's running softmax.  Blocks at or past the slot's length are
+    skipped whole; each live step starts the copies of the next live block
+    (of this slot or the next) into the other buffer before it waits on
+    its own."""
     if quantized:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    else:
-        o_ref, acc_ref, m_ref, l_ref = refs
-    b = pl.program_id(0)
-    ip = pl.program_id(2)
+        ks_ref, vs_ref, *refs = refs
+    o_ref, acc_ref, m_ref, l_ref, k_buf, v_buf, sem_ref, slot_ref = refs
+    b, j = pl.program_id(0), pl.program_id(1)
+    n_kv, dh = q_ref.shape[1], q_ref.shape[3]
+    block_tokens = ppb * page_size
 
-    @pl.when(ip == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def page_copies(row, blk, slot, method):
+        """Start (or wait on) the copies of block ``blk``'s live pages."""
+        first = blk * ppb
+        live = jnp.minimum(pl.cdiv(len_ref[row] - first * page_size,
+                                   page_size), n_p - first)
+        for i in range(ppb):
+            @pl.when(i < live)
+            def _():
+                page = pt_ref[row, first + i]
+                for pool, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                    getattr(pc.make_async_copy(pool.at[:, page],
+                                               buf.at[slot, i],
+                                               sem_ref.at[slot]), method)()
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale       # (group, dh)
-    k = k_ref[0, 0].astype(jnp.float32)               # (page_size, dh)
-    v = v_ref[0, 0].astype(jnp.float32)
-    valid_len = len_ref[b]
+    @pl.when((b == 0) & (j == 0))
+    def _first_block():
+        slot_ref[0] = 0
+        page_copies(0, 0, 0, "start")
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (group, ps)
-    pos = ip * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    ok = pos < valid_len
-    if quantized:
-        # dequantize in VMEM: a key's fp32 scale factors out of its dot
-        # product, and a value's folds into its softmax weight — so the
-        # (1, page_size) scale rows multiply the (group, page_size) tiles
-        # and HBM only ever streams the int8 bytes plus one scale row.
-        s = s * ks_ref[0, 0]
-        online_softmax_step(s, ok, v, acc_ref, m_ref, l_ref,
-                             v_scale=vs_ref[0, 0])
-    else:
-        online_softmax_step(s, ok, v, acc_ref, m_ref, l_ref)
+    n_live = _live_blocks(len_ref[b], block_tokens, pl.num_programs(1))
 
-    @pl.when(ip == n_p - 1)
-    def _flush():
-        o_ref[0, 0, ...] = (acc_ref[...]
-                            / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+    @pl.when(j < n_live)
+    def _block():
+        slot = slot_ref[0]
+        in_row = j + 1 < n_live
+        nb = jnp.where(in_row, b, b + 1)
+
+        @pl.when(nb < pl.num_programs(0))
+        def _prefetch():
+            page_copies(nb, jnp.where(in_row, j + 1, 0), 1 - slot, "start")
+
+        slot_ref[0] = 1 - slot
+
+        @pl.when(j == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+
+        page_copies(b, j, slot, "wait")
+        # keys of this block below the length; rows past it were not
+        # refilled and hold stale bits, so V's rows are zeroed as well as
+        # the scores masked (a zero weight times a NaN row is NaN)
+        n_ok = len_ref[b] - j * block_tokens
+        row_ok = jax.lax.broadcasted_iota(
+            jnp.int32, (block_tokens, 1), 0) < n_ok
+        for h in range(n_kv):   # unrolled: a loop of kv heads runs slower
+            q = q_ref[0, h].astype(jnp.float32) * scale     # (group, dh)
+            k = k_buf[slot, :, h].astype(jnp.float32).reshape(block_tokens,
+                                                                dh)
+            v = v_buf[slot, :, h].astype(jnp.float32).reshape(block_tokens,
+                                                                dh)
+            v = jnp.where(row_ok, v, 0.0)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            ok = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) < n_ok
+            state = (acc_ref.at[h], m_ref.at[h], l_ref.at[h])
+            if quantized:
+                # dequantize in VMEM: a key's fp32 scale factors out of its
+                # dot product, and a value's folds into its softmax weight
+                # — so the (1, block_tokens) scale rows multiply the
+                # (group, block) tiles and HBM only ever streams the int8
+                # bytes plus the scale rows.
+                s = s * ks_ref[0, h]
+                online_softmax_step(s, ok, v, *state,
+                                    v_scale=jnp.where(ok[:1], vs_ref[0, h],
+                                                      0.0))
+            else:
+                online_softmax_step(s, ok, v, *state)
+
+        @pl.when(j == n_live - 1)
+        def _flush():
+            o_ref[0] = (acc_ref[...] / jnp.maximum(
+                l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def _paged_decode_call(q, k_pages, v_pages, scales, page_table, cache_len,
@@ -129,38 +211,62 @@ def _paged_decode_call(q, k_pages, v_pages, scales, page_table, cache_len,
     B, KV, group, dh = q.shape
     page_size = k_pages.shape[2]
     n_p = page_table.shape[1]
+    ppb = pages_per_block(n_p, page_size, KV, dh, k_pages.dtype)
+    n_blocks = pl.cdiv(n_p, ppb)
+    block_tokens = ppb * page_size
     scale = scale if scale is not None else 1.0 / math.sqrt(dh)
     kernel = functools.partial(_paged_decode_kernel, scale=float(scale),
-                               page_size=page_size, n_p=n_p,
+                               page_size=page_size, ppb=ppb, n_p=n_p,
                                quantized=bool(scales))
-    page_spec = pl.BlockSpec((1, 1, page_size, dh),
-                             lambda b, g, ip, lens, pt: (g, pt[b, ip], 0, 0))
-    scale_spec = pl.BlockSpec((1, 1, 1, page_size),
-                              lambda b, g, ip, lens, pt: (g, pt[b, ip], 0, 0))
+    slot_spec = pl.BlockSpec((1, KV, group, dh),
+                             lambda b, j, lens, pt: (b, 0, 0, 0))
+    # A page's (1, page_size) scale row is narrower than the 128-lane tile
+    # a DMA moves, so the int8 scales are gathered per slot by XLA, (B, KV,
+    # 1, n_blocks * block_tokens), and reach the kernel as (1, block) rows
+    # through the pipeline; a skipped block keeps the last live block's
+    # index, which the pipeline does not fetch again.
+    scale_spec = pl.BlockSpec(
+        (1, KV, 1, block_tokens),
+        lambda b, j, lens, pt: (b, 0, 0, jnp.minimum(
+            j, _live_blocks(lens[b], block_tokens, n_blocks) - 1)))
+    scale_rows = [_scale_rows(s, page_table, n_blocks * block_tokens)
+                  for s in scales]
     grid_spec = pc.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,           # cache_len, page_table
-        grid=(B, KV, n_p),
-        in_specs=[
-            pl.BlockSpec((1, 1, group, dh),
-                         lambda b, g, ip, lens, pt: (b, g, 0, 0)),
-            page_spec, page_spec] + [scale_spec] * len(scales),
-        out_specs=pl.BlockSpec((1, 1, group, dh),
-                               lambda b, g, ip, lens, pt: (b, g, 0, 0)),
+        grid=(B, n_blocks),
+        in_specs=[slot_spec, pl.BlockSpec(memory_space=pc.ANY),
+                  pl.BlockSpec(memory_space=pc.ANY)]
+        + [scale_spec] * len(scales),
+        out_specs=slot_spec,
         scratch_shapes=[
-            pc.VMEM((group, dh), jnp.float32),
-            pc.VMEM((group, 1), jnp.float32),
-            pc.VMEM((group, 1), jnp.float32),
+            pc.VMEM((KV, group, dh), jnp.float32),
+            pc.VMEM((KV, group, 1), jnp.float32),
+            pc.VMEM((KV, group, 1), jnp.float32),
+            pc.VMEM((2, ppb, KV, page_size, dh), k_pages.dtype),
+            pc.VMEM((2, ppb, KV, page_size, dh), v_pages.dtype),
+            pc.DMA_SEMAPHORES((2,)),
+            pc.SMEM((1,), jnp.int32),    # the buffer the next block uses
         ],
     )
+    # both dims "arbitrary": a step prefetches the next live block across
+    # slots, so the grid must run in order (v5e has one core)
     return pc.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, group, dh), q.dtype),
-        compiler_params=pc.compiler_params("parallel", "parallel",
-                                           "arbitrary"),
+        compiler_params=pc.compiler_params("arbitrary", "arbitrary"),
         interpret=interpret,
-    )(cache_len.astype(jnp.int32), page_table.astype(jnp.int32),
-      q, k_pages, v_pages, *(s.astype(jnp.float32) for s in scales))
+    )(cache_len.astype(jnp.int32), page_table.astype(jnp.int32), q,
+      k_pages, v_pages, *scale_rows)
+
+
+def _scale_rows(scale_pool, page_table, width: int):
+    """(KV, n_pages, 1, page_size) scale pool -> each slot's scales in table
+    order, (B, KV, 1, width) fp32, zero past the table's end."""
+    rows = scale_pool.astype(jnp.float32)[:, page_table, 0]  # (KV, B, n_p, ps)
+    KV, B = rows.shape[:2]
+    rows = rows.reshape(KV, B, -1).transpose(1, 0, 2)[:, :, None]
+    return jnp.pad(rows, ((0, 0), (0, 0), (0, 0), (0, width - rows.shape[-1])))
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, cache_len, *,
@@ -173,10 +279,11 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, cache_len, *,
     Returns (B, KV, group, dh).
 
     The page table and lengths are *scalar-prefetched*: they reach SMEM
-    before the kernel body runs, and the K/V BlockSpec index_maps read
-    ``page_table[b, ip]`` to aim each page DMA — the grid program never
-    changes shape when sequences grow or move, only the prefetched indices
-    do (the paper's µB reprograms addresses, never re-synthesises).
+    before the kernel body runs, and the kernel reads ``page_table[b, p]``
+    to aim each page DMA and ``cache_len[b]`` to stop the walk — the grid
+    program never changes shape when sequences grow or move, only the
+    prefetched indices do (the paper's µB reprograms addresses, never
+    re-synthesises).
     """
     return _paged_decode_call(q, k_pages, v_pages, (), page_table,
                               cache_len, scale, interpret)
@@ -192,10 +299,10 @@ def paged_decode_attention_int8(q, k_pages, v_pages, k_scale, v_scale,
     scale pools: (KV, n_pages, 1, page_size) fp32 per-token-per-kv-head
     scales; page_table: (B, n_p) int32; cache_len: (B,) int32.
 
-    The scale pools ride the same page-table index_map as K/V, so each grid
-    step DMAs one int8 page plus its (1, page_size) scale row and widens
-    to fp32 only in VMEM — HBM traffic per token drops from 4 B/elem to
-    1 B/elem + 4 B/head (FAMOUS's 8-bit fixed-point operands, paged).
+    Each grid step copies the block's int8 pages plus its (1, block)
+    scale rows (gathered per slot in table order) and widens to fp32 only
+    in VMEM — HBM traffic per token drops from 4 B/elem to 1 B/elem +
+    4 B/head (FAMOUS's 8-bit fixed-point operands, paged).
     """
     assert k_pages.dtype == jnp.int8 and v_pages.dtype == jnp.int8
     return _paged_decode_call(q, k_pages, v_pages, (k_scale, v_scale),

@@ -23,9 +23,13 @@ from jax.experimental.pallas import tpu as pltpu
 CompilerParams = pltpu.CompilerParams
 SMEM = pltpu.MemorySpace.SMEM
 ANY = pltpu.MemorySpace.ANY
-# VMEM is both a memory space and (called with (shape, dtype)) a scratch-
-# buffer constructor.
+# VMEM and SMEM are both memory spaces and (called with (shape, dtype))
+# scratch-buffer constructors.
 VMEM = pltpu.VMEM
+# Manual DMA: kernels that place an operand in ANY (HBM) copy the parts they
+# need with ``make_async_copy``, signalling a ``DMA_SEMAPHORES((n,))`` array.
+DMA_SEMAPHORES = pltpu.SemaphoreType.DMA
+make_async_copy = pltpu.make_async_copy
 
 # Scalar-prefetch grid spec: prefetched int32 operands land in SMEM before
 # the kernel runs and are visible to BlockSpec index_maps — the mechanism

@@ -66,6 +66,7 @@ import numpy as np
 from repro.configs.base import ATTN, LOCAL_ATTN, ModelConfig
 from repro.core.famous import FamousConfig
 from repro.core.flexible import next_pow2
+from repro.kernels.decode.decode_attn import pages_per_block
 from repro.models import transformer
 from repro.obs.runtime import NULL_OBSERVER
 from repro.obs.trace import now as _clock
@@ -206,6 +207,10 @@ class ServingEngine:
             self.pcfg = PagedCacheConfig(page_size=page_size, n_pages=n_pages)
             self.alloc = PageAllocator(self.pcfg, n_slots, max_seq,
                                        observer=self.obs)
+            # tokens per block of the paged decode kernel's walk
+            self._kv_block_tokens = page_size * pages_per_block(
+                self.alloc.pages_per_slot, page_size, cfg.num_kv_heads,
+                cfg.head_dim, jnp.int8 if kv_dtype == "int8" else dtype)
             self.caches = transformer.make_caches(
                 cfg, n_slots, max_seq, dtype, cache_kind="paged",
                 page_size=page_size, n_pages=n_pages, kv_dtype=kv_dtype)
@@ -580,10 +585,21 @@ class ServingEngine:
                 # whose K/V entry is then written exactly once at n-1
                 self.last_token[ch.slot] = seq[-1]
 
-    def _ctx_tokens(self, active: list) -> int:
-        """Cache entries the decoding slots attend over, summed (each
-        slot's cache plus the token it decodes)."""
-        return int(self.cache_len[active].sum()) + len(active)
+    def _span_costs(self, active: list, width: int = 1) -> dict:
+        """The launch's costly span arguments: ``ctx_tokens``, the cache
+        entries the decoding slots attend over, summed (each slot's cache
+        plus the token it decodes), and, paged, ``kv_blocks``, the blocks
+        the paged decode kernel walks: one row per (slot, verify position)
+        of length ``cache_len + j + 1``, ``ceil(length / block)`` blocks
+        a row and kv head."""
+        args = {"ctx_tokens": int(self.cache_len[active].sum())
+                + len(active)}
+        if self.paged:
+            lens = np.minimum(self.cache_len[active][:, None]
+                              + np.arange(1, width + 1), self.max_seq)
+            args["kv_blocks"] = int(-(-lens // self._kv_block_tokens).sum()
+                                    ) * self.cfg.num_kv_heads
+        return args
 
     def _sampling_operands(self, active):
         """Per-slot sampling operands (host numpy, materialized once)."""
@@ -637,7 +653,7 @@ class ServingEngine:
         # settled the slots that take part.
         with self.obs.phase("decode", slots=len(active)) as sp:
             if self.obs.spans_on:
-                sp.set_metadata(ctx_tokens=self._ctx_tokens(active))
+                sp.set_metadata(**self._span_costs(active))
             act = np.zeros((self.n_slots,), bool)
             act[active] = True
             act_dev = jnp.asarray(act)
@@ -738,7 +754,7 @@ class ServingEngine:
                             drafted=sum(len(d) for d in drafts.values())
                             ) as sp:
             if self.obs.spans_on:
-                sp.set_metadata(ctx_tokens=self._ctx_tokens(active))
+                sp.set_metadata(**self._span_costs(active, W))
             logits, self.caches = self._verify(self.params,
                                                jnp.asarray(toks),
                                                self.caches,

@@ -263,6 +263,27 @@ def test_kernel_check_vmem_budget(monkeypatch):
             args=(np.zeros((16, 8), np.float32),))
 
 
+def test_kernel_check_counts_no_vmem_for_operands_left_in_hbm(monkeypatch):
+    """An operand in ANY (HBM, copied by the kernel's own DMAs), SMEM
+    scratch and DMA semaphores take no VMEM; VMEM scratch still counts."""
+    from repro.kernels import pallas_compat as pc
+    pool = np.zeros((4, 1024, 16, 128), np.float32)          # 32 MiB
+    monkeypatch.setenv("REPRO_VMEM_BUDGET_BYTES", str(2 ** 20))
+    launch = dict(
+        name="manual_dma", grid=(2,),
+        in_specs=[pl.BlockSpec(memory_space=pc.ANY)],
+        out_specs=pl.BlockSpec((8, 128), lambda i: (i, 0)),
+        out_shape=_sds((16, 128)), args=(pool,))
+    kernel_check.check_launch(
+        scratch_shapes=[pc.VMEM((2, 32, 16, 128), jnp.bfloat16),
+                        pc.SMEM((1,), jnp.int32), pc.DMA_SEMAPHORES((2,))],
+        **launch)
+    with pytest.raises(KernelContractError, match="VMEM footprint"):
+        kernel_check.check_launch(
+            scratch_shapes=[pc.VMEM((2, 128, 16, 128), jnp.float32)],
+            **launch)
+
+
 def test_kernel_check_mosaic_second_minor_tiling():
     """A size-1 block on the second-minor dim of a larger array (the old
     token-major paged K/V pools) is refused by Mosaic."""

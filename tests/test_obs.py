@@ -331,6 +331,9 @@ def test_observer_spans_name_the_engine_work():
     assert sum(a["slots"] for a in decodes) \
         == m.get("repro_tokens_generated_total").value()
     assert all(a["ctx_tokens"] >= a["slots"] >= 1 for a in decodes)
+    # MAX_SEQ tokens are one block of the paged decode kernel's walk
+    kv = _cfg_params()[0].num_kv_heads
+    assert all(a["kv_blocks"] == a["slots"] * kv for a in decodes)
     assert sum(s["args"]["n_finished"] for s in by["engine.retire"]) \
         == len(done_on)
 
@@ -381,6 +384,7 @@ def test_profiled_run_holds_the_engine_spans(tmp_path):
             assert set(st) >= {"slot", "rid", "start", "n"}
         if n == "engine.decode":
             assert st["slots"] >= 1 and st["ctx_tokens"] >= st["slots"]
+            assert st["kv_blocks"] >= st["slots"]
         if n == "engine.retire":
             assert "n_finished" in st
     assert seen == {"engine.step", "engine.admit", "engine.prefill_chunk",
@@ -476,21 +480,46 @@ def test_decode_span_holds_the_launched_slots_under_preemption():
     assert all(a["ctx_tokens"] >= a["slots"] >= 1 for a in decodes)
 
 
+def test_launch_span_counts_the_paged_kernels_blocks():
+    """``kv_blocks`` is what the paged decode kernel walks in the launch:
+    ``ceil(length / block)`` blocks a row and kv head, at the kernel's
+    lengths (``cache_len + 1``; verify row j: ``cache_len + j + 1``, at
+    most ``max_seq``)."""
+    from repro.kernels.decode.decode_attn import pages_per_block
+    cfg, params = _cfg_params()
+    eng = ServingEngine(params, cfg, FamousConfig(impl="xla"), n_slots=3,
+                        max_seq=1024, chunk=CHUNK, cache_kind="paged",
+                        page_size=16)
+    bt = eng._kv_block_tokens
+    kv = cfg.num_kv_heads
+    assert bt == 16 * pages_per_block(64, 16, kv, cfg.head_dim,
+                                      jnp.float32) and bt < 1024
+    eng.cache_len[:] = [bt - 1, bt, 1022]
+    assert eng._span_costs([0, 1]) == {"ctx_tokens": 2 * bt + 1,
+                                       "kv_blocks": (1 + 2) * kv}
+    rows = [1023, 1024, 1024]                     # W = 3, clipped
+    assert eng._span_costs([2], 3)["kv_blocks"] \
+        == sum(-(-n // bt) for n in rows) * kv
+    contiguous = _engine()
+    assert set(contiguous._span_costs([0])) == {"ctx_tokens"}
+
+
 @pytest.mark.parametrize("metrics", [False, True],
                          ids=["no_observer", "metrics_only"])
 def test_costly_span_args_wait_for_a_span_factory(metrics):
     """Without a span factory the engine computes no span argument that
-    costs work (``ctx_tokens``), on the plain and the speculative path."""
+    costs work (``ctx_tokens``, ``kv_blocks``), on the plain and the
+    speculative path."""
     cfg, _ = _cfg_params()
     for kw in ({}, {"speculative": True, "draft_k": 2}):
         eng = _engine(observer=Observer() if metrics else None,
                       cache_kind="paged", page_size=8, **kw)
         assert eng.speculative_active == bool(kw)
 
-        def refuse(active):
-            raise AssertionError("ctx_tokens computed with spans off")
+        def refuse(*a):
+            raise AssertionError("span costs computed with spans off")
 
-        eng._ctx_tokens = refuse
+        eng._span_costs = refuse
         assert eng.run(_requests(cfg, n=3, seed=6))
 
 

@@ -62,19 +62,117 @@ def test_allocator_exhaustion_leaves_state_untouched():
 # ---------------------------------------------------------------------------
 
 
-def test_paged_kernel_matches_gather_reference():
-    B, KV, group, dh = 3, 2, 4, 16
-    ps, n_pages, n_p = 8, 17, 4
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(ks[0], (B, KV, group, dh), jnp.float32)
-    kp = jax.random.normal(ks[1], (KV, n_pages, ps, dh), jnp.float32)
-    vp = jax.random.normal(ks[2], (KV, n_pages, ps, dh), jnp.float32)
-    rng = np.random.default_rng(0)
-    pt = jnp.asarray(rng.integers(1, n_pages, size=(B, n_p)), jnp.int32)
-    lens = jnp.asarray([5, 23, 32], jnp.int32)
+# Block-walk shapes: 16-page (512-token) blocks over a 35-page table, two
+# whole blocks and a ragged third.  Lengths: one token, one page, one
+# block, one past it, the whole table; then a slot aliasing the first 18
+# pages of slot 4 (a shared prefix) and a dead slot (length 1, null page).
+PS, DH = 32, 16
+PPB = decode_attn.pages_per_block(10 ** 6, PS, 1, DH, jnp.float32)
+BT = PPB * PS
+N_P = 2 * PPB + 3
+LENS = (1, PS, BT, BT + 1, N_P * PS, BT + PS + 5, 1)
+
+
+def _block_walk_table():
+    """(page table (7, N_P), pool size): slots 0-4 own distinct pages,
+    slot 5 aliases slot 4's first pages, slot 6 is dead (all null)."""
+    pt = np.zeros((len(LENS), N_P), np.int32)
+    pt[:5] = np.random.default_rng(11).permutation(
+        np.arange(1, 1 + 5 * N_P)).reshape(5, N_P)
+    shared = -(-LENS[5] // PS)
+    pt[5, :shared] = pt[4, :shared]
+    return pt, 1 + 5 * N_P
+
+
+def _decode_case(case, seed):
+    """(q, page table, lengths, pool shape) of one parity case."""
+    if case == "mixed":                     # one block; repeated pages
+        B, KV, group, dh, ps, n_pages, n_p = 3, 2, 4, 16, 8, 17, 4
+        pt = np.random.default_rng(seed).integers(1, n_pages, size=(B, n_p))
+        lens = (5, 23, 32)
+    else:
+        KV, group = {"gqa_blocks": (2, 7), "mha_blocks": (3, 1)}[case]
+        dh, ps = DH, PS
+        pt, n_pages = _block_walk_table()
+        lens = LENS
+    q = jax.random.normal(jax.random.PRNGKey(seed),
+                          (len(lens), KV, group, dh), jnp.float32)
+    return (q, jnp.asarray(pt, jnp.int32), jnp.asarray(lens, jnp.int32),
+            (KV, n_pages, ps, dh))
+
+
+@pytest.mark.parametrize("n_kv", [2, 3])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int8])
+def test_block_walk_shapes_cover_a_ragged_multi_block_table(n_kv, dtype):
+    """The parity cases' kernels walk 16-page blocks, two whole and a
+    ragged third, whatever their kv heads and pool dtype."""
+    assert decode_attn.pages_per_block(N_P, PS, n_kv, DH, dtype) == PPB
+    assert PPB == 16 and N_P % PPB and N_P // PPB == 2
+
+
+@pytest.mark.parametrize("case", ["mixed", "gqa_blocks", "mha_blocks"])
+def test_paged_kernel_matches_gather_reference(case):
+    q, pt, lens, pool = _decode_case(case, 0)
+    ks = jax.random.split(jax.random.PRNGKey(1), 2)
+    kp = jax.random.normal(ks[0], pool, jnp.float32)
+    vp = jax.random.normal(ks[1], pool, jnp.float32)
     out = decode_attn.paged_decode_attention(q, kp, vp, pt, lens,
                                              interpret=True)
     want = dec_ref.paged_decode_reference(q, kp, vp, pt, lens)
+    np.testing.assert_allclose(out, want, atol=1e-5)
+
+
+@pytest.mark.parametrize("kv", ["fp", "int8"])
+def test_paged_kernel_never_reads_skipped_pages(kv):
+    """Every pool page outside the slots' live pages, and the null page,
+    holds NaN (int8: NaN scales): the output still equals the reference
+    on the same pools with finite pages there, and is finite, so no page
+    past a slot's length reaches the softmax or the PV matmul.  A kernel
+    that walks every page of the table and only masks the scores fails
+    this: a zero weight times a NaN value is NaN."""
+    q, pt, lens, pool = _decode_case("gqa_blocks", 2)
+    q, pt, lens = q[:-1], pt[:-1], lens[:-1]          # no dead slot
+    live = np.zeros(pool[1], bool)
+    for row, n in zip(np.asarray(pt), np.asarray(lens)):
+        live[row[:-(-n // PS)]] = True
+    assert not live[NULL_PAGE] and live.sum() < pool[1] - 1
+    dead = jnp.asarray(~live)[None, :, None, None]
+    ks = jax.random.split(jax.random.PRNGKey(3), 2)
+    if kv == "fp":
+        kp, vp = (jax.random.normal(k, pool) for k in ks)
+        want = dec_ref.paged_decode_reference(q, kp, vp, pt, lens)
+        kp, vp = (jnp.where(dead, jnp.nan, x) for x in (kp, vp))
+        out = decode_attn.paged_decode_attention(q, kp, vp, pt, lens,
+                                                 interpret=True)
+    else:
+        (_, kq, kscale), (_, vq, vscale) = (
+            _quantized_pools(k, pool[1], PS, pool[0], DH) for k in ks)
+        want = dec_ref.paged_decode_reference_int8(q, kq, vq, kscale, vscale,
+                                                   pt, lens)
+        kscale, vscale = (jnp.where(dead, jnp.nan, x)
+                          for x in (kscale, vscale))
+        out = decode_attn.paged_decode_attention_int8(
+            q, kq, vq, kscale, vscale, pt, lens, interpret=True)
+    assert bool(jnp.isfinite(out).all())
+    np.testing.assert_allclose(out, want, atol=1e-5)
+
+
+def test_paged_verify_kernel_matches_reference():
+    """Speculative verify (W = 3) on the block walk, near ``max_seq``:
+    rows of length ``cache_len + j + 1``, clipped at the table's end."""
+    KV, group, W = 2, 7, 3
+    pt, n_pages = _block_walk_table()
+    pt = jnp.asarray(pt[:4], jnp.int32)
+    cache_len = jnp.asarray([N_P * PS - 3, N_P * PS - 2, BT - 1, 0],
+                            jnp.int32)
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (4, W, KV * group, DH), jnp.float32)
+    kp = jax.random.normal(ks[1], (KV, n_pages, PS, DH), jnp.float32)
+    vp = jax.random.normal(ks[2], (KV, n_pages, PS, DH), jnp.float32)
+    from repro.kernels.decode import ops as dec_ops
+    out = dec_ops.paged_verify_attention(q, kp, vp, pt, cache_len,
+                                         interpret=True)
+    want = famous.paged_verify_attention(q, kp, vp, pt, cache_len, cfg=FCFG)
     np.testing.assert_allclose(out, want, atol=1e-5)
 
 
@@ -126,19 +224,15 @@ def _quantized_pools(key, n_pages, ps, KV, dh):
     return pool, q, s.swapaxes(-1, -2).astype(jnp.float32)
 
 
-def test_paged_int8_decode_kernel_matches_oracle():
+@pytest.mark.parametrize("case", ["mixed", "gqa_blocks", "mha_blocks"])
+def test_paged_int8_decode_kernel_matches_oracle(case):
     """Int8 paged decode: Pallas in-kernel dequant vs the XLA
     dequantizing-gather oracle (tight), and both vs the fp kernel on the
     pre-quantization pool (lossy but bounded drift)."""
-    B, KV, group, dh = 3, 2, 4, 16
-    ps, n_pages, n_p = 8, 17, 4
-    ks = jax.random.split(jax.random.PRNGKey(7), 3)
-    q = jax.random.normal(ks[0], (B, KV, group, dh), jnp.float32)
-    kf, kq, kscale = _quantized_pools(ks[1], n_pages, ps, KV, dh)
-    vf, vq, vscale = _quantized_pools(ks[2], n_pages, ps, KV, dh)
-    rng = np.random.default_rng(7)
-    pt = jnp.asarray(rng.integers(1, n_pages, size=(B, n_p)), jnp.int32)
-    lens = jnp.asarray([5, 23, 32], jnp.int32)
+    q, pt, lens, (KV, n_pages, ps, dh) = _decode_case(case, 7)
+    ks = jax.random.split(jax.random.PRNGKey(8), 2)
+    kf, kq, kscale = _quantized_pools(ks[0], n_pages, ps, KV, dh)
+    vf, vq, vscale = _quantized_pools(ks[1], n_pages, ps, KV, dh)
     out = decode_attn.paged_decode_attention_int8(q, kq, vq, kscale, vscale,
                                                   pt, lens, interpret=True)
     want = dec_ref.paged_decode_reference_int8(q, kq, vq, kscale, vscale,
