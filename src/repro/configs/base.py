@@ -15,6 +15,8 @@ ATTN = "attn"            # global dense softmax attention (FAMOUS applies)
 LOCAL_ATTN = "local_attn"  # sliding-window attention (FAMOUS + window mask)
 RGLRU = "rglru"          # Griffin/RecurrentGemma recurrent block
 RWKV6 = "rwkv6"          # RWKV-6 "Finch" time-mix block
+MLA = "mla"              # multi-head latent attention (DeepSeek-V2) over a
+                         # paged latent cache
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,10 +42,35 @@ class ModelConfig:
     rope: bool = True
     rope_theta: float = 10000.0
     logit_softcap: float = 0.0
+    # Latent attention (MLA, kind ``MLA``) -----------------------------------
+    # K/V come from one cached row per token: a ``kv_lora_rank`` latent plus
+    # a ``qk_rope_head_dim`` rotary key shared by every head.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN RoPE (factor 0: plain RoPE): frequencies ramp between
+    # interpolated and extrapolated over the rotary dims, and the softmax
+    # scale takes the attention factor squared (mscale_all_dim)
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     # MoE ---------------------------------------------------------------------
-    num_experts: int = 0
+    num_experts: int = 0            # the router's width: every expert
     experts_per_token: int = 0
     capacity_factor: float = 1.25
+    moe_d_ff: int = 0               # per-expert width (0 -> d_ff)
+    n_shared_experts: int = 0       # always-on experts, moe_d_ff wide each
+    routed_scale: float = 1.0       # routed weights' scale (no renormalising)
+    # The experts this chip holds (ids into the router's outputs): given,
+    # the FFN is the dropless serving layer, which routes over every expert
+    # and computes the held ones; empty, the capacity-based training layer.
+    held_experts: tuple[int, ...] = ()
+    # Leading layers with a dense FFN of width d_ff before the scanned
+    # stack (DeepSeek's first_k_dense_replace).
+    first_dense_layers: int = 0
     # SSM / recurrence --------------------------------------------------------
     lru_width: int = 0              # RG-LRU state width (0 -> d_model)
     conv_width: int = 4             # temporal conv in the recurrent block
@@ -76,12 +103,29 @@ class ModelConfig:
 
     @property
     def num_units(self) -> int:
-        return self.num_layers // len(self.pattern_unit)
+        return ((self.num_layers - self.first_dense_layers)
+                // len(self.pattern_unit))
 
     @property
     def tail_layers(self) -> tuple[str, ...]:
-        n_tail = self.num_layers % len(self.pattern_unit)
+        n_tail = ((self.num_layers - self.first_dense_layers)
+                  % len(self.pattern_unit))
         return self.pattern_unit[:n_tail]
+
+    @property
+    def head_layers(self) -> tuple[str, ...]:
+        """The leading dense-FFN layers, of the stack's first kind."""
+        return self.pattern_unit[:1] * self.first_dense_layers
+
+    @property
+    def latent_row(self) -> int:
+        """Width of an MLA layer's cached row per token (0: no MLA)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim if self.kv_lora_rank \
+            else 0
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
 
     def param_count(self) -> int:
         """Total parameters (analytic, matches init)."""
@@ -95,10 +139,11 @@ class ModelConfig:
         total = self.param_count()
         if self.num_experts == 0:
             return total
-        d_ff, e, k = self.d_ff, self.num_experts, self.experts_per_token
-        per_expert = 3 * self.d_model * d_ff
-        inactive = self.num_layers * per_expert * (e - k)  # every block is MoE
-        return total - inactive
+        # experts in the spec: the held share, or every expert
+        e = len(self.held_experts) or self.num_experts
+        per_expert = 3 * self.d_model * self.expert_d_ff
+        moe_layers = self.num_layers - self.first_dense_layers
+        return total - moe_layers * per_expert * (e - self.experts_per_token)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -152,6 +197,7 @@ def _ensure_loaded():
         llava_next_34b,
         grok_1_314b,
         kimi_k2_1t_a32b,
+        deepseek_v2_lite,
         hubert_xlarge,
         rwkv6_1b6,
         famous_bert,
@@ -207,7 +253,8 @@ def shrink(cfg: ModelConfig, **over) -> ModelConfig:
     unit = cfg.pattern_unit
     defaults = dict(
         name=cfg.name + "-smoke",
-        num_layers=2 * len(unit) + len(cfg.tail_layers),
+        num_layers=(2 * len(unit) + len(cfg.tail_layers)
+                    + cfg.first_dense_layers),
         d_model=64,
         num_heads=4,
         num_kv_heads=min(cfg.num_kv_heads, 2) if cfg.num_kv_heads else 2,
@@ -220,5 +267,14 @@ def shrink(cfg: ModelConfig, **over) -> ModelConfig:
         window=min(cfg.window, 16) if cfg.window else 0,
         rwkv_head_dim=16,
     )
+    if cfg.kv_lora_rank:
+        defaults.update(kv_lora_rank=32, qk_nope_head_dim=16,
+                        qk_rope_head_dim=8, v_head_dim=16,
+                        head_dim=24)
+    if cfg.moe_d_ff:
+        defaults.update(moe_d_ff=32)
+    if cfg.held_experts:
+        # a share of the shrunk router's experts, more than it routes to
+        defaults.update(held_experts=tuple(range(defaults["num_experts"] - 1)))
     defaults.update(over)
     return dataclasses.replace(cfg, **defaults)

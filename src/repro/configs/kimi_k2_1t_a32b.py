@@ -1,5 +1,9 @@
 """kimi-k2-1t-a32b — trillion-parameter MoE: 384 experts, top-8.
 
+A GQA stand-in: the published model attends with multi-head latent
+attention (MLA, as ``deepseek-v2-lite`` does), which this entry does not
+use; it exercises the capacity-based training MoE at kimi-k2's widths.
+
 [arXiv:2501.kimi2; unverified]
 61L d_model=7168 64H (GQA kv=8) d_ff=2048 vocab=163840, MoE 384e top-8.
 ``d_ff`` is the per-expert hidden width (fine-grained experts).

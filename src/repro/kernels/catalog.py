@@ -11,8 +11,10 @@ share the list:
 * ``chip_smoke.py`` runs every case on the chip with seeded operands and
   compares it with its oracle.
 
-Shapes are those of the engine ``chip_smoke.py`` serves, which imports
-the sizes below: ``SLOTS`` slots of ``MAX_SEQ`` tokens in ``PAGE``-token
+:func:`latent_cases` holds the latent-attention (MLA) model's kernels —
+its paged decode kernel and the held-expert grouped matmul — at its own
+widths.  Shapes are those of the engine ``chip_smoke.py`` serves, which
+imports the sizes below: ``SLOTS`` slots of ``MAX_SEQ`` tokens in ``PAGE``-token
 pages (a pool of ``SLOTS * MAX_SEQ / PAGE`` pages plus the null page), one
 ``CHUNK``-token prefill chunk, one decode token per slot, in bf16.
 """
@@ -178,3 +180,55 @@ def main_path_cases(cfg: ModelConfig) -> list[KernelCase]:
                    qkv_proj.matmul_tiled_int8, int8_matmul_ref),
     ]
 
+
+
+def latent_cases(cfg: ModelConfig) -> list[KernelCase]:
+    """The latent-attention model's kernels at ``cfg``'s widths: the paged
+    latent decode over ``SLOTS`` slots of ``MAX_SEQ`` tokens (rows stored
+    lane-padded), and one grouped matmul of a decode step's (token,
+    expert) rows over the held experts' up projection."""
+    from repro.models import mla, moe
+    H, r, width = cfg.num_heads, cfg.kv_lora_rank, mla.stored_width(cfg)
+    slots, page_size, S = SLOTS, PAGE, MAX_SEQ
+    pages_per_slot = S // page_size
+    n_pages = 1 + slots * pages_per_slot
+    scale = mla.softmax_scale(cfg)
+    E, D, F = len(cfg.held_experts), cfg.d_model, cfg.expert_d_ff
+    rows = -(-SLOTS * cfg.experts_per_token // moe.GMM_ROWS) * moe.GMM_ROWS
+
+    def latent_decode(key):
+        ks = jax.random.split(key, 4)
+        ids = jax.random.permutation(ks[2], jnp.arange(1, n_pages))
+        return (jax.random.normal(ks[0], (slots, 1, H, width)).astype(DTYPE),
+                jax.random.normal(ks[1], (1, n_pages, page_size, width)
+                                  ).astype(DTYPE),
+                ids.reshape(slots, pages_per_slot).astype(jnp.int32),
+                jax.random.randint(ks[3], (slots,), 1, S + 1, jnp.int32))
+
+    def grouped(key):
+        ks = jax.random.split(key, 3)
+        sizes = jax.random.multinomial(ks[2], rows // 2,
+                                       jnp.full((E,), 1.0 / E)
+                                       ).astype(jnp.int32)
+        return (jax.random.normal(ks[0], (rows, D)).astype(DTYPE),
+                jax.random.normal(ks[1], (E, D, F)).astype(DTYPE), sizes)
+
+    def ragged_ref(lhs, rhs, sizes):
+        out = jax.lax.ragged_dot(lhs, rhs, sizes,
+                                 preferred_element_type=jnp.float32)
+        live = jnp.arange(lhs.shape[0])[:, None] < sizes.sum()
+        return jnp.where(live, out, 0.0).astype(lhs.dtype)
+
+    def gmm(lhs, rhs, sizes):
+        live = jnp.arange(lhs.shape[0])[:, None] < sizes.sum()
+        return jnp.where(live, moe.grouped_matmul(lhs, rhs, sizes, "pallas"),
+                         0.0)
+
+    return [
+        KernelCase("mla_decode", latent_decode,
+                   lambda *a: decode_attn.mla_decode_attention(
+                       *a, scale=scale, rank=r),
+                   lambda *a: dec_ref.mla_decode_reference(
+                       *a, scale=scale, rank=r)),
+        KernelCase("moe_gmm", grouped, gmm, ragged_ref),
+    ]

@@ -50,8 +50,11 @@ def online_softmax_step(s, ok, v, acc_ref, m_ref, l_ref, v_scale=None):
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
     pv = p if v_scale is None else p * v_scale
+    # a narrower value type (the latent kernel's bf16 rows) takes the
+    # weights in its type; float32 values take them as they are
     acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        pv, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        pv.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
 
@@ -113,19 +116,30 @@ def _live_blocks(length, block_tokens: int, n_blocks):
     return jnp.clip((length + block_tokens - 1) // block_tokens, 1, n_blocks)
 
 
-def _paged_decode_kernel(len_ref, pt_ref, q_ref, k_hbm, v_hbm, *refs,
+def _paged_decode_kernel(len_ref, pt_ref, q_ref, k_hbm, *refs,
                          scale: float, page_size: int, ppb: int, n_p: int,
-                         quantized: bool):
+                         quantized: bool, v_width: int):
     """One grid step (b, j): block j of slot b — pages ``[j*ppb,
     (j+1)*ppb)`` of its table, every kv head — copied page by page from
     the HBM pools into one of two VMEM buffers, then folded into each kv
     head's running softmax.  Blocks at or past the slot's length are
     skipped whole; each live step starts the copies of the next live block
     (of this slot or the next) into the other buffer before it waits on
-    its own."""
+    its own.
+
+    ``v_width`` 0: V is its own pool, the operand after K.  ``v_width`` >
+    0 (latent attention): there is one pool, V is the first ``v_width``
+    columns of K's rows, and the dots take the pool's type (bf16 rows
+    stay bf16, accumulation in float32)."""
+    if not v_width:
+        v_hbm, *refs = refs
     if quantized:
         ks_ref, vs_ref, *refs = refs
-    o_ref, acc_ref, m_ref, l_ref, k_buf, v_buf, sem_ref, slot_ref = refs
+    o_ref, acc_ref, m_ref, l_ref, k_buf, *refs = refs
+    if not v_width:
+        v_buf, *refs = refs
+    sem_ref, slot_ref = refs
+    pools = ((k_hbm, k_buf),) if v_width else ((k_hbm, k_buf), (v_hbm, v_buf))
     b, j = pl.program_id(0), pl.program_id(1)
     n_kv, dh = q_ref.shape[1], q_ref.shape[3]
     block_tokens = ppb * page_size
@@ -139,7 +153,7 @@ def _paged_decode_kernel(len_ref, pt_ref, q_ref, k_hbm, v_hbm, *refs,
             @pl.when(i < live)
             def _():
                 page = pt_ref[row, first + i]
-                for pool, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                for pool, buf in pools:
                     getattr(pc.make_async_copy(pool.at[:, page],
                                                buf.at[slot, i],
                                                sem_ref.at[slot]), method)()
@@ -178,10 +192,16 @@ def _paged_decode_kernel(len_ref, pt_ref, q_ref, k_hbm, v_hbm, *refs,
             jnp.int32, (block_tokens, 1), 0) < n_ok
         for h in range(n_kv):   # unrolled: a loop of kv heads runs slower
             q = q_ref[0, h].astype(jnp.float32) * scale     # (group, dh)
-            k = k_buf[slot, :, h].astype(jnp.float32).reshape(block_tokens,
-                                                                dh)
-            v = v_buf[slot, :, h].astype(jnp.float32).reshape(block_tokens,
-                                                                dh)
+            if v_width:
+                k = k_buf[slot, :, h].reshape(block_tokens, dh)
+                q = q.astype(k.dtype)
+                v = k_buf[slot, :, h, :, :v_width].reshape(block_tokens,
+                                                            v_width)
+            else:
+                k = k_buf[slot, :, h].astype(jnp.float32).reshape(
+                    block_tokens, dh)
+                v = v_buf[slot, :, h].astype(jnp.float32).reshape(
+                    block_tokens, dh)
             v = jnp.where(row_ok, v, 0.0)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
@@ -207,19 +227,25 @@ def _paged_decode_kernel(len_ref, pt_ref, q_ref, k_hbm, v_hbm, *refs,
 
 
 def _paged_decode_call(q, k_pages, v_pages, scales, page_table, cache_len,
-                       scale, interpret):
+                       scale, interpret, v_width: int = 0):
+    """The paged decode launch.  ``v_pages`` None: latent attention, whose
+    values are the first ``v_width`` columns of ``k_pages``' rows."""
     B, KV, group, dh = q.shape
     page_size = k_pages.shape[2]
     n_p = page_table.shape[1]
     ppb = pages_per_block(n_p, page_size, KV, dh, k_pages.dtype)
     n_blocks = pl.cdiv(n_p, ppb)
     block_tokens = ppb * page_size
+    dv = v_width or dh
     scale = scale if scale is not None else 1.0 / math.sqrt(dh)
     kernel = functools.partial(_paged_decode_kernel, scale=float(scale),
                                page_size=page_size, ppb=ppb, n_p=n_p,
-                               quantized=bool(scales))
+                               quantized=bool(scales), v_width=v_width)
     slot_spec = pl.BlockSpec((1, KV, group, dh),
                              lambda b, j, lens, pt: (b, 0, 0, 0))
+    out_spec = pl.BlockSpec((1, KV, group, dv),
+                            lambda b, j, lens, pt: (b, 0, 0, 0))
+    pools = (k_pages,) if v_width else (k_pages, v_pages)
     # A page's (1, page_size) scale row is narrower than the 128-lane tile
     # a DMA moves, so the int8 scales are gathered per slot by XLA, (B, KV,
     # 1, n_blocks * block_tokens), and reach the kernel as (1, block) rows
@@ -234,16 +260,15 @@ def _paged_decode_call(q, k_pages, v_pages, scales, page_table, cache_len,
     grid_spec = pc.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,           # cache_len, page_table
         grid=(B, n_blocks),
-        in_specs=[slot_spec, pl.BlockSpec(memory_space=pc.ANY),
-                  pl.BlockSpec(memory_space=pc.ANY)]
+        in_specs=[slot_spec] + [pl.BlockSpec(memory_space=pc.ANY)] * len(pools)
         + [scale_spec] * len(scales),
-        out_specs=slot_spec,
+        out_specs=out_spec,
         scratch_shapes=[
-            pc.VMEM((KV, group, dh), jnp.float32),
+            pc.VMEM((KV, group, dv), jnp.float32),
             pc.VMEM((KV, group, 1), jnp.float32),
             pc.VMEM((KV, group, 1), jnp.float32),
-            pc.VMEM((2, ppb, KV, page_size, dh), k_pages.dtype),
-            pc.VMEM((2, ppb, KV, page_size, dh), v_pages.dtype),
+        ] + [pc.VMEM((2, ppb, KV, page_size, dh), p.dtype) for p in pools]
+        + [
             pc.DMA_SEMAPHORES((2,)),
             pc.SMEM((1,), jnp.int32),    # the buffer the next block uses
         ],
@@ -253,11 +278,11 @@ def _paged_decode_call(q, k_pages, v_pages, scales, page_table, cache_len,
     return pc.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, group, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KV, group, dv), q.dtype),
         compiler_params=pc.compiler_params("arbitrary", "arbitrary"),
         interpret=interpret,
     )(cache_len.astype(jnp.int32), page_table.astype(jnp.int32), q,
-      k_pages, v_pages, *scale_rows)
+      *pools, *scale_rows)
 
 
 def _scale_rows(scale_pool, page_table, width: int):
@@ -287,6 +312,25 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, cache_len, *,
     """
     return _paged_decode_call(q, k_pages, v_pages, (), page_table,
                               cache_len, scale, interpret)
+
+
+def mla_decode_attention(q, pool, page_table, cache_len, *, scale: float,
+                         rank: int, interpret: bool = False):
+    """Paged decode of latent attention (MLA): every query head of a slot
+    against one cached row per token.
+
+    q: (B, 1, H, row) absorbed queries ``[q_nope W_UK^T; q_pe]``; pool:
+    (1, n_pages, page_size, row) latent rows ``[c_kv; k_pe]``, ``row`` a
+    multiple of 128 (Mosaic copies no narrower page; zero-padded past
+    the latent's own width); page_table:
+    (B, n_p) int32; cache_len: (B,) int32.  Scores take the whole row, the
+    values its first ``rank`` columns.  Returns (B, 1, H, rank).
+
+    The same block walk as :func:`paged_decode_attention` (one "kv head"
+    of ``row``-wide pages, the H heads as its group), copying each page
+    once for both the scores and the values."""
+    return _paged_decode_call(q, pool, None, (), page_table, cache_len,
+                              scale, interpret, v_width=rank)
 
 
 def paged_decode_attention_int8(q, k_pages, v_pages, k_scale, v_scale,

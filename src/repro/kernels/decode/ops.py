@@ -62,6 +62,18 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, cache_len, *,
     return out.reshape(B, 1, H, dh)
 
 
+@functools.partial(jax.jit, static_argnames=("scale", "rank", "interpret"))
+def mla_decode_attention(q, pool, page_table, cache_len, *, scale, rank,
+                         interpret=None):
+    """q: (B, 1, H, row) absorbed queries; pool: (1, n_pages, page_size,
+    row) latent rows; page_table: (B, n_p) int32; cache_len: (B,) int32.
+    Returns the latent output (B, 1, H, rank)."""
+    interpret = pc.interpret_default() if interpret is None else interpret
+    return decode_attn.mla_decode_attention(q, pool, page_table, cache_len,
+                                            scale=scale, rank=rank,
+                                            interpret=interpret)
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode_attention_int8(q, k_pages, v_pages, k_scale, v_scale,
                                 page_table, cache_len, *, scale=None,

@@ -121,3 +121,19 @@ def paged_chunk_prefill_reference_int8(q, k_pages, v_pages, k_scale, v_scale,
     k = gather_pages_int8(k_pages, k_scale, page_table)
     v = gather_pages_int8(v_pages, v_scale, page_table)
     return chunk_prefill_reference(q, k, v, q_offset, scale=scale)
+
+
+def mla_decode_reference(q, pool, page_table, cache_len, *, scale: float,
+                         rank: int):
+    """Gather-based oracle for the latent (MLA) decode kernel.
+
+    q: (B, 1, H, row); pool: (1, n_pages, page_size, row); page_table:
+    (B, n_p); cache_len: (B,).  Returns (B, 1, H, rank) in q's type."""
+    B = q.shape[0]
+    rows = pool[0][page_table].reshape(B, -1, pool.shape[-1])
+    rows = rows.astype(jnp.float32)
+    s = jnp.einsum("bhr,bkr->bhk", q[:, 0].astype(jnp.float32), rows) * scale
+    ok = jnp.arange(rows.shape[1])[None, None, :] < cache_len[:, None, None]
+    p = jax.nn.softmax(jnp.where(ok, s, -jnp.inf), axis=-1)
+    out = jnp.einsum("bhk,bkr->bhr", p, rows[..., :rank])
+    return out[:, None].astype(q.dtype)

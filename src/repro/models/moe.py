@@ -1,4 +1,11 @@
-"""Token-choice top-k Mixture-of-Experts FFN (grok-1, kimi-k2).
+"""Token-choice top-k Mixture-of-Experts FFNs.
+
+Two layers.  The serving layer (:func:`apply_moe_serving`, configs with
+``held_experts``: deepseek-v2-lite) is dropless: it routes over every
+expert, computes only the experts this chip holds, with a grouped matmul
+over the (token, expert) pairs sorted by expert, and a token's output never
+depends on its batch-mates.  The training layer (:func:`apply_moe`,
+grok-1, kimi-k2) is capacity-based:
 
 GShard/GSPMD-style *grouped dense dispatch*: tokens are split into G groups;
 each group dispatches into a per-group expert buffer of capacity C via a
@@ -6,7 +13,8 @@ each group dispatches into a per-group expert buffer of capacity C via a
 partitions perfectly (groups on the dp axes, experts on "model" = expert
 parallelism with all-to-all routing inserted by XLA).  A scatter/gather
 formulation was tried first and rejected: GSPMD replicates scatter operands,
-costing ~190 GiB/device on grok-1 (see EXPERIMENTS.md §Perf).
+costing ~190 GiB/device on grok-1 in a dry-run compile (a compile's
+figure; PERF.md holds what was measured on the chip).
 
 Capacity inflation is bounded: C = ceil(cf · S_g · K / E) per group, so the
 buffer is cf·K·T token-slots globally.  Tokens beyond an expert's capacity
@@ -17,6 +25,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from repro.configs.base import ModelConfig
@@ -125,3 +134,113 @@ def aux_load_balance_loss(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     _, _, aux = router_dispatch(logits, cfg.experts_per_token,
                                 cfg.capacity_factor, cfg.logit_softcap)
     return aux
+
+
+# ---------------------------------------------------------------------------
+# dropless serving layer over the held experts
+# ---------------------------------------------------------------------------
+
+
+def serving_moe_spec(cfg: ModelConfig) -> dict:
+    """Router over all ``num_experts``; weights of the held experts only
+    (in ``held_experts`` order); shared experts as one gated MLP of
+    ``n_shared_experts * expert_d_ff``."""
+    d, f, n = cfg.d_model, cfg.expert_d_ff, len(cfg.held_experts)
+    spec = {
+        "router": ParamSpec((d, cfg.num_experts), ("embed", None)),
+        "w_in": ParamSpec((n, d, f), ("experts", None, "expert_ff")),
+        "w_gate": ParamSpec((n, d, f), ("experts", None, "expert_ff")),
+        "w_out": ParamSpec((n, f, d), ("experts", "expert_ff", None)),
+    }
+    if cfg.n_shared_experts:
+        spec["shared"] = layers.mlp_spec(d, f * cfg.n_shared_experts, cfg.act)
+    return spec
+
+
+def route(router, x, cfg: ModelConfig):
+    """Softmax over every expert in float32, the top ``experts_per_token``
+    with their probabilities (not renormalised) times ``routed_scale``.
+    x: (T, D) -> (weights (T, K) f32, expert ids (T, K) int32)."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                           cfg.experts_per_token)
+    return w * cfg.routed_scale, idx
+
+
+# Largest expert-weight tile the grouped matmul streams per grid step.
+GMM_TILE_BYTES = 3 << 20
+GMM_ROWS = 32    # rows of (token, expert) pairs per tile
+
+
+def _gmm_tiling(k: int, n: int, itemsize: int) -> tuple:
+    """(rows, k, n) tile of megablox's grouped matmul: k in 512-wide tiles
+    where it divides (else whole), n whole, or halved while the weight
+    tile exceeds :data:`GMM_TILE_BYTES` (halves stay 128-aligned)."""
+    tk = 512 if k % 512 == 0 else k
+    tn = n
+    while tk * tn * itemsize > GMM_TILE_BYTES and tn % 256 == 0:
+        tn //= 2
+    return GMM_ROWS, tk, tn
+
+
+def grouped_matmul(lhs, rhs, group_sizes, impl: str):
+    """Rows of ``lhs`` (M, K), sorted by group, times their group's matrix
+    of ``rhs`` (G, K, N); rows past ``sum(group_sizes)`` are left
+    undefined.  impl="pallas": megablox's ``gmm`` kernel, which visits
+    only the groups that have rows; else ``jax.lax.ragged_dot``."""
+    if impl != "pallas":
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                                  preferred_element_type=jnp.float32
+                                  ).astype(lhs.dtype)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    tiling = _gmm_tiling(rhs.shape[1], rhs.shape[2], rhs.dtype.itemsize)
+    return gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
+               tiling=tiling)
+
+
+def apply_moe_serving(p: dict, x: jax.Array, cfg: ModelConfig,
+                      impl: str = "xla", valid=None):
+    """x: (B, S, D) -> (y (B, S, D), counts (n_held + 2,) int32).
+
+    y = sum over the token's top-k experts that this chip holds of
+    p_e * E_e(x), plus the shared experts.  Dropless: each (token, expert)
+    pair is one row of the grouped matmul, sorted by held expert; pairs
+    for experts held elsewhere, and every pair of a token that is not
+    ``valid`` (B, S) (a free slot, a prefill chunk's pad), sort past the
+    last group and are not computed.  ``counts``: the rows each held
+    expert computed, then the pairs routed (valid tokens x k, held or
+    not), then the held experts with a row (whose weights the layer
+    reads)."""
+    B, S, D = x.shape
+    K, held = cfg.experts_per_token, cfg.held_experts
+    n = len(held)
+    xt = x.reshape(B * S, D)
+    w, idx = route(p["router"], xt, cfg)
+    local = np.full((cfg.num_experts,), n, np.int32)
+    local[list(held)] = np.arange(n, dtype=np.int32)
+    e = jnp.asarray(local)[idx]                               # (T, K)
+    ok = (jnp.ones((B * S,), bool) if valid is None
+          else valid.reshape(B * S))
+    e = jnp.where(ok[:, None], e, n).reshape(-1)              # (T*K,)
+    M = -(-e.shape[0] // GMM_ROWS) * GMM_ROWS
+    e = jnp.pad(e, (0, M - e.shape[0]), constant_values=n)
+    order = jnp.argsort(e, stable=True)
+    e_sorted = e[order]
+    tok = jnp.minimum(order // K, B * S - 1)
+    sizes = jnp.zeros((n + 1,), jnp.int32).at[e].add(1)[:n]
+    rows = xt[tok]
+    h = (layers.act_fn(cfg.act)(grouped_matmul(rows, p["w_gate"], sizes, impl))
+         * grouped_matmul(rows, p["w_in"], sizes, impl))
+    out = grouped_matmul(h, p["w_out"], sizes, impl)
+    wt = jnp.pad(w.reshape(-1), (0, M - w.size))[order]
+    out = jnp.where((e_sorted < n)[:, None],
+                    out.astype(jnp.float32) * wt[:, None], 0.0)
+    y = jnp.zeros((B * S, D), jnp.float32).at[tok].add(out)
+    y = y.astype(x.dtype).reshape(B, S, D)
+    if "shared" in p:
+        y = y + layers.apply_mlp(p["shared"], x, cfg.act)
+    counts = jnp.concatenate([sizes, jnp.stack([ok.sum() * K,
+                                                 (sizes > 0).sum()]
+                                                ).astype(jnp.int32)])
+    return y, counts

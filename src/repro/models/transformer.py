@@ -24,9 +24,9 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ATTN, LOCAL_ATTN, RGLRU, RWKV6, ModelConfig
+from repro.configs.base import ATTN, LOCAL_ATTN, MLA, RGLRU, RWKV6, ModelConfig
 from repro.core.famous import FamousConfig
-from repro.models import attention, layers, moe, rglru, rwkv6
+from repro.models import attention, layers, mla, moe, rglru, rwkv6
 from repro.models.module import ParamSpec, stack_specs
 from repro.parallel.incontext import constrain_residual
 
@@ -35,21 +35,24 @@ from repro.parallel.incontext import constrain_residual
 # ---------------------------------------------------------------------------
 
 
-def _ffn_spec(cfg: ModelConfig):
-    if cfg.num_experts:
-        return moe.moe_spec(cfg)
+def _ffn_spec(cfg: ModelConfig, dense: bool = False):
+    if cfg.num_experts and not dense:
+        return (moe.serving_moe_spec(cfg) if cfg.held_experts
+                else moe.moe_spec(cfg))
     gated = cfg.act in ("silu", "gelu") and cfg.norm == "rmsnorm"
     return layers.mlp_spec(cfg.d_model, cfg.d_ff, cfg.act, gated=gated)
 
 
-def block_spec(kind: str, cfg: ModelConfig) -> dict:
+def block_spec(kind: str, cfg: ModelConfig, dense: bool = False) -> dict:
+    """One layer; ``dense``: a leading layer, whose FFN is the dense MLP."""
     d = cfg.d_model
-    if kind in (ATTN, LOCAL_ATTN):
+    if kind in (ATTN, LOCAL_ATTN, MLA):
         return {
             "ln1": layers.norm_spec(d, cfg.norm),
-            "attn": attention.attn_spec(cfg),
+            "attn": (mla.mla_spec(cfg) if kind == MLA
+                     else attention.attn_spec(cfg)),
             "ln2": layers.norm_spec(d, cfg.norm),
-            "ffn": _ffn_spec(cfg),
+            "ffn": _ffn_spec(cfg, dense),
         }
     if kind == RGLRU:
         return {
@@ -77,6 +80,8 @@ def model_spec(cfg: ModelConfig) -> dict:
     }
     for i, k in enumerate(cfg.tail_layers):
         spec[f"tail{i}"] = block_spec(k, cfg)
+    for i, k in enumerate(cfg.head_layers):
+        spec[f"head{i}"] = block_spec(k, cfg, dense=True)
     if not cfg.tie_embeddings:
         spec["lm_head"] = {
             "w": ParamSpec((cfg.d_model, cfg.vocab_size), ("embed", "vocab"),
@@ -91,14 +96,48 @@ def model_spec(cfg: ModelConfig) -> dict:
 
 
 def _apply_ffn(p, x, cfg: ModelConfig):
-    if cfg.num_experts:
+    if "router" in p:
+        if cfg.held_experts:
+            return moe.apply_moe_serving(p, x, cfg)[0]
         return moe.apply_moe(p, x, cfg)
     return layers.apply_mlp(p, x, cfg.act)
+
+
+def _mla_block(p, x, cfg: ModelConfig, fcfg: FamousConfig, attend,
+               valid=None, counts=None):
+    """An MLA layer: ``attend(normed x) -> (out, cache)``, then the FFN
+    (the held-expert layer, told which tokens are ``valid``).  The layer's
+    expert row counts are appended to ``counts`` when it is a list."""
+    n = functools.partial(layers.apply_norm, kind=cfg.norm)
+    a, cache = attend(n(p["ln1"], x))
+    x = x + a
+    h = n(p["ln2"], x)
+    if "router" in p["ffn"]:
+        y, c = moe.apply_moe_serving(p["ffn"], h, cfg, fcfg.impl, valid)
+        if counts is not None:
+            counts.append(c)
+    else:
+        y = layers.apply_mlp(p["ffn"], h, cfg.act)
+    return x + y, cache
+
+
+def _apply_heads(params, x, caches, cfg: ModelConfig, block_fn):
+    """The leading dense layers, in order: ``block_fn(kind, p, x, cache)
+    -> (x, cache)``; returns (x, their new caches by key)."""
+    new = {}
+    for i, kind in enumerate(cfg.head_layers):
+        key = f"head{i}"
+        x, new[key] = block_fn(kind, params[key], x,
+                               None if caches is None else caches[key])
+    return x, new
 
 
 def apply_block(kind: str, p: dict, x: jax.Array, cfg: ModelConfig,
                 fcfg: FamousConfig, q_offset: int = 0) -> jax.Array:
     n = functools.partial(layers.apply_norm, kind=cfg.norm)
+    if kind == MLA:
+        return _mla_block(p, x, cfg, fcfg, lambda h: (mla.apply_mla(
+            p["attn"], h, cfg, fcfg, q_offset=q_offset), None))[0]
     if kind in (ATTN, LOCAL_ATTN):
         window = cfg.window if kind == LOCAL_ATTN else 0
         x = constrain_residual(x, cfg.num_heads)
@@ -141,6 +180,8 @@ def forward(params: dict, inputs: jax.Array, cfg: ModelConfig,
     logits tile-by-tile to avoid materialising the full logit tensor)."""
     x = _embed_inputs(params, inputs, cfg,
                       compute_dtype or params["final_norm"]["scale"].dtype)
+    x, _ = _apply_heads(params, x, None, cfg, lambda kind, p, x, _: (
+        apply_block(kind, p, x, cfg, fcfg), None))
 
     def unit_body(x, unit_params):
         for i, kind in enumerate(cfg.pattern_unit):
@@ -173,6 +214,15 @@ def logits_fn(params, x, cfg: ModelConfig):
 def _block_cache(kind: str, cfg: ModelConfig, batch: int, max_seq: int, dtype,
                  shapes_only: bool = False, cache_kind: str = "contiguous",
                  page_size: int = 0, n_pages: int = 0, kv_dtype: str = "fp"):
+    if kind == MLA:
+        if kv_dtype != "fp":
+            raise ValueError("latent attention (MLA) caches hold fp rows; "
+                             f"kv_dtype={kv_dtype!r} is not supported")
+        if cache_kind == "paged":
+            fn = mla.paged_cache_shape if shapes_only else mla.make_paged_cache
+            return fn(cfg, n_pages, page_size, dtype)
+        fn = mla.cache_shape if shapes_only else mla.make_cache
+        return fn(cfg, batch, max_seq, dtype)
     if kind == ATTN and cache_kind == "paged":
         # global-attention layers share a page pool; sliding-window and
         # recurrent layers are already O(window)/O(1) per slot and keep
@@ -230,10 +280,11 @@ def make_caches(cfg: ModelConfig, batch: int, max_seq: int, dtype,
             for i, k in enumerate(cfg.pattern_unit)}
     caches: dict[str, Any] = {
         "blocks": _stack_cache_tree(unit, cfg.num_units, shapes_only)}
-    for i, k in enumerate(cfg.tail_layers):
-        caches[f"tail{i}"] = _block_cache(k, cfg, batch, max_seq, dtype,
-                                          shapes_only, cache_kind, page_size,
-                                          n_pages, kv_dtype)
+    for name, kinds in (("tail", cfg.tail_layers), ("head", cfg.head_layers)):
+        for i, k in enumerate(kinds):
+            caches[f"{name}{i}"] = _block_cache(
+                k, cfg, batch, max_seq, dtype, shapes_only, cache_kind,
+                page_size, n_pages, kv_dtype)
     return caches
 
 
@@ -256,6 +307,9 @@ def cache_axes(cfg: ModelConfig, cache_kind: str = "contiguous",
     add the scale-pool leaves, so the tree structure tracks the cache
     structure exactly."""
     def block_axes(kind):
+        if kind == MLA:
+            return (mla.PAGED_CACHE_AXES if cache_kind == "paged"
+                    else mla.CACHE_AXES)
         if kind == ATTN and cache_kind == "paged":
             return (attention.PAGED_ATTN_CACHE_AXES_INT8
                     if kv_dtype == "int8"
@@ -273,11 +327,16 @@ def cache_axes(cfg: ModelConfig, cache_kind: str = "contiguous",
     axes: dict[str, Any] = {"blocks": stacked}
     for i, k in enumerate(cfg.tail_layers):
         axes[f"tail{i}"] = block_axes(k)
+    for i, k in enumerate(cfg.head_layers):
+        axes[f"head{i}"] = block_axes(k)
     return axes
 
 
 def _apply_block_prefill(kind, p, x, cache, cfg, fcfg):
     n = functools.partial(layers.apply_norm, kind=cfg.norm)
+    if kind == MLA:
+        return _mla_block(p, x, cfg, fcfg, lambda h: mla.apply_mla_prefill(
+            p["attn"], h, cache, cfg, fcfg))
     if kind in (ATTN, LOCAL_ATTN):
         window = cfg.window if kind == LOCAL_ATTN else 0
         a, cache = attention.apply_attn_prefill(p["attn"], n(p["ln1"], x),
@@ -301,8 +360,18 @@ def _apply_block_prefill(kind, p, x, cache, cfg, fcfg):
 
 
 def _apply_block_decode(kind, p, x, cache, cache_len, cfg, fcfg,
-                        page_table=None):
+                        page_table=None, active=None, counts=None):
     n = functools.partial(layers.apply_norm, kind=cfg.norm)
+    if kind == MLA:
+        def attend(h):
+            if page_table is not None:
+                return mla.apply_mla_decode_paged(p["attn"], h, cache,
+                                                  page_table, cache_len, cfg,
+                                                  fcfg)
+            return mla.apply_mla_decode(p["attn"], h, cache, cache_len, cfg,
+                                        fcfg)
+        valid = None if active is None else active[:, None]
+        return _mla_block(p, x, cfg, fcfg, attend, valid, counts)
     if kind == ATTN and page_table is not None:
         a, cache = attention.apply_attn_decode_paged(
             p["attn"], n(p["ln1"], x), cache, page_table, cache_len, cfg, fcfg)
@@ -337,6 +406,9 @@ def prefill(params, inputs, caches, cfg: ModelConfig,
     """Returns (last-position logits (B, vocab), new caches)."""
     dtype = compute_dtype or params["final_norm"]["scale"].dtype
     x = _embed_inputs(params, inputs, cfg, dtype)
+    x, head_caches = _apply_heads(
+        params, x, caches, cfg, lambda kind, p, x, c: _apply_block_prefill(
+            kind, p, x, c, cfg, fcfg))
 
     def unit_body(x, scanned):
         unit_params, unit_cache = scanned
@@ -349,7 +421,7 @@ def prefill(params, inputs, caches, cfg: ModelConfig,
 
     x, new_block_caches = jax.lax.scan(
         unit_body, x, (params["blocks"], caches["blocks"]))
-    new_caches = {"blocks": new_block_caches}
+    new_caches = {"blocks": new_block_caches, **head_caches}
     for i, kind in enumerate(cfg.tail_layers):
         x, new_caches[f"tail{i}"] = _apply_block_prefill(
             kind, params[f"tail{i}"], x, caches[f"tail{i}"], cfg, fcfg)
@@ -367,7 +439,7 @@ def _mask_state_update(kind: str, new_cache, old_cache, active):
     unconditionally and carried across prefill chunks — a junk update
     between chunks would corrupt it, so it only commits where ``active``.
     """
-    if active is None or kind in (ATTN, LOCAL_ATTN):
+    if active is None or kind in (ATTN, LOCAL_ATTN, MLA):
         return new_cache
     return jax.tree_util.tree_map(
         lambda nc, oc: jnp.where(
@@ -378,41 +450,49 @@ def _mask_state_update(kind: str, new_cache, old_cache, active):
 
 def decode_step(params, tokens, caches, cache_len, cfg: ModelConfig,
                 fcfg: FamousConfig = FamousConfig(), compute_dtype=None,
-                page_table=None, active=None):
+                page_table=None, active=None, moe_counts: bool = False):
     """tokens: (B,) int32 (or (B, D) embeddings); cache_len: (B,).
     page_table: optional (B, pages_per_slot) int32 — when given, global
     attention layers treat their caches as shared page pools (see
     ``make_caches(cache_kind="paged")``); when None, caches are the
     contiguous per-slot baseline.  active: optional (B,) bool — slots not
     decoding this step (free, or mid-chunked-prefill) keep their recurrent
-    state untouched.  Returns (logits (B, vocab), new caches)."""
+    state untouched, and their tokens take no held expert's rows.
+    Returns (logits (B, vocab), new caches), and with ``moe_counts`` also
+    the held-expert layers' row counts summed over layers
+    (``moe.apply_moe_serving``'s ``counts``)."""
     dtype = compute_dtype or params["final_norm"]["scale"].dtype
     inputs = tokens[:, None] if tokens.ndim == 1 else tokens[:, None, :]
     x = _embed_inputs(params, inputs, cfg, dtype)
+    counts = [] if moe_counts else None
+
+    def block(kind, p, x, cache, sink=None):
+        x, new = _apply_block_decode(kind, p, x, cache, cache_len, cfg, fcfg,
+                                     page_table, active, sink)
+        return x, _mask_state_update(kind, new, cache, active)
+
+    x, head_caches = _apply_heads(params, x, caches, cfg, block)
 
     def unit_body(x, scanned):
         unit_params, unit_cache = scanned
-        new_caches = {}
+        new_caches, sink = {}, [] if moe_counts else None
         for i, kind in enumerate(cfg.pattern_unit):
             key = f"pos{i}"
-            x, new = _apply_block_decode(
-                kind, unit_params[key], x, unit_cache[key], cache_len, cfg,
-                fcfg, page_table)
-            new_caches[key] = _mask_state_update(kind, new, unit_cache[key],
-                                                 active)
-        return x, new_caches
+            x, new_caches[key] = block(kind, unit_params[key], x,
+                                       unit_cache[key], sink)
+        return x, (new_caches, sum(sink)) if moe_counts else new_caches
 
-    x, new_block_caches = jax.lax.scan(
-        unit_body, x, (params["blocks"], caches["blocks"]))
-    new_caches = {"blocks": new_block_caches}
+    x, ys = jax.lax.scan(unit_body, x, (params["blocks"], caches["blocks"]))
+    new_block_caches, unit_counts = ys if moe_counts else (ys, None)
+    new_caches = {"blocks": new_block_caches, **head_caches}
     for i, kind in enumerate(cfg.tail_layers):
-        x, new = _apply_block_decode(
-            kind, params[f"tail{i}"], x, caches[f"tail{i}"], cache_len, cfg,
-            fcfg, page_table)
-        new_caches[f"tail{i}"] = _mask_state_update(kind, new,
-                                                    caches[f"tail{i}"], active)
+        x, new_caches[f"tail{i}"] = block(kind, params[f"tail{i}"], x,
+                                          caches[f"tail{i}"], counts)
     x = layers.apply_norm(params["final_norm"], x, cfg.norm)
-    return logits_fn(params, x, cfg)[:, 0], new_caches
+    logits = logits_fn(params, x, cfg)[:, 0]
+    if not moe_counts:
+        return logits, new_caches
+    return logits, new_caches, sum(counts, jnp.sum(unit_counts, axis=0))
 
 
 def _apply_block_verify(kind, p, x, cache, cache_len, cfg, fcfg,
@@ -507,6 +587,10 @@ def _write_slot_state(cache, sub, slot):
 def _apply_block_chunk(kind, p, x, cache, slot, offset, n_valid, cfg, fcfg,
                        page_table):
     n = functools.partial(layers.apply_norm, kind=cfg.norm)
+    if kind == MLA:
+        valid = (jnp.arange(x.shape[1]) < n_valid)[None]
+        return _mla_block(p, x, cfg, fcfg, lambda h: mla.apply_mla_chunk_paged(
+            p["attn"], h, cache, page_table, slot, offset, cfg, fcfg), valid)
     if kind == ATTN and page_table is not None:
         a, cache = attention.apply_attn_chunk_paged(
             p["attn"], n(p["ln1"], x), cache, page_table, slot, offset, cfg,
@@ -560,6 +644,9 @@ def prefill_chunk(params, tokens, caches, slot, offset, n_valid,
     """
     dtype = compute_dtype or params["final_norm"]["scale"].dtype
     x = _embed_inputs(params, tokens, cfg, dtype)
+    x, head_caches = _apply_heads(
+        params, x, caches, cfg, lambda kind, p, x, c: _apply_block_chunk(
+            kind, p, x, c, slot, offset, n_valid, cfg, fcfg, page_table))
 
     def unit_body(x, scanned):
         unit_params, unit_cache = scanned
@@ -573,7 +660,7 @@ def prefill_chunk(params, tokens, caches, slot, offset, n_valid,
 
     x, new_block_caches = jax.lax.scan(
         unit_body, x, (params["blocks"], caches["blocks"]))
-    new_caches = {"blocks": new_block_caches}
+    new_caches = {"blocks": new_block_caches, **head_caches}
     for i, kind in enumerate(cfg.tail_layers):
         x, new_caches[f"tail{i}"] = _apply_block_chunk(
             kind, params[f"tail{i}"], x, caches[f"tail{i}"], slot, offset,
@@ -674,17 +761,16 @@ def clear_slot(caches, slot, cfg: ModelConfig, paged: bool = False) -> dict:
             return buf.at[idx].set(jnp.zeros((), buf.dtype))
         return jax.tree_util.tree_map(z, tree)
 
+    pooled = (ATTN, MLA) if paged else ()
     out: dict[str, Any] = {"blocks": {}}
     for i, kind in enumerate(cfg.pattern_unit):
         key = f"pos{i}"
-        if paged and kind == ATTN:
+        if kind in pooled:
             out["blocks"][key] = caches["blocks"][key]
         else:
             out["blocks"][key] = zero_tree(caches["blocks"][key], 1)
-    for i, kind in enumerate(cfg.tail_layers):
-        key = f"tail{i}"
-        if paged and kind == ATTN:
-            out[key] = caches[key]
-        else:
-            out[key] = zero_tree(caches[key], 0)
+    for key, kind in ([(f"tail{i}", k) for i, k in enumerate(cfg.tail_layers)]
+                      + [(f"head{i}", k)
+                         for i, k in enumerate(cfg.head_layers)]):
+        out[key] = caches[key] if kind in pooled else zero_tree(caches[key], 0)
     return out

@@ -146,6 +146,19 @@ class Observer:
             "repro_draft_lookups_total", "drafter probes", ("result",))
         self._draft_proposed = m.counter(
             "repro_draft_proposed_tokens_total", "tokens drafters proposed")
+        # -- held experts (dropless serving MoE) ----------------------------
+        self._moe_rows = m.counter(
+            "repro_moe_expert_rows_total",
+            "(token, expert) rows each held expert computed in decode "
+            "launches, summed over layers", ("expert",))
+        self._moe_routed = m.counter(
+            "repro_moe_routed_rows_total",
+            "(token, expert) pairs routed in decode launches, held here or "
+            "not, summed over layers")
+        self._moe_hits = m.counter(
+            "repro_moe_experts_hit_total",
+            "held experts with at least one row in a decode launch's layer "
+            "(the expert weights it reads), summed over layers")
         # -- executables ----------------------------------------------------
         self._compilations = m.gauge(
             "repro_engine_compilations",
@@ -216,6 +229,14 @@ class Observer:
 
     def on_decode_launch(self) -> None:
         self._decode_launches.inc()
+
+    def on_moe_rows(self, rows: dict, routed: int, hit: int) -> None:
+        """One decode launch's held-expert rows ({expert id: rows}), routed
+        pairs and held experts hit, each summed over the MoE layers."""
+        for expert, n in rows.items():
+            self._moe_rows.inc(n, expert=str(expert))
+        self._moe_routed.inc(routed)
+        self._moe_hits.inc(hit)
 
     def on_preempt(self, rid, slot: int) -> None:
         self._preempts.inc()
@@ -293,6 +314,7 @@ class NullObserver:
     def on_prefill_tokens(self, n) -> None: pass
     def on_tokens(self, n) -> None: pass
     def on_decode_launch(self) -> None: pass
+    def on_moe_rows(self, rows, routed, hit) -> None: pass
     def on_preempt(self, rid, slot) -> None: pass
     def on_retire(self, req, slot=-1) -> None: pass
     def on_spec_step(self) -> None: pass

@@ -63,11 +63,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ATTN, LOCAL_ATTN, ModelConfig
+from repro.configs.base import ATTN, LOCAL_ATTN, MLA, ModelConfig
 from repro.core.famous import FamousConfig
 from repro.core.flexible import next_pow2
 from repro.kernels.decode.decode_attn import pages_per_block
-from repro.models import transformer
+from repro.models import mla, transformer
 from repro.obs.runtime import NULL_OBSERVER
 from repro.obs.trace import now as _clock
 from repro.parallel import sharding as shardlib
@@ -186,6 +186,18 @@ class ServingEngine:
         # all-global-ATTN stacks run speculatively; other archs fall back
         # to plain decode explicitly (`speculative_active` False).
         assert draft_k >= 1, draft_k
+        # latent attention (kv_lora_rank > 0) serves from a paged pool of
+        # fp latent rows on one device, by chunked prefill and plain decode
+        self.latent = cfg.kv_lora_rank > 0
+        if self.latent:
+            for refused, what in ((kv_dtype != "fp", f"kv_dtype={kv_dtype!r}"),
+                                  (speculative, "speculative decoding"),
+                                  (not self.chunked, "monolithic prefill"),
+                                  (cache_kind != "paged", "a contiguous cache"),
+                                  (mesh is not None, "a device mesh")):
+                if refused:
+                    raise ValueError(f"{cfg.name}: latent attention (MLA) "
+                                     f"does not support {what}")
         self.draft_k = draft_k
         all_attn = all(
             k == ATTN for k in tuple(cfg.pattern_unit) + tuple(cfg.tail_layers))
@@ -207,10 +219,12 @@ class ServingEngine:
             self.pcfg = PagedCacheConfig(page_size=page_size, n_pages=n_pages)
             self.alloc = PageAllocator(self.pcfg, n_slots, max_seq,
                                        observer=self.obs)
-            # tokens per block of the paged decode kernel's walk
+            # tokens per block of the paged decode kernel's walk (a latent
+            # pool is one kv head of stored latent rows)
             self._kv_block_tokens = page_size * pages_per_block(
                 self.alloc.pages_per_slot, page_size, cfg.num_kv_heads,
-                cfg.head_dim, jnp.int8 if kv_dtype == "int8" else dtype)
+                mla.stored_width(cfg) if self.latent else cfg.head_dim,
+                jnp.int8 if kv_dtype == "int8" else dtype)
             self.caches = transformer.make_caches(
                 cfg, n_slots, max_seq, dtype, cache_kind="paged",
                 page_size=page_size, n_pages=n_pages, kv_dtype=kv_dtype)
@@ -269,7 +283,8 @@ class ServingEngine:
             assert prefill_mode == "chunked", \
                 "prefix_cache requires chunked prefill (runtime offsets)"
         self.prefix_shareable = all(
-            k == ATTN for k in tuple(cfg.pattern_unit) + tuple(cfg.tail_layers))
+            k in (ATTN, MLA) for k in tuple(cfg.pattern_unit)
+            + tuple(cfg.tail_layers) + tuple(cfg.head_layers))
         self.prefix_cache_active = prefix_cache and self.prefix_shareable
         self.prefix_lookups = 0        # admissions that probed the index
         self.prefix_hit_pages = 0      # pages aliased instead of allocated
@@ -291,8 +306,12 @@ class ServingEngine:
         self._prefill_chunk_exec = jax.jit(
             _named(transformer.prefill_chunk, cfg=cfg, fcfg=fcfg),
             donate_argnums=2, **self._jit_kw_caches)
+        # held-expert row counts ride the decode step's outputs only when
+        # an Observer is there to take them
+        self._moe_counts = bool(cfg.held_experts) and observer is not None
         self._decode = jax.jit(
-            _named(transformer.decode_step, cfg=cfg, fcfg=fcfg),
+            _named(transformer.decode_step, cfg=cfg, fcfg=fcfg,
+                   **({"moe_counts": True} if self._moe_counts else {})),
             donate_argnums=2, **self._jit_kw_logits)
         # the speculative path REPLACES decode with one fixed-shape verify
         # executable (batch n_slots, width draft_k+1, per-slot runtime
@@ -658,11 +677,9 @@ class ServingEngine:
             act[active] = True
             act_dev = jnp.asarray(act)
             kw = {"page_table": self._page_table()} if self.paged else {}
-            logits, self.caches = self._decode(self.params,
-                                               jnp.asarray(self.last_token),
-                                               self.caches,
-                                               jnp.asarray(self.cache_len),
-                                               active=act_dev, **kw)
+            logits, self.caches, *moe_rows = self._decode(
+                self.params, jnp.asarray(self.last_token), self.caches,
+                jnp.asarray(self.cache_len), active=act_dev, **kw)
             temps, topks, seeds, idxs = self._sampling_operands(active)
             if temps.any():
                 # k_cap: pow-2 roundup of the largest requested top-k, so
@@ -679,6 +696,11 @@ class ServingEngine:
             self.obs.on_decode_launch()
             with self.obs.span("engine.sync"):
                 toks = np.asarray(next_tok)   # the step's ONE host sync
+                # the counts came from the same launch: already on hand
+                rows = [np.asarray(r).tolist() for r in moe_rows]
+            for *r, routed, hit in rows:
+                self.obs.on_moe_rows(dict(zip(self.cfg.held_experts, r)),
+                                     routed, hit)
         with self.obs.span("engine.retire") as sp:
             n0 = len(finished)
             self.cache_len[act] += 1

@@ -700,3 +700,66 @@ def test_request_marks_come_from_the_shared_clock():
     for r in done:
         assert t0 <= r.t_submit <= r.t_first <= r.t_done <= t1, \
             (r.rid, r.t_submit, r.t_first, r.t_done, t0, t1)
+
+
+@functools.lru_cache(maxsize=None)
+def _latent_runs():
+    """deepseek-v2-lite (shrunk) served with the Observer off and on."""
+    cfg = shrink(get_config("deepseek-v2-lite"))
+    params = module.init_params(transformer.model_spec(cfg),
+                                jax.random.PRNGKey(0), jnp.float32)
+    runs = []
+    for obs in (None, Observer(span=SpanLog())):
+        eng = ServingEngine(params, cfg, FamousConfig(impl="xla"),
+                            n_slots=2, max_seq=MAX_SEQ, chunk=CHUNK,
+                            cache_kind="paged", page_size=8, observer=obs)
+        done = sorted(eng.run(_requests(cfg, n=4, seed=3)),
+                      key=lambda r: r.rid)
+        runs.append((eng, obs, done))
+    return cfg, runs
+
+
+def test_moe_counters_count_held_rows_only_with_an_observer():
+    cfg, ((off, _, done_off), (on, obs, done_on)) = _latent_runs()
+    assert [r.out for r in done_on] == [r.out for r in done_off]
+    assert not off._moe_counts and on._moe_counts
+    m = obs.metrics
+    moe_layers = cfg.num_layers - cfg.first_dense_layers
+    tokens = m.get("repro_tokens_generated_total").value()
+    # every decoded token routes k pairs in every MoE layer; the held
+    # experts computed at most those
+    routed = m.get("repro_moe_routed_rows_total").value()
+    assert routed == tokens * cfg.experts_per_token * moe_layers > 0
+    rows = m.get("repro_moe_expert_rows_total")
+    assert {k[0] for k in rows.label_keys()} == {
+        str(e) for e in cfg.held_experts}
+    held = sum(rows.value(expert=str(e)) for e in cfg.held_experts)
+    assert 0 < held <= routed
+    # each launch's layer reads the held experts that took a row: at
+    # least one and at most all of them while any row was computed
+    hits = m.get("repro_moe_experts_hit_total").value()
+    launches = m.get("repro_decode_launches_total").value()
+    assert 0 < hits <= min(held, launches * moe_layers
+                           * len(cfg.held_experts))
+    assert validate_prometheus_text(obs.prometheus_text()) > 0
+
+
+def test_latent_decode_span_counts_the_latent_kernels_blocks():
+    from repro.kernels.decode.decode_attn import pages_per_block
+    from repro.models import mla
+    cfg, ((_, _, _), (on, obs, _)) = _latent_runs()
+    # the runs' slots hold one block (max_seq 32): one per launched slot
+    decodes = [s["args"] for s in obs._span.spans
+               if s["name"] == "engine.decode"]
+    assert decodes and all(a["kv_blocks"] == a["slots"] for a in decodes)
+    # one latent row per token: ceil(length / block) blocks a row, no
+    # kv-head factor
+    eng = ServingEngine(on.params, cfg, FamousConfig(impl="xla"), n_slots=3,
+                        max_seq=1024, chunk=CHUNK, cache_kind="paged",
+                        page_size=16)
+    bt = eng._kv_block_tokens
+    assert bt == 16 * pages_per_block(64, 16, 1, mla.stored_width(cfg),
+                                      jnp.float32) and bt < 1024
+    eng.cache_len[:] = [bt - 1, bt, 1022]
+    assert eng._span_costs([0, 1, 2]) == {
+        "ctx_tokens": 2 * bt - 1 + 1022 + 3, "kv_blocks": 1 + 2 + -(-1023 // bt)}

@@ -1,8 +1,10 @@
 """Ahead-of-time compiles of the serving main path's Pallas kernels for a
-TPU v5e at qwen2-7b's published widths (KV 4, group 7, dh 128), and of the
-paged decode kernels at deepseek-7b's (KV 32, group 1), at the serving
-sizes of ``repro.kernels.catalog`` (page 16, 4 slots x 128 pages, chunk
-32; bf16 and int8).
+TPU v5e at qwen2-7b's published widths (KV 4, group 7, dh 128), of the
+paged decode kernels at deepseek-7b's (KV 32, group 1), and of
+deepseek-v2-lite's latent decode kernel (16 heads over 640-wide stored
+latent rows) and held-expert grouped matmul, at the serving sizes of
+``repro.kernels.catalog`` (page 16, 4 slots x 128 pages, chunk 32; bf16
+and int8).
 
 Interpret-mode tests never apply Mosaic's tiling rules; the TPU compiler
 installed with jax does, for a chip that is described and not attached.
@@ -18,7 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import get_config
-from repro.kernels.catalog import main_path_cases
+from repro.kernels.catalog import latent_cases, main_path_cases
 
 CASES = {c.name: c for c in main_path_cases(get_config("qwen2-7b"))}
 # the paged decode kernel at deepseek-7b's widths too: MHA (32 kv heads,
@@ -26,6 +28,8 @@ CASES = {c.name: c for c in main_path_cases(get_config("qwen2-7b"))}
 CASES.update({f"deepseek-7b/{c.name}": c
               for c in main_path_cases(get_config("deepseek-7b"))
               if c.name in ("paged_decode", "paged_decode_int8")})
+CASES.update({f"deepseek-v2-lite/{c.name}": c
+              for c in latent_cases(get_config("deepseek-v2-lite"))})
 
 
 @pytest.fixture(scope="module")
