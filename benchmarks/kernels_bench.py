@@ -93,7 +93,8 @@ def run():
 
     @jax.jit
     def paged_decode(q, kp, vp, pt, lens):
-        return famous.paged_decode_attention(q, kp, vp, pt, lens, cfg=dcfg)
+        return famous.paged_decode_attention(q, kp[None], vp[None], pt, lens, 0,
+                                             cfg=dcfg)
 
     us = common.timeit(paged_decode, qd, kp, vp, ids, lens)
     common.emit("kernels/decode_paged_gather_xla", us,
@@ -103,7 +104,8 @@ def run():
 
     @jax.jit
     def paged_decode_pl(q, kp, vp, pt, lens):
-        return famous.paged_decode_attention(q, kp, vp, pt, lens, cfg=pcfg)
+        return famous.paged_decode_attention(q, kp[None], vp[None], pt, lens, 0,
+                                             cfg=pcfg)
 
     us = common.timeit(paged_decode_pl, qd, kp, vp, ids, lens,
                        warmup=1, iters=3)
@@ -121,8 +123,9 @@ def run():
 
     @jax.jit
     def paged_decode_q8(q, kp, vp, ks, vs, pt, lens):
-        return famous.paged_decode_attention(q, kp, vp, pt, lens,
-                                             k_scale=ks, v_scale=vs,
+        return famous.paged_decode_attention(q, kp[None], vp[None], pt, lens,
+                                             0, k_scale=ks[None],
+                                             v_scale=vs[None],
                                              cfg=dcfg)
 
     us = common.timeit(paged_decode_q8, qd, kq8, vq8, kscale, vscale,
@@ -136,8 +139,9 @@ def run():
 
     @jax.jit
     def paged_decode_q8_pl(q, kp, vp, ks, vs, pt, lens):
-        return famous.paged_decode_attention(q, kp, vp, pt, lens,
-                                             k_scale=ks, v_scale=vs,
+        return famous.paged_decode_attention(q, kp[None], vp[None], pt, lens,
+                                             0, k_scale=ks[None],
+                                             v_scale=vs[None],
                                              cfg=pcfg)
 
     us = common.timeit(paged_decode_q8_pl, qd, kq8, vq8, kscale, vscale,

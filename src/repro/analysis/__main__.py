@@ -97,28 +97,29 @@ def run_kernel_check() -> int:
         pt = jnp.asarray(np.arange(1, 9, dtype=np.int32).reshape(2, 4))
         launches.append(("decode/paged_decode_attention",
                          lambda: paged_decode_attention(
-                             arr(2, 1, 2, 8), arr(1, 9, 4, 8),
-                             arr(1, 9, 4, 8), pt,
-                             jnp.array([5, 9], jnp.int32), interpret=True)))
+                             arr(2, 1, 2, 8), arr(2, 1, 9, 4, 8),
+                             arr(2, 1, 9, 4, 8), pt,
+                             jnp.array([5, 9], jnp.int32), 1,
+                             interpret=True)))
         launches.append(("decode/paged_decode_attention_int8",
                          lambda: paged_decode_attention_int8(
-                             arr(2, 1, 2, 8), qarr(1, 9, 4, 8),
-                             qarr(1, 9, 4, 8), sarr(1, 9, 1, 4), sarr(1, 9, 1, 4),
-                             pt, jnp.array([5, 9], jnp.int32),
+                             arr(2, 1, 2, 8), qarr(2, 1, 9, 4, 8),
+                             qarr(2, 1, 9, 4, 8), sarr(2, 1, 9, 1, 4), sarr(2, 1, 9, 1, 4),
+                             pt, jnp.array([5, 9], jnp.int32), 1,
                              interpret=True)))
         launches.append(("decode/chunk_prefill", lambda: chunk_prefill(
             arr(2, 8, 8), arr(2, 16, 8), arr(2, 16, 8), 4, chunk=4,
             block_k=8, interpret=True)))
         launches.append(("decode/paged_chunk_prefill",
                          lambda: paged_chunk_prefill(
-                             arr(2, 1, 8, 8), arr(1, 9, 4, 8),
-                             arr(1, 9, 4, 8), pt, 4, chunk=4,
+                             arr(2, 1, 8, 8), arr(2, 1, 9, 4, 8),
+                             arr(2, 1, 9, 4, 8), pt, 4, 1, chunk=4,
                              interpret=True)))
         launches.append(("decode/paged_chunk_prefill_int8",
                          lambda: paged_chunk_prefill_int8(
-                             arr(2, 1, 8, 8), qarr(1, 9, 4, 8),
-                             qarr(1, 9, 4, 8), sarr(1, 9, 1, 4), sarr(1, 9, 1, 4),
-                             pt, 4, chunk=4, interpret=True)))
+                             arr(2, 1, 8, 8), qarr(2, 1, 9, 4, 8),
+                             qarr(2, 1, 9, 4, 8), sarr(2, 1, 9, 1, 4), sarr(2, 1, 9, 1, 4),
+                             pt, 4, 1, chunk=4, interpret=True)))
         launches.append(("scan/rglru_scan", lambda: rglru_scan(
             arr(2, 16, 256), arr(2, 16, 256), block_r=128, block_s=8,
             interpret=True)))

@@ -74,8 +74,8 @@ class FamousConfig:
 # Where each kernel wrapper's operands keep their heads (or kv heads): the
 # dim index, or None for operands every device holds whole.
 HEADS = 2        # (B, S, H|KV, dh) activations and contiguous caches
-POOL = 0         # (KV, n_pages, ...) page and scale pools
-REP = None       # page tables, lengths, offsets, x
+POOL = 1         # (L, KV, n_pages, ...) the layer stack's page and scale pools
+REP = None       # page tables, lengths, offsets, layer indices, x
 
 
 def _per_device(cfg: FamousConfig, fn, in_dims, out_dims, *args):
@@ -449,48 +449,56 @@ def verify_attention(q, k_cache, v_cache, cache_len, *, scale=None,
 
 
 def _paged_kernel(cfg, op, q, k_pages, v_pages, page_table, lens_or_offset,
-                  scale, k_scale, v_scale):
+                  layer, scale, k_scale, v_scale):
     """Dispatch one paged Pallas wrapper of kernels/decode/ops.py (fp or
     int8 pools) per device of ``cfg.mesh``."""
     from repro.kernels.decode import ops as dec_ops
     if k_scale is None:
         fn = functools.partial(getattr(dec_ops, op), scale=scale)
-        return _per_device(cfg, fn, (HEADS, POOL, POOL, REP, REP), HEADS,
-                           q, k_pages, v_pages, page_table, lens_or_offset)
+        return _per_device(cfg, fn, (HEADS, POOL, POOL, REP, REP, REP), HEADS,
+                           q, k_pages, v_pages, page_table, lens_or_offset,
+                           layer)
     fn = functools.partial(getattr(dec_ops, op + "_int8"), scale=scale)
-    return _per_device(cfg, fn, (HEADS,) + (POOL,) * 4 + (REP, REP), HEADS,
+    return _per_device(cfg, fn, (HEADS,) + (POOL,) * 4 + (REP,) * 3, HEADS,
                        q, k_pages, v_pages, k_scale, v_scale, page_table,
-                       lens_or_offset)
+                       lens_or_offset, layer)
 
 
-def paged_verify_attention(q, k_pages, v_pages, page_table, cache_len, *,
-                           scale=None, k_scale=None, v_scale=None,
+def _gather_layer(k_pages, v_pages, page_table, layer, k_scale, v_scale):
+    """Layer ``layer``'s K/V of each slot's table, gathered from the stacked
+    pools into contiguous (B, n_p * page_size, KV, dh) views (dequantized
+    when the pools are int8) — the XLA reference path."""
+    from repro.kernels.decode.ref import gather_pages, gather_pages_int8
+    if k_scale is not None:
+        return (gather_pages_int8(k_pages, k_scale, page_table, layer),
+                gather_pages_int8(v_pages, v_scale, page_table, layer))
+    return (gather_pages(k_pages, page_table, layer),
+            gather_pages(v_pages, page_table, layer))
+
+
+def paged_verify_attention(q, k_pages, v_pages, page_table, cache_len, layer,
+                           *, scale=None, k_scale=None, v_scale=None,
                            cfg: FamousConfig = FamousConfig()):
     """Speculative-verify attention against a *paged* KV cache.
 
-    q: (B, W, H, dh) at per-slot positions ``cache_len[b] + j``; pools:
-    (KV, n_pages, page_size, dh); page_table: (B, n_p) int32.  impl=
-    "pallas" flattens (slot, verify position) pairs into rows of the
-    scalar-prefetched page-table decode kernel; other impls gather the
-    table into a contiguous view and reuse :func:`verify_attention`.
+    q: (B, W, H, dh) at per-slot positions ``cache_len[b] + j``; pools: the
+    layer stack's (L, KV, n_pages, page_size, dh); page_table: (B, n_p)
+    int32; layer: () int32, the layer read.  impl="pallas" flattens (slot,
+    verify position) pairs into rows of the scalar-prefetched page-table
+    decode kernel; other impls gather the table into a contiguous view and
+    reuse :func:`verify_attention`.
 
-    With ``k_scale``/``v_scale`` (fp32 (KV, n_pages, 1, page_size) pools) the
-    K/V pools are int8 and dequantized in-kernel (pallas) or via the
-    dequantizing gather (other impls) — the ``kv_dtype="int8"`` path.
+    With ``k_scale``/``v_scale`` (fp32 (L, KV, n_pages, 1, page_size)
+    pools) the K/V pools are int8 and dequantized in-kernel (pallas) or via
+    the dequantizing gather (other impls) — the ``kv_dtype="int8"`` path.
     """
     dh = q.shape[-1]
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(dh)
     if cfg.impl == "pallas":
         return _paged_kernel(cfg, "paged_verify_attention", q, k_pages, v_pages,
-                             page_table, cache_len, scale, k_scale,
+                             page_table, cache_len, layer, scale, k_scale,
                              v_scale)
-    from repro.kernels.decode.ref import gather_pages, gather_pages_int8
-    if k_scale is not None:
-        k = gather_pages_int8(k_pages, k_scale, page_table)
-        v = gather_pages_int8(v_pages, v_scale, page_table)
-    else:
-        k = gather_pages(k_pages, page_table)
-        v = gather_pages(v_pages, page_table)
+    k, v = _gather_layer(k_pages, v_pages, page_table, layer, k_scale, v_scale)
     return verify_attention(q, k, v, cache_len, scale=scale, cfg=cfg)
 
 
@@ -556,13 +564,15 @@ def chunked_prefill_attention(q, k_cache, v_cache, q_offset, *, scale=None,
 
 
 def paged_chunked_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
-                                    *, scale=None, k_scale=None, v_scale=None,
+                                    layer, *, scale=None, k_scale=None,
+                                    v_scale=None,
                                     cfg: FamousConfig = FamousConfig()):
     """Chunked-prefill attention against a *paged* KV cache.
 
-    q: (B, C, H, dh) at positions ``[q_offset, q_offset + C)``; pools:
-    (KV, n_pages, page_size, dh); page_table: (B, n_p) int32.  The chunk's
-    K/V must already be scattered into the slot's pages.  impl="pallas"
+    q: (B, C, H, dh) at positions ``[q_offset, q_offset + C)``; pools: the
+    layer stack's (L, KV, n_pages, page_size, dh); page_table: (B, n_p)
+    int32; layer: () int32.  The chunk's K/V must already be scattered
+    into the slot's pages of that layer.  impl="pallas"
     reuses the scalar-prefetched page-table BlockSpec machinery of
     ``paged_decode_attention``; other impls gather the table into a
     contiguous view and run the dense chunked reference.  ``k_scale``/
@@ -571,27 +581,22 @@ def paged_chunked_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
     B, C, H, dh = q.shape
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(dh)
     if cfg.impl == "pallas":
-        return _paged_kernel(cfg, "paged_chunk_prefill_attention", q, k_pages, v_pages,
-                             page_table, q_offset, scale, k_scale,
-                             v_scale)
-    from repro.kernels.decode.ref import gather_pages, gather_pages_int8
-    if k_scale is not None:
-        k = gather_pages_int8(k_pages, k_scale, page_table)
-        v = gather_pages_int8(v_pages, v_scale, page_table)
-    else:
-        k = gather_pages(k_pages, page_table)
-        v = gather_pages(v_pages, page_table)
+        return _paged_kernel(cfg, "paged_chunk_prefill_attention", q, k_pages,
+                             v_pages, page_table, q_offset, layer, scale,
+                             k_scale, v_scale)
+    k, v = _gather_layer(k_pages, v_pages, page_table, layer, k_scale, v_scale)
     return chunked_prefill_attention(q, k, v, q_offset, scale=scale, cfg=cfg)
 
 
-def paged_decode_attention(q, k_pages, v_pages, page_table, cache_len, *,
-                           scale=None, k_scale=None, v_scale=None,
+def paged_decode_attention(q, k_pages, v_pages, page_table, cache_len, layer,
+                           *, scale=None, k_scale=None, v_scale=None,
                            cfg: FamousConfig = FamousConfig()):
     """One-token attention against a *paged* KV cache.
 
-    q: (B, 1, H, dh); pools: (KV, n_pages, page_size, dh) shared by every
-    sequence; page_table: (B, n_p) int32 page ids per slot; cache_len: (B,)
-    int32 valid entries (the new token's k/v already written to its page).
+    q: (B, 1, H, dh); pools: the layer stack's (L, KV, n_pages, page_size,
+    dh), shared by every sequence; page_table: (B, n_p) int32 page ids per
+    slot; cache_len: (B,) int32 valid entries (the new token's k/v already
+    written to its page); layer: () int32, the layer read.
 
     impl="pallas" streams pages directly via a scalar-prefetched page table
     (kernels/decode); other impls gather the table into a contiguous
@@ -603,15 +608,9 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, cache_len, *,
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(dh)
     if cfg.impl == "pallas":
         return _paged_kernel(cfg, "paged_decode_attention", q, k_pages, v_pages,
-                             page_table, cache_len, scale, k_scale,
+                             page_table, cache_len, layer, scale, k_scale,
                              v_scale)
-    from repro.kernels.decode.ref import gather_pages, gather_pages_int8
-    if k_scale is not None:
-        k = gather_pages_int8(k_pages, k_scale, page_table)
-        v = gather_pages_int8(v_pages, v_scale, page_table)
-    else:
-        k = gather_pages(k_pages, page_table)
-        v = gather_pages(v_pages, page_table)
+    k, v = _gather_layer(k_pages, v_pages, page_table, layer, k_scale, v_scale)
     return decode_attention(q, k, v, cache_len, scale=scale, cfg=cfg)
 
 

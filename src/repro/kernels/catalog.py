@@ -16,7 +16,9 @@ its paged decode kernel and the held-expert grouped matmul — at its own
 widths.  Shapes are those of the engine ``chip_smoke.py`` serves, which
 imports the sizes below: ``SLOTS`` slots of ``MAX_SEQ`` tokens in ``PAGE``-token
 pages (a pool of ``SLOTS * MAX_SEQ / PAGE`` pages plus the null page), one
-``CHUNK``-token prefill chunk, one decode token per slot, in bf16.
+``CHUNK``-token prefill chunk, one decode token per slot, in bf16.  The
+paged kernels read the last layer of a ``LAYERS``-layer stack of pools, as
+the layer scan hands them theirs.
 """
 from __future__ import annotations
 
@@ -35,6 +37,7 @@ from repro.kernels.decode import ref as dec_ref
 from repro.kernels.qkv import qkv_proj
 
 SLOTS, MAX_SEQ, PAGE, CHUNK = 4, 2048, 16, 32
+LAYERS = 2
 DTYPE = jnp.bfloat16
 
 
@@ -48,7 +51,7 @@ class KernelCase:
 
 def _int8_pool(key, shape):
     """Symmetric per-token int8 quantization of a normal pool: (int8 pool
-    (KV, n_pages, ps, dh), fp32 scale rows (KV, n_pages, 1, ps))."""
+    (..., n_pages, ps, dh), fp32 scale rows (..., n_pages, 1, ps))."""
     q, s = quant_lib.quantize(jax.random.normal(key, shape), axis=-1)
     return q, s.swapaxes(-1, -2)
 
@@ -77,7 +80,8 @@ def main_path_cases(cfg: ModelConfig) -> list[KernelCase]:
     n_pages = 1 + slots * pages_per_slot
     D = cfg.d_model
     F = (H + 2 * KV) * dh                     # fused [Wq|Wk|Wv] width
-    pool = (KV, n_pages, page_size, dh)
+    pool = (LAYERS, KV, n_pages, page_size, dh)
+    layer = jnp.int32(LAYERS - 1)
 
     def table(key):
         ids = jax.random.permutation(key, jnp.arange(1, n_pages))
@@ -92,14 +96,14 @@ def main_path_cases(cfg: ModelConfig) -> list[KernelCase]:
     def paged_decode(key):
         ks = jax.random.split(key, 5)
         return (normal(ks[0], (slots, KV, group, dh)), normal(ks[1], pool),
-                normal(ks[2], pool), table(ks[3]), lens(ks[4], slots))
+                normal(ks[2], pool), table(ks[3]), lens(ks[4], slots), layer)
 
     def paged_decode_int8(key):
         ks = jax.random.split(key, 5)
         kq, kscale = _int8_pool(ks[1], pool)
         vq, vscale = _int8_pool(ks[2], pool)
         return (normal(ks[0], (slots, KV, group, dh)), kq, vq, kscale,
-                vscale, table(ks[3]), lens(ks[4], slots))
+                vscale, table(ks[3]), lens(ks[4], slots), layer)
 
     def offset(key):      # a chunk-aligned offset inside the slot
         return chunk * jax.random.randint(key, (), 0, S // chunk, jnp.int32)
@@ -107,14 +111,14 @@ def main_path_cases(cfg: ModelConfig) -> list[KernelCase]:
     def paged_chunk(key):
         ks = jax.random.split(key, 5)
         return (normal(ks[0], (1, chunk, H, dh)), normal(ks[1], pool),
-                normal(ks[2], pool), table(ks[3])[:1], offset(ks[4]))
+                normal(ks[2], pool), table(ks[3])[:1], offset(ks[4]), layer)
 
     def paged_chunk_int8(key):
         ks = jax.random.split(key, 5)
         kq, kscale = _int8_pool(ks[1], pool)
         vq, vscale = _int8_pool(ks[2], pool)
         return (normal(ks[0], (1, chunk, H, dh)), kq, vq, kscale, vscale,
-                table(ks[3])[:1], offset(ks[4]))
+                table(ks[3])[:1], offset(ks[4]), layer)
 
     def decode(key):
         ks = jax.random.split(key, 4)
@@ -200,10 +204,11 @@ def latent_cases(cfg: ModelConfig) -> list[KernelCase]:
         ks = jax.random.split(key, 4)
         ids = jax.random.permutation(ks[2], jnp.arange(1, n_pages))
         return (jax.random.normal(ks[0], (slots, 1, H, width)).astype(DTYPE),
-                jax.random.normal(ks[1], (1, n_pages, page_size, width)
-                                  ).astype(DTYPE),
+                jax.random.normal(ks[1], (LAYERS, 1, n_pages, page_size,
+                                          width)).astype(DTYPE),
                 ids.reshape(slots, pages_per_slot).astype(jnp.int32),
-                jax.random.randint(ks[3], (slots,), 1, S + 1, jnp.int32))
+                jax.random.randint(ks[3], (slots,), 1, S + 1, jnp.int32),
+                jnp.int32(LAYERS - 1))
 
     def grouped(key):
         ks = jax.random.split(key, 3)

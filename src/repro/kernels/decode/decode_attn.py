@@ -12,18 +12,19 @@ runtime-programmable "sequence length register" of the paper's µB.
 Two cache layouts share the online-softmax inner loop:
 
   * ``decode_attention``       — contiguous (BKV, Skv, dh) per-slot caches.
-  * ``paged_decode_attention`` — a shared kv-head-major
-    (KV, n_pages, page_size, dh) page pool left in HBM; a scalar-prefetched
-    per-slot page table aims the kernel's own page DMAs (no gather
-    materialisation), the TPU analogue of FAMOUS's banked-BRAM tiling.
+  * ``paged_decode_attention`` — the layer stack's kv-head-major page
+    pools (L, KV, n_pages, page_size, dh), left in HBM; a scalar-prefetched
+    layer index and per-slot page table aim the kernel's own page DMAs (no
+    slice of the stack, no gather materialisation), the TPU analogue of
+    FAMOUS's banked-BRAM tiling.
     Grid (B, n_blocks): a step copies one block of ``pages_per_block``
     pages of a slot, every kv head of a page in one strided DMA, into a
     double-buffered VMEM buffer, and runs each kv head's (group, block)
     score tile and PV matmul.  Blocks at or past the slot's length issue
     no copy and no compute, so the work follows the live cache, not
     ``max_seq``.  kv-head-major keeps each page a whole (page_size, dh)
-    tile per head; int8 pools carry (KV, n_pages, 1, page_size) scale
-    rows, gathered per slot before the kernel.
+    tile per head; int8 pools carry (L, KV, n_pages, 1, page_size) scale
+    rows, the layer's gathered per slot before the kernel.
 """
 from __future__ import annotations
 
@@ -116,13 +117,14 @@ def _live_blocks(length, block_tokens: int, n_blocks):
     return jnp.clip((length + block_tokens - 1) // block_tokens, 1, n_blocks)
 
 
-def _paged_decode_kernel(len_ref, pt_ref, q_ref, k_hbm, *refs,
+def _paged_decode_kernel(len_ref, pt_ref, layer_ref, q_ref, k_hbm, *refs,
                          scale: float, page_size: int, ppb: int, n_p: int,
                          quantized: bool, v_width: int):
     """One grid step (b, j): block j of slot b — pages ``[j*ppb,
     (j+1)*ppb)`` of its table, every kv head — copied page by page from
-    the HBM pools into one of two VMEM buffers, then folded into each kv
-    head's running softmax.  Blocks at or past the slot's length are
+    layer ``layer_ref[0]`` of the stacked HBM pools into one of two VMEM
+    buffers, then folded into each kv head's running softmax.  Blocks at
+    or past the slot's length are
     skipped whole; each live step starts the copies of the next live block
     (of this slot or the next) into the other buffer before it waits on
     its own.
@@ -141,6 +143,7 @@ def _paged_decode_kernel(len_ref, pt_ref, q_ref, k_hbm, *refs,
     sem_ref, slot_ref = refs
     pools = ((k_hbm, k_buf),) if v_width else ((k_hbm, k_buf), (v_hbm, v_buf))
     b, j = pl.program_id(0), pl.program_id(1)
+    layer = layer_ref[0]
     n_kv, dh = q_ref.shape[1], q_ref.shape[3]
     block_tokens = ppb * page_size
 
@@ -154,7 +157,7 @@ def _paged_decode_kernel(len_ref, pt_ref, q_ref, k_hbm, *refs,
             def _():
                 page = pt_ref[row, first + i]
                 for pool, buf in pools:
-                    getattr(pc.make_async_copy(pool.at[:, page],
+                    getattr(pc.make_async_copy(pool.at[layer, :, page],
                                                buf.at[slot, i],
                                                sem_ref.at[slot]), method)()
 
@@ -227,11 +230,12 @@ def _paged_decode_kernel(len_ref, pt_ref, q_ref, k_hbm, *refs,
 
 
 def _paged_decode_call(q, k_pages, v_pages, scales, page_table, cache_len,
-                       scale, interpret, v_width: int = 0):
-    """The paged decode launch.  ``v_pages`` None: latent attention, whose
-    values are the first ``v_width`` columns of ``k_pages``' rows."""
+                       layer, scale, interpret, v_width: int = 0):
+    """The paged decode launch over layer ``layer`` of the stacked pools.
+    ``v_pages`` None: latent attention, whose values are the first
+    ``v_width`` columns of ``k_pages``' rows."""
     B, KV, group, dh = q.shape
-    page_size = k_pages.shape[2]
+    page_size = k_pages.shape[3]
     n_p = page_table.shape[1]
     ppb = pages_per_block(n_p, page_size, KV, dh, k_pages.dtype)
     n_blocks = pl.cdiv(n_p, ppb)
@@ -242,9 +246,9 @@ def _paged_decode_call(q, k_pages, v_pages, scales, page_table, cache_len,
                                page_size=page_size, ppb=ppb, n_p=n_p,
                                quantized=bool(scales), v_width=v_width)
     slot_spec = pl.BlockSpec((1, KV, group, dh),
-                             lambda b, j, lens, pt: (b, 0, 0, 0))
+                             lambda b, j, lens, pt, lyr: (b, 0, 0, 0))
     out_spec = pl.BlockSpec((1, KV, group, dv),
-                            lambda b, j, lens, pt: (b, 0, 0, 0))
+                            lambda b, j, lens, pt, lyr: (b, 0, 0, 0))
     pools = (k_pages,) if v_width else (k_pages, v_pages)
     # A page's (1, page_size) scale row is narrower than the 128-lane tile
     # a DMA moves, so the int8 scales are gathered per slot by XLA, (B, KV,
@@ -253,12 +257,12 @@ def _paged_decode_call(q, k_pages, v_pages, scales, page_table, cache_len,
     # index, which the pipeline does not fetch again.
     scale_spec = pl.BlockSpec(
         (1, KV, 1, block_tokens),
-        lambda b, j, lens, pt: (b, 0, 0, jnp.minimum(
+        lambda b, j, lens, pt, lyr: (b, 0, 0, jnp.minimum(
             j, _live_blocks(lens[b], block_tokens, n_blocks) - 1)))
-    scale_rows = [_scale_rows(s, page_table, n_blocks * block_tokens)
+    scale_rows = [_scale_rows(s, layer, page_table, n_blocks * block_tokens)
                   for s in scales]
     grid_spec = pc.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,           # cache_len, page_table
+        num_scalar_prefetch=3,           # cache_len, page_table, layer
         grid=(B, n_blocks),
         in_specs=[slot_spec] + [pl.BlockSpec(memory_space=pc.ANY)] * len(pools)
         + [scale_spec] * len(scales),
@@ -281,67 +285,79 @@ def _paged_decode_call(q, k_pages, v_pages, scales, page_table, cache_len,
         out_shape=jax.ShapeDtypeStruct((B, KV, group, dv), q.dtype),
         compiler_params=pc.compiler_params("arbitrary", "arbitrary"),
         interpret=interpret,
-    )(cache_len.astype(jnp.int32), page_table.astype(jnp.int32), q,
-      *pools, *scale_rows)
+    )(cache_len.astype(jnp.int32), page_table.astype(jnp.int32),
+      layer_operand(layer), q, *pools, *scale_rows)
 
 
-def _scale_rows(scale_pool, page_table, width: int):
-    """(KV, n_pages, 1, page_size) scale pool -> each slot's scales in table
-    order, (B, KV, 1, width) fp32, zero past the table's end."""
-    rows = scale_pool.astype(jnp.float32)[:, page_table, 0]  # (KV, B, n_p, ps)
-    KV, B = rows.shape[:2]
-    rows = rows.reshape(KV, B, -1).transpose(1, 0, 2)[:, :, None]
+def layer_operand(layer):
+    """The layer index as the (1,) int32 operand a kernel scalar-prefetches."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def _scale_rows(scale_pool, layer, page_table, width: int):
+    """(L, KV, n_pages, 1, page_size) scale pools -> layer ``layer``'s
+    scales of each slot in table order, (B, KV, 1, width) fp32, zero past
+    the table's end.  One gather of those rows: the layer's pool is never
+    sliced whole."""
+    heads = jnp.arange(scale_pool.shape[1])[None, :, None]
+    rows = scale_pool[layer, heads, page_table[:, None], 0]   # (B, KV, n_p, ps)
+    B, KV = rows.shape[:2]
+    rows = rows.astype(jnp.float32).reshape(B, KV, 1, -1)
     return jnp.pad(rows, ((0, 0), (0, 0), (0, 0), (0, width - rows.shape[-1])))
 
 
-def paged_decode_attention(q, k_pages, v_pages, page_table, cache_len, *,
-                           scale: float | None = None,
+def paged_decode_attention(q, k_pages, v_pages, page_table, cache_len,
+                           layer, *, scale: float | None = None,
                            interpret: bool = False):
     """Page-table-indexed decode attention.
 
-    q: (B, KV, group, dh); pools: (KV, n_pages, page_size, dh);
-    page_table: (B, n_p) int32 page ids; cache_len: (B,) int32.
+    q: (B, KV, group, dh); pools: the layer stack's (L, KV, n_pages,
+    page_size, dh); page_table: (B, n_p) int32 page ids; cache_len: (B,)
+    int32; layer: () int32, the layer of the stack read.
     Returns (B, KV, group, dh).
 
-    The page table and lengths are *scalar-prefetched*: they reach SMEM
-    before the kernel body runs, and the kernel reads ``page_table[b, p]``
-    to aim each page DMA and ``cache_len[b]`` to stop the walk — the grid
-    program never changes shape when sequences grow or move, only the
+    The page table, lengths and layer are *scalar-prefetched*: they reach
+    SMEM before the kernel body runs, and the kernel reads
+    ``page_table[b, p]`` and ``layer`` to aim each page DMA and
+    ``cache_len[b]`` to stop the walk — the grid program never changes
+    shape when sequences grow or move, or from layer to layer, only the
     prefetched indices do (the paper's µB reprograms addresses, never
     re-synthesises).
     """
     return _paged_decode_call(q, k_pages, v_pages, (), page_table,
-                              cache_len, scale, interpret)
+                              cache_len, layer, scale, interpret)
 
 
-def mla_decode_attention(q, pool, page_table, cache_len, *, scale: float,
-                         rank: int, interpret: bool = False):
+def mla_decode_attention(q, pool, page_table, cache_len, layer, *,
+                         scale: float, rank: int, interpret: bool = False):
     """Paged decode of latent attention (MLA): every query head of a slot
     against one cached row per token.
 
     q: (B, 1, H, row) absorbed queries ``[q_nope W_UK^T; q_pe]``; pool:
-    (1, n_pages, page_size, row) latent rows ``[c_kv; k_pe]``, ``row`` a
-    multiple of 128 (Mosaic copies no narrower page; zero-padded past
-    the latent's own width); page_table:
-    (B, n_p) int32; cache_len: (B,) int32.  Scores take the whole row, the
-    values its first ``rank`` columns.  Returns (B, 1, H, rank).
+    the layer stack's (L, 1, n_pages, page_size, row) latent rows
+    ``[c_kv; k_pe]``, ``row`` a multiple of 128 (Mosaic copies no narrower
+    page; zero-padded past the latent's own width); page_table:
+    (B, n_p) int32; cache_len: (B,) int32; layer: () int32.  Scores take
+    the whole row, the values its first ``rank`` columns.  Returns
+    (B, 1, H, rank).
 
     The same block walk as :func:`paged_decode_attention` (one "kv head"
     of ``row``-wide pages, the H heads as its group), copying each page
     once for both the scores and the values."""
     return _paged_decode_call(q, pool, None, (), page_table, cache_len,
-                              scale, interpret, v_width=rank)
+                              layer, scale, interpret, v_width=rank)
 
 
 def paged_decode_attention_int8(q, k_pages, v_pages, k_scale, v_scale,
-                                page_table, cache_len, *,
+                                page_table, cache_len, layer, *,
                                 scale: float | None = None,
                                 interpret: bool = False):
     """Int8 paged decode attention with in-kernel dequantization.
 
-    q: (B, KV, group, dh) fp; pools: (KV, n_pages, page_size, dh) **int8**;
-    scale pools: (KV, n_pages, 1, page_size) fp32 per-token-per-kv-head
-    scales; page_table: (B, n_p) int32; cache_len: (B,) int32.
+    q: (B, KV, group, dh) fp; pools: (L, KV, n_pages, page_size, dh)
+    **int8**; scale pools: (L, KV, n_pages, 1, page_size) fp32
+    per-token-per-kv-head scales; page_table: (B, n_p) int32; cache_len:
+    (B,) int32; layer: () int32.
 
     Each grid step copies the block's int8 pages plus its (1, block)
     scale rows (gathered per slot in table order) and widens to fp32 only
@@ -350,7 +366,7 @@ def paged_decode_attention_int8(q, k_pages, v_pages, k_scale, v_scale,
     """
     assert k_pages.dtype == jnp.int8 and v_pages.dtype == jnp.int8
     return _paged_decode_call(q, k_pages, v_pages, (k_scale, v_scale),
-                              page_table, cache_len, scale, interpret)
+                              page_table, cache_len, layer, scale, interpret)
 
 
 def decode_attention(q, k_cache, v_cache, cache_len, *, window: int = 0,

@@ -26,28 +26,31 @@ def decode_reference(q, k_cache, v_cache, cache_len, *, window: int = 0,
                       v_cache.astype(jnp.float32)).astype(q.dtype)
 
 
-def gather_pages(pages, page_table):
-    """Flatten a kv-head-major page pool into per-sequence contiguous caches.
+def gather_pages(pages, page_table, layer):
+    """Flatten layer ``layer`` of a stack of kv-head-major page pools into
+    per-sequence contiguous caches, in one gather of the table's pages.
 
-    pages: (KV, n_pages, page_size, dh); page_table: (B, n_p) int32.
-    Returns (B, n_p * page_size, KV, dh).
+    pages: (L, KV, n_pages, page_size, dh); page_table: (B, n_p) int32;
+    layer: () int32.  Returns (B, n_p * page_size, KV, dh).
     """
-    KV, _, ps, dh = pages.shape
+    _, KV, _, ps, dh = pages.shape
     B, n_p = page_table.shape
-    g = pages[:, page_table]                  # (KV, B, n_p, ps, dh)
-    return g.reshape(KV, B, n_p * ps, dh).transpose(1, 2, 0, 3)
+    g = pages[layer, :, page_table]           # (B, n_p, KV, ps, dh)
+    return g.transpose(0, 1, 3, 2, 4).reshape(B, n_p * ps, KV, dh)
 
 
-def gather_pages_int8(pages, scale_pool, page_table):
+def gather_pages_int8(pages, scale_pool, page_table, layer):
     """Dequantizing gather for int8 page pools (XLA oracle path).
 
-    pages: (KV, n_pages, page_size, dh) int8; scale_pool: (KV, n_pages, 1,
-    page_size) fp32 per-token-per-kv-head scales; page_table: (B, n_p).
-    Returns fp32 (B, n_p * page_size, KV, dh) — what the Pallas int8
-    kernels compute tile-by-tile in VMEM, materialised whole.
+    pages: (L, KV, n_pages, page_size, dh) int8; scale_pool: (L, KV,
+    n_pages, 1, page_size) fp32 per-token-per-kv-head scales; page_table:
+    (B, n_p); layer: () int32.  Returns fp32 (B, n_p * page_size, KV, dh)
+    — what the Pallas int8 kernels compute tile-by-tile in VMEM,
+    materialised whole.
     """
-    g = gather_pages(pages, page_table).astype(jnp.float32)
-    s = gather_pages(scale_pool.swapaxes(-1, -2), page_table)  # (B, S, KV, 1)
+    g = gather_pages(pages, page_table, layer).astype(jnp.float32)
+    s = gather_pages(scale_pool.swapaxes(-1, -2), page_table,
+                     layer)                                 # (B, S, KV, 1)
     return g * s.astype(jnp.float32)
 
 
@@ -75,23 +78,24 @@ def chunk_prefill_reference(q, k_cache, v_cache, q_offset, *,
 
 
 def paged_chunk_prefill_reference(q, k_pages, v_pages, page_table, q_offset,
-                                  *, scale: float | None = None):
+                                  layer, *, scale: float | None = None):
     """Gather-based oracle for the paged chunked-prefill kernel."""
-    k = gather_pages(k_pages, page_table)
-    v = gather_pages(v_pages, page_table)
+    k = gather_pages(k_pages, page_table, layer)
+    v = gather_pages(v_pages, page_table, layer)
     return chunk_prefill_reference(q, k, v, q_offset, scale=scale)
 
 
-def paged_decode_reference(q, k_pages, v_pages, page_table, cache_len, *,
-                           scale: float | None = None):
+def paged_decode_reference(q, k_pages, v_pages, page_table, cache_len, layer,
+                           *, scale: float | None = None):
     """Gather-based oracle for the paged kernel.
 
-    q: (B, KV, group, dh); pools: (KV, n_pages, page_size, dh);
-    page_table: (B, n_p); cache_len: (B,).  Returns (B, KV, group, dh).
+    q: (B, KV, group, dh); pools: (L, KV, n_pages, page_size, dh);
+    page_table: (B, n_p); cache_len: (B,); layer: ().  Returns
+    (B, KV, group, dh).
     """
     B, KV, group, dh = q.shape
-    k = gather_pages(k_pages, page_table)     # (B, Skv, KV, dh)
-    v = gather_pages(v_pages, page_table)
+    k = gather_pages(k_pages, page_table, layer)     # (B, Skv, KV, dh)
+    v = gather_pages(v_pages, page_table, layer)
     qf = q.reshape(B * KV, group, dh)
     kf = k.transpose(0, 2, 1, 3).reshape(B * KV, -1, dh)
     vf = v.transpose(0, 2, 1, 3).reshape(B * KV, -1, dh)
@@ -101,11 +105,11 @@ def paged_decode_reference(q, k_pages, v_pages, page_table, cache_len, *,
 
 
 def paged_decode_reference_int8(q, k_pages, v_pages, k_scale, v_scale,
-                                page_table, cache_len, *,
+                                page_table, cache_len, layer, *,
                                 scale: float | None = None):
     """Dequantizing-gather oracle for the int8 paged decode kernel."""
-    k = gather_pages_int8(k_pages, k_scale, page_table)
-    v = gather_pages_int8(v_pages, v_scale, page_table)
+    k = gather_pages_int8(k_pages, k_scale, page_table, layer)
+    v = gather_pages_int8(v_pages, v_scale, page_table, layer)
     B, KV, group, dh = q.shape
     qf = q.reshape(B * KV, group, dh)
     kf = k.transpose(0, 2, 1, 3).reshape(B * KV, -1, dh)
@@ -115,22 +119,23 @@ def paged_decode_reference_int8(q, k_pages, v_pages, k_scale, v_scale,
 
 
 def paged_chunk_prefill_reference_int8(q, k_pages, v_pages, k_scale, v_scale,
-                                       page_table, q_offset, *,
+                                       page_table, q_offset, layer, *,
                                        scale: float | None = None):
     """Dequantizing-gather oracle for the int8 paged chunk-prefill kernel."""
-    k = gather_pages_int8(k_pages, k_scale, page_table)
-    v = gather_pages_int8(v_pages, v_scale, page_table)
+    k = gather_pages_int8(k_pages, k_scale, page_table, layer)
+    v = gather_pages_int8(v_pages, v_scale, page_table, layer)
     return chunk_prefill_reference(q, k, v, q_offset, scale=scale)
 
 
-def mla_decode_reference(q, pool, page_table, cache_len, *, scale: float,
-                         rank: int):
+def mla_decode_reference(q, pool, page_table, cache_len, layer, *,
+                         scale: float, rank: int):
     """Gather-based oracle for the latent (MLA) decode kernel.
 
-    q: (B, 1, H, row); pool: (1, n_pages, page_size, row); page_table:
-    (B, n_p); cache_len: (B,).  Returns (B, 1, H, rank) in q's type."""
+    q: (B, 1, H, row); pool: (L, 1, n_pages, page_size, row); page_table:
+    (B, n_p); cache_len: (B,); layer: ().  Returns (B, 1, H, rank) in q's
+    type."""
     B = q.shape[0]
-    rows = pool[0][page_table].reshape(B, -1, pool.shape[-1])
+    rows = pool[layer, 0][page_table].reshape(B, -1, pool.shape[-1])
     rows = rows.astype(jnp.float32)
     s = jnp.einsum("bhr,bkr->bhk", q[:, 0].astype(jnp.float32), rows) * scale
     ok = jnp.arange(rows.shape[1])[None, None, :] < cache_len[:, None, None]

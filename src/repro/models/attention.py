@@ -107,16 +107,30 @@ def _kv_quantize(x):
     return q, s[..., 0].astype(jnp.float32)
 
 
-def _paged_write(cache: dict, pids, offs, k, v) -> dict:
-    """Scatter per-token K/V rows (..., kv, dh) into the kv-head-major page
-    pool at (pids, offs) — quantizing at write time when the pool is int8
-    (``k_scale`` present)."""
-    def put(pool, rows):   # rows (..., kv, dh) -> pool[:, pids, offs]
-        return pool.at[:, pids, offs].set(
-            jnp.moveaxis(rows, -2, 0).astype(pool.dtype))
+def _paged_write(cache: dict, layer, pids, offs, k, v) -> dict:
+    """Scatter per-token K/V rows (..., kv, dh) into layer ``layer`` of the
+    stacked kv-head-major page pools (L, kv, n_pages, page_size, dh) at
+    (pids, offs) — quantizing at write time when the pool is int8
+    (``k_scale`` present).  Only the rows move: the stack is the layer
+    scan's carry, updated in place."""
+    # one dh-row per (kv head, token): a scatter of (kv, dh) windows makes
+    # XLA give the carried stack a kv-minor layout, which every Pallas
+    # call then copies whole into the layout it reads
+    heads = jnp.arange(cache["k"].shape[1])
+    at = (pids[..., None], offs[..., None])
 
-    def put_scale(pool, s):   # s (..., kv) -> pool[:, pids, 0, offs]
-        return pool.at[:, pids, 0, offs].set(jnp.moveaxis(s, -1, 0))
+    def put(pool, rows):   # rows (..., kv, dh) -> pool[layer, h, pids, offs]
+        return pool.at[layer, heads, at[0], at[1]].set(rows.astype(pool.dtype))
+
+    def put_scale(pool, s):   # s (..., kv) -> pool[layer, :, pids, 0, offs]
+        # a (kv,) column per token: an element scatter gives the carried
+        # scale stack a layout of its own, which every launch of the
+        # chunk-prefill kernel would then copy back, layer by layer
+        s, p, o = s.reshape(-1, s.shape[-1]), pids.reshape(-1), offs.reshape(-1)
+        for i in range(s.shape[0]):
+            pool = jax.lax.dynamic_update_slice(
+                pool, s[i].reshape(1, -1, 1, 1, 1), (layer, 0, p[i], 0, o[i]))
+        return pool
 
     if "k_scale" in cache:
         kq, ks = _kv_quantize(k)
@@ -249,27 +263,28 @@ def apply_attn_chunk(p: dict, x: jax.Array, cache: dict, slot, offset,
     return o, cache
 
 
-def apply_attn_chunk_paged(p: dict, x: jax.Array, cache: dict, page_table,
-                           slot, offset, cfg: ModelConfig,
+def apply_attn_chunk_paged(p: dict, x: jax.Array, cache: dict, layer,
+                           page_table, slot, offset, cfg: ModelConfig,
                            fcfg: famous.FamousConfig):
     """Chunked prefill against the shared page pool.
 
-    x: (1, C, D); cache: {"k","v"} pools (kv, n_pages, page_size, dh);
-    page_table: (n_slots, n_p) int32.  The chunk's K/V scatter into the
-    slot's pages (position p -> page ``page_table[slot, p // ps]``, offset
-    ``p % ps``); pad positions past the reserved pages land on the null
-    page, which absorbs them by convention.  Returns (out, new cache).
+    x: (1, C, D); cache: {"k","v"} the layer stack's pools (L, kv,
+    n_pages, page_size, dh), of which this is layer ``layer``; page_table:
+    (n_slots, n_p) int32.  The chunk's K/V scatter into the slot's pages
+    (position p -> page ``page_table[slot, p // ps]``, offset ``p % ps``);
+    pad positions past the reserved pages land on the null page, which
+    absorbs them by convention.  Returns (out, new cache).
     """
     C = x.shape[1]
     positions = offset + jnp.arange(C)
     q, k, v = _project(p, x, cfg, fcfg, positions)
-    ps = cache["k"].shape[2]
+    ps = cache["k"].shape[3]
     pt_row = page_table[slot]                          # (n_p,)
     pids = pt_row[positions // ps]
     offs = positions % ps
-    cache = _paged_write(cache, pids, offs, k[0], v[0])
+    cache = _paged_write(cache, layer, pids, offs, k[0], v[0])
     out = famous.paged_chunked_prefill_attention(q, cache["k"], cache["v"],
-                                                 pt_row[None], offset,
+                                                 pt_row[None], offset, layer,
                                                  cfg=fcfg,
                                                  **_pool_scales(cache))
     o = jnp.einsum("bshe,hed->bsd", out, p["wo"].astype(out.dtype))
@@ -328,13 +343,14 @@ def apply_attn_verify(p: dict, x: jax.Array, cache: dict, cache_len,
     return o, cache
 
 
-def apply_attn_verify_paged(p: dict, x: jax.Array, cache: dict, page_table,
-                            cache_len, cfg: ModelConfig,
+def apply_attn_verify_paged(p: dict, x: jax.Array, cache: dict, layer,
+                            page_table, cache_len, cfg: ModelConfig,
                             fcfg: famous.FamousConfig):
     """Speculative verify against the shared page pool.  x: (B, W, D);
-    cache: {"k","v"} pools (kv, n_pages, page_size, dh); page_table:
-    (B, n_p) int32; cache_len: (B,) valid entries BEFORE the first verify
-    token.  Position p of slot b scatters into page
+    cache: {"k","v"} the layer stack's pools (L, kv, n_pages, page_size,
+    dh), of which this is layer ``layer``; page_table: (B, n_p) int32;
+    cache_len: (B,) valid entries BEFORE the first verify token.  Position
+    p of slot b scatters into page
     ``page_table[b, p // ps]`` — explicitly redirected to the null page
     when ``p // ps`` runs past the table (pad rows of a nearly-full slot;
     a clamped gather would alias a live page and corrupt it).  Rollback of
@@ -345,27 +361,28 @@ def apply_attn_verify_paged(p: dict, x: jax.Array, cache: dict, page_table,
     B, W = x.shape[:2]
     positions = cache_len[:, None] + jnp.arange(W)      # (B, W)
     q, k, v = _project(p, x, cfg, fcfg, positions)
-    ps = cache["k"].shape[2]
+    ps = cache["k"].shape[3]
     n_p = page_table.shape[1]
     blk = positions // ps
     b_idx = jnp.arange(B)[:, None]
     pids = jnp.where(blk < n_p,
                      page_table[b_idx, jnp.minimum(blk, n_p - 1)], 0)
     offs = positions % ps
-    cache = _paged_write(cache, pids, offs, k, v)
+    cache = _paged_write(cache, layer, pids, offs, k, v)
     out = famous.paged_verify_attention(q, cache["k"], cache["v"],
-                                        page_table, cache_len, cfg=fcfg,
-                                        **_pool_scales(cache))
+                                        page_table, cache_len, layer,
+                                        cfg=fcfg, **_pool_scales(cache))
     o = jnp.einsum("bshe,hed->bsd", out, p["wo"].astype(out.dtype))
     return o, cache
 
 
-def apply_attn_decode_paged(p: dict, x: jax.Array, cache: dict, page_table,
-                            cache_len, cfg: ModelConfig,
+def apply_attn_decode_paged(p: dict, x: jax.Array, cache: dict, layer,
+                            page_table, cache_len, cfg: ModelConfig,
                             fcfg: famous.FamousConfig):
     """One-token decode against the shared page pool.
 
-    x: (B, 1, D); cache: {"k","v"} pools (kv, n_pages, page_size, dh);
+    x: (B, 1, D); cache: {"k","v"} the layer stack's pools (L, kv,
+    n_pages, page_size, dh), of which this is layer ``layer``;
     page_table: (B, pages_per_slot) int32; cache_len: (B,) valid entries
     BEFORE this token.  The new token's K/V scatter into page
     ``page_table[b, len // page_size]`` at offset ``len % page_size``.
@@ -377,12 +394,12 @@ def apply_attn_decode_paged(p: dict, x: jax.Array, cache: dict, page_table,
     B = x.shape[0]
     positions = cache_len[:, None]
     q, k, v = _project(p, x, cfg, fcfg, positions)
-    ps = cache["k"].shape[2]
+    ps = cache["k"].shape[3]
     pids = page_table[jnp.arange(B), cache_len // ps]      # (B,)
     offs = cache_len % ps
-    cache = _paged_write(cache, pids, offs, k[:, 0], v[:, 0])
+    cache = _paged_write(cache, layer, pids, offs, k[:, 0], v[:, 0])
     out = famous.paged_decode_attention(q, cache["k"], cache["v"],
-                                        page_table, cache_len + 1, cfg=fcfg,
-                                        **_pool_scales(cache))
+                                        page_table, cache_len + 1, layer,
+                                        cfg=fcfg, **_pool_scales(cache))
     o = jnp.einsum("bshe,hed->bsd", out, p["wo"].astype(out.dtype))
     return o, cache

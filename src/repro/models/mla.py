@@ -17,7 +17,8 @@ the plain form above; the two agree (tests/test_mla.py).
 
 Cache layouts: contiguous ``{"c": (batch, max_seq, width)}``, or a paged
 pool ``{"c": (1, n_pages, page_size, width)}`` (one "kv head", so the paged
-decode kernel's block walk serves it unchanged; see kernels/decode).  The
+decode kernel's block walk serves it unchanged; see kernels/decode), which
+the serving steps take stacked over the layers and address by layer.  The
 stored width is the row's rounded up to the 128-lane tile (576 -> 640):
 Mosaic copies no narrower page.  Rows and queries carry zeros there, which
 add nothing to any score.
@@ -269,51 +270,54 @@ def apply_mla_decode(p, x, cache, cache_len, cfg: ModelConfig, fcfg):
 # ---------------------------------------------------------------------------
 
 
-def _paged_write(pool, pids, offs, rows):
-    """Rows (..., row) into the (1, n_pages, page_size, row) pool."""
-    return pool.at[0, pids, offs].set(rows.astype(pool.dtype))
+def _paged_write(pool, layer, pids, offs, rows):
+    """Rows (..., row) into layer ``layer`` of the stacked (L, 1, n_pages,
+    page_size, row) pools, in place."""
+    return pool.at[layer, 0, pids, offs].set(rows.astype(pool.dtype))
 
 
-def apply_mla_chunk_paged(p, x, cache, page_table, slot, offset,
+def apply_mla_chunk_paged(p, x, cache, layer, page_table, slot, offset,
                           cfg: ModelConfig, fcfg):
-    """One prefill chunk against the paged pool: the chunk's rows scatter
-    into the slot's pages (pads past the reserved pages land on the null
-    page), then the absorbed form attends over the slot's whole table,
-    gathered (XLA)."""
+    """One prefill chunk against layer ``layer`` of the stacked paged pools:
+    the chunk's rows scatter into the slot's pages (pads past the reserved
+    pages land on the null page), then the absorbed form attends over the
+    slot's whole table, gathered (XLA) from the stack."""
     C = x.shape[1]
     positions = offset + jnp.arange(C)
     rows = latent_rows(p, x, positions, cfg)
     pool = cache["c"]
-    ps = pool.shape[2]
+    ps = pool.shape[3]
     pt_row = page_table[slot]
-    pool = _paged_write(pool, pt_row[positions // ps], positions % ps, rows[0])
-    own = pool[0, pt_row].reshape(1, -1, pool.shape[-1])   # (1, n_p*ps, row)
+    pool = _paged_write(pool, layer, pt_row[positions // ps], positions % ps,
+                        rows[0])
+    own = pool[layer, 0, pt_row].reshape(1, -1, pool.shape[-1])  # (1, S, row)
     q = absorbed_queries(p, x, positions, cfg)
     ok = _causal(positions[None], own.shape[1])
     o = latent_attention(q, own, ok, softmax_scale(cfg), cfg.kv_lora_rank)
     return absorbed_output(p, o, cfg), {"c": pool}
 
 
-def apply_mla_decode_paged(p, x, cache, page_table, cache_len,
+def apply_mla_decode_paged(p, x, cache, layer, page_table, cache_len,
                            cfg: ModelConfig, fcfg: famous.FamousConfig):
-    """One token per slot against the paged pool: the new row goes to page
-    ``page_table[b, len // ps]``; impl="pallas" runs the latent decode
-    kernel over each slot's live pages, other impls gather the table."""
+    """One token per slot against layer ``layer`` of the stacked paged
+    pools: the new row goes to page ``page_table[b, len // ps]``;
+    impl="pallas" runs the latent decode kernel over each slot's live pages
+    of that layer, other impls gather the table."""
     B = x.shape[0]
     positions = cache_len[:, None]
     rows = latent_rows(p, x, positions, cfg)
     pool = cache["c"]
-    ps = pool.shape[2]
-    pool = _paged_write(pool, page_table[jnp.arange(B), cache_len // ps],
+    ps = pool.shape[3]
+    pool = _paged_write(pool, layer, page_table[jnp.arange(B), cache_len // ps],
                         cache_len % ps, rows[:, 0])
     q = absorbed_queries(p, x, positions, cfg)
     scale, r = softmax_scale(cfg), cfg.kv_lora_rank
     if fcfg.impl == "pallas":
         from repro.kernels.decode import ops as dec_ops
         o = dec_ops.mla_decode_attention(q, pool, page_table, cache_len + 1,
-                                         scale=scale, rank=r)
+                                         layer, scale=scale, rank=r)
     else:
-        own = pool[0][page_table].reshape(B, -1, pool.shape[-1])
+        own = pool[layer, 0, page_table].reshape(B, -1, pool.shape[-1])
         o = latent_attention(q, own, _causal(positions, own.shape[1]), scale,
                              r)
     return absorbed_output(p, o, cfg), {"c": pool}

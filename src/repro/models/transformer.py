@@ -359,13 +359,74 @@ def _apply_block_prefill(kind, p, x, cache, cfg, fcfg):
     raise ValueError(kind)
 
 
-def _apply_block_decode(kind, p, x, cache, cache_len, cfg, fcfg,
+def _pooled(kind: str, page_table) -> bool:
+    """Whether a layer of ``kind`` keeps its cache in a shared page pool in
+    this step: global and latent attention, when a page table is given.
+    The serving steps carry pools through the layer scan whole and address
+    them by layer; every other cache is per slot."""
+    return page_table is not None and kind in (ATTN, MLA)
+
+
+def _unstacked(block_fn, page_table):
+    """``block_fn(kind, p, x, cache, layer, sink)`` for a head or tail
+    layer, whose cache has no layer dim: a pool rides as a stack of one
+    (a reshape, no copy) at layer 0."""
+    def fn(kind, p, x, cache, sink=None):
+        if not _pooled(kind, page_table):
+            return block_fn(kind, p, x, cache, None, sink)
+        x, new = block_fn(kind, p, x,
+                          jax.tree_util.tree_map(lambda a: a[None], cache), 0,
+                          sink)
+        return x, jax.tree_util.tree_map(lambda a: a[0], new)
+    return fn
+
+
+def _scan_units(block_fn, x, params, caches, cfg: ModelConfig, page_table):
+    """Run the scanned unit stack of a serving step:
+    ``block_fn(kind, p, x, cache, layer, sink) -> (x, new cache)`` for each
+    layer of each unit.  Returns (x, new block caches, the sum of each
+    unit's ``sink`` stacked over units, or None where nothing was sunk).
+
+    Page pools (:func:`_pooled`) ride the loop's carry whole, stacked
+    (units, ...) as :func:`make_caches` builds them: a layer writes its new
+    rows into the stack in place and its kernels read it at the scanned
+    ``layer`` index, so no iteration slices a pool in or stacks one out
+    (the caches are donated, so the carry is the input buffer).  Per-slot
+    caches (contiguous, ring, recurrent state) scan as xs/ys: a layer's
+    slice of them is read whole anyway, and ``layer`` is None for them."""
+    blocks = caches["blocks"]
+    pooled = {f"pos{i}" for i, k in enumerate(cfg.pattern_unit)
+              if _pooled(k, page_table)}
+    pools = {k: v for k, v in blocks.items() if k in pooled}
+    per_slot = {k: v for k, v in blocks.items() if k not in pooled}
+
+    def body(carry, xs):
+        x, pools = carry
+        unit_params, layer, unit_cache = xs
+        new, sink = {}, []
+        for i, kind in enumerate(cfg.pattern_unit):
+            key = f"pos{i}"
+            if key in pooled:
+                x, pools[key] = block_fn(kind, unit_params[key], x,
+                                         pools[key], layer, sink)
+            else:
+                x, new[key] = block_fn(kind, unit_params[key], x,
+                                       unit_cache[key], None, sink)
+        return (x, pools), (new, sum(sink) if sink else None)
+
+    (x, pools), (per_slot, sunk) = jax.lax.scan(
+        body, (x, pools),
+        (params["blocks"], jnp.arange(cfg.num_units), per_slot))
+    return x, {**pools, **per_slot}, sunk
+
+
+def _apply_block_decode(kind, p, x, cache, layer, cache_len, cfg, fcfg,
                         page_table=None, active=None, counts=None):
     n = functools.partial(layers.apply_norm, kind=cfg.norm)
     if kind == MLA:
         def attend(h):
             if page_table is not None:
-                return mla.apply_mla_decode_paged(p["attn"], h, cache,
+                return mla.apply_mla_decode_paged(p["attn"], h, cache, layer,
                                                   page_table, cache_len, cfg,
                                                   fcfg)
             return mla.apply_mla_decode(p["attn"], h, cache, cache_len, cfg,
@@ -374,7 +435,8 @@ def _apply_block_decode(kind, p, x, cache, cache_len, cfg, fcfg,
         return _mla_block(p, x, cfg, fcfg, attend, valid, counts)
     if kind == ATTN and page_table is not None:
         a, cache = attention.apply_attn_decode_paged(
-            p["attn"], n(p["ln1"], x), cache, page_table, cache_len, cfg, fcfg)
+            p["attn"], n(p["ln1"], x), cache, layer, page_table, cache_len,
+            cfg, fcfg)
         x = x + a
         return x + _apply_ffn(p["ffn"], n(p["ln2"], x), cfg), cache
     if kind in (ATTN, LOCAL_ATTN):
@@ -466,28 +528,20 @@ def decode_step(params, tokens, caches, cache_len, cfg: ModelConfig,
     x = _embed_inputs(params, inputs, cfg, dtype)
     counts = [] if moe_counts else None
 
-    def block(kind, p, x, cache, sink=None):
-        x, new = _apply_block_decode(kind, p, x, cache, cache_len, cfg, fcfg,
-                                     page_table, active, sink)
+    def block(kind, p, x, cache, layer, sink):
+        x, new = _apply_block_decode(kind, p, x, cache, layer, cache_len, cfg,
+                                     fcfg, page_table, active,
+                                     sink if moe_counts else None)
         return x, _mask_state_update(kind, new, cache, active)
 
-    x, head_caches = _apply_heads(params, x, caches, cfg, block)
-
-    def unit_body(x, scanned):
-        unit_params, unit_cache = scanned
-        new_caches, sink = {}, [] if moe_counts else None
-        for i, kind in enumerate(cfg.pattern_unit):
-            key = f"pos{i}"
-            x, new_caches[key] = block(kind, unit_params[key], x,
-                                       unit_cache[key], sink)
-        return x, (new_caches, sum(sink)) if moe_counts else new_caches
-
-    x, ys = jax.lax.scan(unit_body, x, (params["blocks"], caches["blocks"]))
-    new_block_caches, unit_counts = ys if moe_counts else (ys, None)
+    one = _unstacked(block, page_table)
+    x, head_caches = _apply_heads(params, x, caches, cfg, one)
+    x, new_block_caches, unit_counts = _scan_units(block, x, params, caches,
+                                                   cfg, page_table)
     new_caches = {"blocks": new_block_caches, **head_caches}
     for i, kind in enumerate(cfg.tail_layers):
-        x, new_caches[f"tail{i}"] = block(kind, params[f"tail{i}"], x,
-                                          caches[f"tail{i}"], counts)
+        x, new_caches[f"tail{i}"] = one(kind, params[f"tail{i}"], x,
+                                        caches[f"tail{i}"], counts)
     x = layers.apply_norm(params["final_norm"], x, cfg.norm)
     logits = logits_fn(params, x, cfg)[:, 0]
     if not moe_counts:
@@ -495,7 +549,7 @@ def decode_step(params, tokens, caches, cache_len, cfg: ModelConfig,
     return logits, new_caches, sum(counts, jnp.sum(unit_counts, axis=0))
 
 
-def _apply_block_verify(kind, p, x, cache, cache_len, cfg, fcfg,
+def _apply_block_verify(kind, p, x, cache, layer, cache_len, cfg, fcfg,
                         page_table=None):
     n = functools.partial(layers.apply_norm, kind=cfg.norm)
     if kind != ATTN:
@@ -508,8 +562,8 @@ def _apply_block_verify(kind, p, x, cache, cache_len, cfg, fcfg,
             f"verify_step only supports global-attention layers, got {kind}")
     if page_table is not None:
         a, cache = attention.apply_attn_verify_paged(
-            p["attn"], n(p["ln1"], x), cache, page_table, cache_len, cfg,
-            fcfg)
+            p["attn"], n(p["ln1"], x), cache, layer, page_table, cache_len,
+            cfg, fcfg)
     else:
         a, cache = attention.apply_attn_verify(
             p["attn"], n(p["ln1"], x), cache, cache_len, cfg, fcfg)
@@ -539,23 +593,17 @@ def verify_step(params, tokens, caches, cache_len, cfg: ModelConfig,
     dtype = compute_dtype or params["final_norm"]["scale"].dtype
     x = _embed_inputs(params, tokens, cfg, dtype)
 
-    def unit_body(x, scanned):
-        unit_params, unit_cache = scanned
-        new_caches = {}
-        for i, kind in enumerate(cfg.pattern_unit):
-            key = f"pos{i}"
-            x, new_caches[key] = _apply_block_verify(
-                kind, unit_params[key], x, unit_cache[key], cache_len, cfg,
-                fcfg, page_table)
-        return x, new_caches
+    def block(kind, p, x, cache, layer, sink):
+        return _apply_block_verify(kind, p, x, cache, layer, cache_len, cfg,
+                                   fcfg, page_table)
 
-    x, new_block_caches = jax.lax.scan(
-        unit_body, x, (params["blocks"], caches["blocks"]))
+    x, new_block_caches, _ = _scan_units(block, x, params, caches, cfg,
+                                         page_table)
     new_caches = {"blocks": new_block_caches}
+    one = _unstacked(block, page_table)
     for i, kind in enumerate(cfg.tail_layers):
-        x, new_caches[f"tail{i}"] = _apply_block_verify(
-            kind, params[f"tail{i}"], x, caches[f"tail{i}"], cache_len, cfg,
-            fcfg, page_table)
+        x, new_caches[f"tail{i}"] = one(kind, params[f"tail{i}"], x,
+                                        caches[f"tail{i}"])
     x = layers.apply_norm(params["final_norm"], x, cfg.norm)
     return logits_fn(params, x, cfg), new_caches
 
@@ -584,17 +632,18 @@ def _write_slot_state(cache, sub, slot):
         cache, sub)
 
 
-def _apply_block_chunk(kind, p, x, cache, slot, offset, n_valid, cfg, fcfg,
-                       page_table):
+def _apply_block_chunk(kind, p, x, cache, layer, slot, offset, n_valid, cfg,
+                       fcfg, page_table):
     n = functools.partial(layers.apply_norm, kind=cfg.norm)
     if kind == MLA:
         valid = (jnp.arange(x.shape[1]) < n_valid)[None]
         return _mla_block(p, x, cfg, fcfg, lambda h: mla.apply_mla_chunk_paged(
-            p["attn"], h, cache, page_table, slot, offset, cfg, fcfg), valid)
+            p["attn"], h, cache, layer, page_table, slot, offset, cfg, fcfg),
+            valid)
     if kind == ATTN and page_table is not None:
         a, cache = attention.apply_attn_chunk_paged(
-            p["attn"], n(p["ln1"], x), cache, page_table, slot, offset, cfg,
-            fcfg)
+            p["attn"], n(p["ln1"], x), cache, layer, page_table, slot, offset,
+            cfg, fcfg)
         x = x + a
         return x + _apply_ffn(p["ffn"], n(p["ln2"], x), cfg), cache
     if kind in (ATTN, LOCAL_ATTN):
@@ -644,27 +693,19 @@ def prefill_chunk(params, tokens, caches, slot, offset, n_valid,
     """
     dtype = compute_dtype or params["final_norm"]["scale"].dtype
     x = _embed_inputs(params, tokens, cfg, dtype)
-    x, head_caches = _apply_heads(
-        params, x, caches, cfg, lambda kind, p, x, c: _apply_block_chunk(
-            kind, p, x, c, slot, offset, n_valid, cfg, fcfg, page_table))
 
-    def unit_body(x, scanned):
-        unit_params, unit_cache = scanned
-        new_caches = {}
-        for i, kind in enumerate(cfg.pattern_unit):
-            key = f"pos{i}"
-            x, new_caches[key] = _apply_block_chunk(
-                kind, unit_params[key], x, unit_cache[key], slot, offset,
-                n_valid, cfg, fcfg, page_table)
-        return x, new_caches
+    def block(kind, p, x, cache, layer, sink):
+        return _apply_block_chunk(kind, p, x, cache, layer, slot, offset,
+                                  n_valid, cfg, fcfg, page_table)
 
-    x, new_block_caches = jax.lax.scan(
-        unit_body, x, (params["blocks"], caches["blocks"]))
+    one = _unstacked(block, page_table)
+    x, head_caches = _apply_heads(params, x, caches, cfg, one)
+    x, new_block_caches, _ = _scan_units(block, x, params, caches, cfg,
+                                         page_table)
     new_caches = {"blocks": new_block_caches, **head_caches}
     for i, kind in enumerate(cfg.tail_layers):
-        x, new_caches[f"tail{i}"] = _apply_block_chunk(
-            kind, params[f"tail{i}"], x, caches[f"tail{i}"], slot, offset,
-            n_valid, cfg, fcfg, page_table)
+        x, new_caches[f"tail{i}"] = one(kind, params[f"tail{i}"], x,
+                                        caches[f"tail{i}"])
     return new_caches
 
 

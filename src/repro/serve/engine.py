@@ -242,8 +242,9 @@ class ServingEngine:
         self.mesh = mesh
         # every hot executable donates the caches it is handed, so the
         # updated caches reuse the input pool's buffers instead of a second
-        # pool per step (the layer scan still stacks its new caches in one
-        # pool-sized temporary)
+        # pool per step; the layer scan carries the stacked pools in that
+        # buffer and writes only each step's new rows into it
+        # (transformer._scan_units), so no step holds a pool-sized temporary
         self._jit_kw_caches: dict = {}   # jits returning caches only
         self._jit_kw_logits: dict = {}   # jits returning (logits, caches)
         if mesh is not None:

@@ -380,8 +380,8 @@ def test_existing_kernels_pass_contract_check(rng):
         assert out.shape == (2, 16, 8)
         pt = jnp.asarray(np.arange(1, 9, dtype=np.int32).reshape(2, 4))
         out = paged_decode_attention(
-            arr(2, 1, 2, 8), arr(1, 9, 4, 8), arr(1, 9, 4, 8), pt,
-            jnp.array([5, 9], jnp.int32), interpret=True)
+            arr(2, 1, 2, 8), arr(2, 1, 9, 4, 8), arr(2, 1, 9, 4, 8), pt,
+            jnp.array([5, 9], jnp.int32), 1, interpret=True)
         assert out.shape == (2, 1, 2, 8)
 
 
@@ -393,12 +393,12 @@ def test_paged_kernel_passes_under_jit(rng):
     def arr(*shape):
         return jnp.asarray(rng.standard_normal(shape), jnp.float32)
 
-    fn = jax.jit(lambda q, k, v, pt, ln: paged_decode_attention(
-        q, k, v, pt, ln, interpret=True))
+    fn = jax.jit(lambda q, k, v, pt, ln, layer: paged_decode_attention(
+        q, k, v, pt, ln, layer, interpret=True))
     pt = jnp.asarray(np.arange(1, 9, dtype=np.int32).reshape(2, 4))
     with checking(True):
-        out = fn(arr(2, 1, 2, 8), arr(1, 9, 4, 8), arr(1, 9, 4, 8), pt,
-                 jnp.array([5, 9], jnp.int32))
+        out = fn(arr(2, 1, 2, 8), arr(2, 1, 9, 4, 8), arr(2, 1, 9, 4, 8), pt,
+                 jnp.array([5, 9], jnp.int32), jnp.int32(1))
     assert out.shape == (2, 1, 2, 8)
 
 

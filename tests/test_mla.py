@@ -80,12 +80,19 @@ def test_latent_rows_are_stored_lane_padded_with_zeros():
     assert not jnp.any(q[..., cfg.latent_row:])
 
 
+# the latent kernel reads layer LAYER of a 3-layer stack of pools whose
+# other layers hold NaN
+LAYER = 1
+
+
 def _pool_case(key, B, H, width, page_size, n_p, lens, dtype=jnp.bfloat16):
     ks = jax.random.split(key, 3)
     n_pages = 1 + B * n_p
     q = jax.random.normal(ks[0], (B, 1, H, width)).astype(dtype)
     pool = jax.random.normal(ks[1], (1, n_pages, page_size, width)
                              ).astype(dtype)
+    nan = jnp.full_like(pool, jnp.nan)
+    pool = jnp.stack([nan, pool, nan])
     table = jax.random.permutation(ks[2], jnp.arange(1, n_pages)).reshape(
         B, n_p).astype(jnp.int32)
     return q, pool, table, jnp.asarray(lens, jnp.int32)
@@ -99,10 +106,11 @@ def _pool_case(key, B, H, width, page_size, n_p, lens, dtype=jnp.bfloat16):
 def test_latent_kernel_matches_its_oracle(page_size, n_p, lens):
     q, pool, table, lens = _pool_case(jax.random.PRNGKey(3), len(lens), 4,
                                       128, page_size, n_p, lens)
-    out = decode_attn.mla_decode_attention(q, pool, table, lens, scale=0.09,
-                                           rank=96, interpret=True)
-    want = dec_ref.mla_decode_reference(q, pool, table, lens, scale=0.09,
-                                        rank=96)
+    out = decode_attn.mla_decode_attention(q, pool, table, lens, LAYER,
+                                           scale=0.09, rank=96,
+                                           interpret=True)
+    want = dec_ref.mla_decode_reference(q, pool, table, lens, LAYER,
+                                        scale=0.09, rank=96)
     assert out.shape == (len(lens), 1, 4, 96)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), atol=2e-2)
@@ -113,10 +121,11 @@ def test_latent_kernel_at_published_head_widths():
     q, pool, table, lens = _pool_case(jax.random.PRNGKey(4), 3, 16, 640, 16,
                                       36, [2, 300, 576])
     scale = mla.softmax_scale(get_config("deepseek-v2-lite"))
-    out = decode_attn.mla_decode_attention(q, pool, table, lens, scale=scale,
-                                           rank=512, interpret=True)
-    want = dec_ref.mla_decode_reference(q, pool, table, lens, scale=scale,
-                                        rank=512)
+    out = decode_attn.mla_decode_attention(q, pool, table, lens, LAYER,
+                                           scale=scale, rank=512,
+                                           interpret=True)
+    want = dec_ref.mla_decode_reference(q, pool, table, lens, LAYER,
+                                        scale=scale, rank=512)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), atol=2e-2)
 
@@ -124,14 +133,15 @@ def test_latent_kernel_at_published_head_widths():
 def test_latent_kernel_never_reads_past_the_length():
     q, pool, table, lens = _pool_case(jax.random.PRNGKey(5), 2, 4, 128, 16,
                                       40, [100, 513], dtype=jnp.float32)
-    live = np.zeros(pool.shape[1], bool)
+    live = np.zeros(pool.shape[2], bool)
     for b, n in enumerate(np.asarray(lens)):
         live[np.asarray(table[b, :-(-int(n) // 16)])] = True
-    bad = pool.at[0, ~live].set(jnp.nan)
-    out = decode_attn.mla_decode_attention(q, bad, table, lens, scale=0.1,
-                                           rank=96, interpret=True)
-    want = dec_ref.mla_decode_reference(q, pool, table, lens, scale=0.1,
-                                        rank=96)
+    bad = pool.at[LAYER, 0, ~live].set(jnp.nan)
+    out = decode_attn.mla_decode_attention(q, bad, table, lens, LAYER,
+                                           scale=0.1, rank=96,
+                                           interpret=True)
+    want = dec_ref.mla_decode_reference(q, pool, table, lens, LAYER,
+                                        scale=0.1, rank=96)
     assert bool(jnp.isfinite(out).all())
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-4)
 
@@ -216,6 +226,71 @@ def test_latent_pool_refuses_int8():
         transformer.make_caches(_cfg(), 2, 64, jnp.float32,
                                 cache_kind="paged", page_size=16, n_pages=9,
                                 kv_dtype="int8")
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_latent_step_writes_only_its_rows_into_each_layer(step):
+    """One decode step, or one prefill chunk, through the latent pools of
+    all three layers (the dense head's own pool and the two-layer stack
+    the scan carries): every (layer, page, offset) it does not write is
+    bit-identical after it, and the rows it writes are the latent rows the
+    same tokens get layer by layer through contiguous caches (decode: the
+    same history, gathered from the pools; the chunk, at offset 0: the
+    whole-prompt prefill), so a row written into the wrong layer, or a
+    layer that reads another's pool, fails."""
+    cfg = _cfg()
+    params = _params(cfg, seed=4)
+    B, ps, n_p = 2, 4, 4
+    S, n_pages = n_p * ps, 1 + B * n_p
+    pt = jnp.asarray(np.random.default_rng(0).permutation(
+        np.arange(1, n_pages)).reshape(B, n_p), jnp.int32)
+    paged = transformer.make_caches(cfg, B, S, jnp.float32,
+                                    cache_kind="paged", page_size=ps,
+                                    n_pages=n_pages)
+    leaves, treedef = jax.tree_util.tree_flatten(paged)
+    paged = treedef.unflatten([
+        jax.random.normal(k, a.shape) for a, k in
+        zip(leaves, jax.random.split(jax.random.PRNGKey(1), len(leaves)))])
+    # every pool as (layers, n_pages, ps, row): the head's, then the stack's
+    stack = lambda c: jnp.concatenate(                 # noqa: E731
+        [c["head0"]["c"], c["blocks"]["pos0"]["c"][:, 0]])
+    if step == "decode":
+        toks = jnp.asarray([3, 7], jnp.int32)
+        clen = jnp.asarray([5, 10], jnp.int32)
+        act = jnp.ones((B,), bool)
+        gather = lambda pool: pool[0][pt].reshape(B, S, -1)  # noqa: E731
+        contig = {"head0": {"c": gather(paged["head0"]["c"])},
+                  "blocks": {"pos0": {"c": jax.vmap(gather)(
+                      paged["blocks"]["pos0"]["c"])}}}
+        _, new = transformer.decode_step(params, toks, paged, clen, cfg,
+                                         FCFG, page_table=pt, active=act)
+        _, ref = transformer.decode_step(params, toks, contig, clen, cfg,
+                                         FCFG, active=act)
+        rows = [(b, int(n), b, int(n)) for b, n in enumerate(clen)]
+        ref_rows = jnp.concatenate([ref["head0"]["c"][None],
+                                    ref["blocks"]["pos0"]["c"]])
+    else:
+        slot, C = 1, 8
+        toks = jnp.asarray([[5, 9, 2, 11, 4, 8, 1, 6]], jnp.int32)
+        new = transformer.prefill_chunk(
+            params, toks, paged, jnp.int32(slot), jnp.int32(0), jnp.int32(C),
+            cfg, FCFG, page_table=pt)
+        _, ref = transformer.prefill(
+            params, toks, transformer.make_caches(cfg, 1, C, jnp.float32),
+            cfg, FCFG)
+        rows = [(slot, p, 0, p) for p in range(C)]
+        ref_rows = jnp.concatenate([ref["head0"]["c"][None],
+                                    ref["blocks"]["pos0"]["c"]])
+    old, new = stack(paged), stack(new)              # (3, n_pages, ps, row)
+    written = np.zeros(old.shape[:3], bool)
+    for b, p, _, _ in rows:
+        written[:, int(pt[b, p // ps]), p % ps] = True
+    np.testing.assert_array_equal(np.asarray(new)[~written],
+                                  np.asarray(old)[~written])
+    for b, p, rb, rp in rows:
+        np.testing.assert_allclose(new[:, int(pt[b, p // ps]), p % ps],
+                                   ref_rows[:, rb, rp], rtol=1e-5,
+                                   atol=1e-5)
 
 
 def test_engine_serves_through_the_paged_latent_pool():

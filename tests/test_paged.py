@@ -62,6 +62,18 @@ def test_allocator_exhaustion_leaves_state_untouched():
 # ---------------------------------------------------------------------------
 
 
+# The kernels read layer LAYER of a stack of pools whose other layers hold
+# NaN (int8 pools: NaN scale rows), so a kernel that reads the wrong layer
+# returns NaN.
+LAYER = 1
+
+
+def _stack(pool, fill=jnp.nan):
+    """``pool`` as layer LAYER of a 3-layer stack, the others ``fill``."""
+    other = jnp.full_like(pool, fill)
+    return jnp.stack([other, pool, other])
+
+
 # Block-walk shapes: 16-page (512-token) blocks over a 35-page table, two
 # whole blocks and a ragged third.  Lengths: one token, one page, one
 # block, one past it, the whole table; then a slot aliasing the first 18
@@ -114,11 +126,11 @@ def test_block_walk_shapes_cover_a_ragged_multi_block_table(n_kv, dtype):
 def test_paged_kernel_matches_gather_reference(case):
     q, pt, lens, pool = _decode_case(case, 0)
     ks = jax.random.split(jax.random.PRNGKey(1), 2)
-    kp = jax.random.normal(ks[0], pool, jnp.float32)
-    vp = jax.random.normal(ks[1], pool, jnp.float32)
-    out = decode_attn.paged_decode_attention(q, kp, vp, pt, lens,
+    kp = _stack(jax.random.normal(ks[0], pool, jnp.float32))
+    vp = _stack(jax.random.normal(ks[1], pool, jnp.float32))
+    out = decode_attn.paged_decode_attention(q, kp, vp, pt, lens, LAYER,
                                              interpret=True)
-    want = dec_ref.paged_decode_reference(q, kp, vp, pt, lens)
+    want = dec_ref.paged_decode_reference(q, kp, vp, pt, lens, LAYER)
     np.testing.assert_allclose(out, want, atol=1e-5)
 
 
@@ -139,20 +151,22 @@ def test_paged_kernel_never_reads_skipped_pages(kv):
     dead = jnp.asarray(~live)[None, :, None, None]
     ks = jax.random.split(jax.random.PRNGKey(3), 2)
     if kv == "fp":
-        kp, vp = (jax.random.normal(k, pool) for k in ks)
-        want = dec_ref.paged_decode_reference(q, kp, vp, pt, lens)
+        kp, vp = (_stack(jax.random.normal(k, pool)) for k in ks)
+        want = dec_ref.paged_decode_reference(q, kp, vp, pt, lens, LAYER)
         kp, vp = (jnp.where(dead, jnp.nan, x) for x in (kp, vp))
-        out = decode_attn.paged_decode_attention(q, kp, vp, pt, lens,
+        out = decode_attn.paged_decode_attention(q, kp, vp, pt, lens, LAYER,
                                                  interpret=True)
     else:
         (_, kq, kscale), (_, vq, vscale) = (
             _quantized_pools(k, pool[1], PS, pool[0], DH) for k in ks)
+        kq, vq = _stack(kq, 0), _stack(vq, 0)
+        kscale, vscale = _stack(kscale), _stack(vscale)
         want = dec_ref.paged_decode_reference_int8(q, kq, vq, kscale, vscale,
-                                                   pt, lens)
+                                                   pt, lens, LAYER)
         kscale, vscale = (jnp.where(dead, jnp.nan, x)
                           for x in (kscale, vscale))
         out = decode_attn.paged_decode_attention_int8(
-            q, kq, vq, kscale, vscale, pt, lens, interpret=True)
+            q, kq, vq, kscale, vscale, pt, lens, LAYER, interpret=True)
     assert bool(jnp.isfinite(out).all())
     np.testing.assert_allclose(out, want, atol=1e-5)
 
@@ -167,12 +181,13 @@ def test_paged_verify_kernel_matches_reference():
                             jnp.int32)
     ks = jax.random.split(jax.random.PRNGKey(5), 3)
     q = jax.random.normal(ks[0], (4, W, KV * group, DH), jnp.float32)
-    kp = jax.random.normal(ks[1], (KV, n_pages, PS, DH), jnp.float32)
-    vp = jax.random.normal(ks[2], (KV, n_pages, PS, DH), jnp.float32)
+    kp = _stack(jax.random.normal(ks[1], (KV, n_pages, PS, DH), jnp.float32))
+    vp = _stack(jax.random.normal(ks[2], (KV, n_pages, PS, DH), jnp.float32))
     from repro.kernels.decode import ops as dec_ops
-    out = dec_ops.paged_verify_attention(q, kp, vp, pt, cache_len,
+    out = dec_ops.paged_verify_attention(q, kp, vp, pt, cache_len, LAYER,
                                          interpret=True)
-    want = famous.paged_verify_attention(q, kp, vp, pt, cache_len, cfg=FCFG)
+    want = famous.paged_verify_attention(q, kp, vp, pt, cache_len, LAYER,
+                                         cfg=FCFG)
     np.testing.assert_allclose(out, want, atol=1e-5)
 
 
@@ -201,18 +216,18 @@ def test_paged_chunk_prefill_kernel_matches_reference():
     n_pages = 1 + B * n_p
     ks = jax.random.split(jax.random.PRNGKey(4), 3)
     q = jax.random.normal(ks[0], (B, C, H, dh), jnp.float32)
-    kp = jax.random.normal(ks[1], (KV, n_pages, ps, dh), jnp.float32)
-    vp = jax.random.normal(ks[2], (KV, n_pages, ps, dh), jnp.float32)
+    kp = _stack(jax.random.normal(ks[1], (KV, n_pages, ps, dh), jnp.float32))
+    vp = _stack(jax.random.normal(ks[2], (KV, n_pages, ps, dh), jnp.float32))
     rng = np.random.default_rng(4)
     pt = jnp.asarray(
         rng.permutation(np.arange(1, n_pages)).reshape(B, n_p), jnp.int32)
     from repro.kernels.decode import ops as dec_ops
     for off in (0, 8, 21):
         out = dec_ops.paged_chunk_prefill_attention(q, kp, vp, pt,
-                                                    jnp.int32(off),
+                                                    jnp.int32(off), LAYER,
                                                     interpret=True)
         want = dec_ref.paged_chunk_prefill_reference(q, kp, vp, pt,
-                                                     jnp.int32(off))
+                                                     jnp.int32(off), LAYER)
         np.testing.assert_allclose(out, want, atol=1e-5)
 
 
@@ -224,6 +239,12 @@ def _quantized_pools(key, n_pages, ps, KV, dh):
     return pool, q, s.swapaxes(-1, -2).astype(jnp.float32)
 
 
+def _stacked_quantized_pools(key, n_pages, ps, KV, dh):
+    """:func:`_quantized_pools`, each as layer LAYER of a 3-layer stack."""
+    pool, q, s = _quantized_pools(key, n_pages, ps, KV, dh)
+    return _stack(pool), _stack(q, 0), _stack(s)
+
+
 @pytest.mark.parametrize("case", ["mixed", "gqa_blocks", "mha_blocks"])
 def test_paged_int8_decode_kernel_matches_oracle(case):
     """Int8 paged decode: Pallas in-kernel dequant vs the XLA
@@ -231,14 +252,15 @@ def test_paged_int8_decode_kernel_matches_oracle(case):
     pre-quantization pool (lossy but bounded drift)."""
     q, pt, lens, (KV, n_pages, ps, dh) = _decode_case(case, 7)
     ks = jax.random.split(jax.random.PRNGKey(8), 2)
-    kf, kq, kscale = _quantized_pools(ks[0], n_pages, ps, KV, dh)
-    vf, vq, vscale = _quantized_pools(ks[1], n_pages, ps, KV, dh)
+    kf, kq, kscale = _stacked_quantized_pools(ks[0], n_pages, ps, KV, dh)
+    vf, vq, vscale = _stacked_quantized_pools(ks[1], n_pages, ps, KV, dh)
     out = decode_attn.paged_decode_attention_int8(q, kq, vq, kscale, vscale,
-                                                  pt, lens, interpret=True)
+                                                  pt, lens, LAYER,
+                                                  interpret=True)
     want = dec_ref.paged_decode_reference_int8(q, kq, vq, kscale, vscale,
-                                               pt, lens)
+                                               pt, lens, LAYER)
     np.testing.assert_allclose(out, want, atol=1e-5)
-    fp = decode_attn.paged_decode_attention(q, kf, vf, pt, lens,
+    fp = decode_attn.paged_decode_attention(q, kf, vf, pt, lens, LAYER,
                                             interpret=True)
     drift = float(jnp.abs(out - fp).max())
     assert 0 < drift < 0.05, drift
@@ -250,17 +272,18 @@ def test_paged_int8_chunk_prefill_kernel_matches_oracle():
     n_pages = 1 + B * n_p
     ks = jax.random.split(jax.random.PRNGKey(8), 3)
     q = jax.random.normal(ks[0], (B, C, H, dh), jnp.float32)
-    _, kq, kscale = _quantized_pools(ks[1], n_pages, ps, KV, dh)
-    _, vq, vscale = _quantized_pools(ks[2], n_pages, ps, KV, dh)
+    _, kq, kscale = _stacked_quantized_pools(ks[1], n_pages, ps, KV, dh)
+    _, vq, vscale = _stacked_quantized_pools(ks[2], n_pages, ps, KV, dh)
     rng = np.random.default_rng(8)
     pt = jnp.asarray(
         rng.permutation(np.arange(1, n_pages)).reshape(B, n_p), jnp.int32)
     from repro.kernels.decode import ops as dec_ops
     for off in (0, 8, 21):
         out = dec_ops.paged_chunk_prefill_attention_int8(
-            q, kq, vq, kscale, vscale, pt, jnp.int32(off), interpret=True)
+            q, kq, vq, kscale, vscale, pt, jnp.int32(off), LAYER,
+            interpret=True)
         want = dec_ref.paged_chunk_prefill_reference_int8(
-            q, kq, vq, kscale, vscale, pt, jnp.int32(off))
+            q, kq, vq, kscale, vscale, pt, jnp.int32(off), LAYER)
         np.testing.assert_allclose(out, want, atol=1e-5)
 
 
@@ -288,9 +311,109 @@ def test_paged_matches_contiguous_decode(impl):
                            .transpose(3, 0, 1, 2, 4))
     pt = jnp.asarray(ids, jnp.int32)
     fcfg = FamousConfig(impl=impl)
-    paged = famous.paged_decode_attention(q, kp, vp, pt, lens, cfg=fcfg)
+    paged = famous.paged_decode_attention(q, _stack(kp), _stack(vp), pt, lens,
+                                          LAYER, cfg=fcfg)
     dense = famous.decode_attention(q, kc, vc, lens, cfg=fcfg)
     np.testing.assert_allclose(paged, dense, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the layer scan's in-place writes into the stacked pools
+# ---------------------------------------------------------------------------
+
+
+def _filled(tree, key):
+    """Every pool of a cache tree filled at random: fp rows normal, int8
+    rows uniform over [-127, 127], scale rows in [0.01, 0.05]."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    keys = jax.random.split(key, len(leaves))
+
+    def fill(a, k):
+        if a.dtype == jnp.int8:
+            return jax.random.randint(k, a.shape, -127, 128).astype(jnp.int8)
+        if a.ndim == 5 and a.shape[-2] == 1:          # scale rows
+            return jax.random.uniform(k, a.shape, a.dtype, 0.01, 0.05)
+        return jax.random.normal(k, a.shape, a.dtype)
+
+    return treedef.unflatten([fill(a, k) for a, k in zip(leaves, keys)])
+
+
+def _contiguous_history(pools, pt):
+    """The contiguous caches (layers, B, n_p * ps, KV, dh) holding each
+    slot's pages of each layer of ``pools`` in table order, dequantized."""
+    n = pools["k"].shape[0]
+    if "k_scale" in pools:
+        return {name: jnp.stack([dec_ref.gather_pages_int8(
+            pools[name], pools[name + "_scale"], pt, l) for l in range(n)])
+            for name in ("k", "v")}
+    return {name: jnp.stack([dec_ref.gather_pages(pools[name], pt, l)
+                             for l in range(n)]) for name in ("k", "v")}
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+@pytest.mark.parametrize("kv", ["fp", "int8"])
+def test_paged_step_writes_only_its_rows_into_each_layer(kv, step):
+    """One decode step, or one prefill chunk, on a 3-layer stack of pools
+    (Pallas kernels, interpreted): every (layer, kv head, page, offset) it
+    does not write is bit-identical after it, and the rows it writes are
+    the K/V the same step computes layer by layer through contiguous
+    per-slot caches holding the same history (int8: within a quantization
+    step), so a row written into the wrong layer, or a layer that reads
+    another's pool, fails."""
+    cfg = shrink(get_config("qwen2-7b"), num_layers=3)
+    params = _params(cfg)
+    B, ps, n_p = 2, 4, 4
+    n_pages = 1 + B * n_p
+    pt = jnp.asarray(np.random.default_rng(0).permutation(
+        np.arange(1, n_pages)).reshape(B, n_p), jnp.int32)
+    paged = _filled(transformer.make_caches(
+        cfg, B, n_p * ps, jnp.float32, cache_kind="paged", page_size=ps,
+        n_pages=n_pages, kv_dtype=kv), jax.random.PRNGKey(1))
+    contig = {"blocks": {"pos0": _contiguous_history(
+        paged["blocks"]["pos0"], pt)}}
+    pcfg = FamousConfig(impl="pallas")
+    if step == "decode":
+        toks = jnp.asarray([3, 7], jnp.int32)
+        clen = jnp.asarray([5, 10], jnp.int32)
+        act = jnp.ones((B,), bool)
+        _, new = transformer.decode_step(params, toks, paged, clen, cfg, pcfg,
+                                         page_table=pt, active=act)
+        _, ref = transformer.decode_step(params, toks, contig, clen, cfg,
+                                         FCFG, active=act)
+        rows = [(b, int(n)) for b, n in enumerate(clen)]
+    else:
+        slot, offset, C = 1, 4, 8
+        toks = jnp.asarray([[5, 9, 2, 11, 4, 8, 0, 0]], jnp.int32)
+        args = (jnp.int32(slot), jnp.int32(offset), jnp.int32(6))
+        new = transformer.prefill_chunk(params, toks, paged, *args, cfg, pcfg,
+                                        page_table=pt)
+        ref = transformer.prefill_chunk(params, toks, contig, *args, cfg,
+                                        FCFG)
+        rows = [(slot, p) for p in range(offset, offset + C)]
+    old, new, ref = (c["blocks"]["pos0"] for c in (paged, new, ref))
+    written = np.zeros(old["k"].shape[:4], bool)       # (L, KV, n_pages, ps)
+    for b, p in rows:
+        written[:, :, int(pt[b, p // ps]), p % ps] = True
+    for name, pool in old.items():
+        mask = written[..., None, :] if name.endswith("scale") else written
+        np.testing.assert_array_equal(np.asarray(new[name])[~mask],
+                                      np.asarray(pool)[~mask])
+    for b, p in rows:
+        at = (slice(None), slice(None), int(pt[b, p // ps]), p % ps)
+        for name in ("k", "v"):
+            got, want = new[name][at], ref[name][:, b, p]   # (L, KV, dh)
+            if kv == "fp":
+                np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+            else:
+                # in quantization steps: layer 0's input is the same on
+                # both paths, so its rows round within half a step; later
+                # layers' inputs drift, as the paged step attends over the
+                # rows it wrote quantized and the contiguous one over them
+                # exact, by less than another half
+                scale = new[name + "_scale"][at[:3] + (0, p % ps)]
+                steps = jnp.abs(got * scale[..., None] - want) / scale[..., None]
+                assert float(steps[0].max()) <= 0.5 + 1e-4
+                assert float(steps.max()) <= 1.0
 
 
 # ---------------------------------------------------------------------------
