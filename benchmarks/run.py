@@ -7,8 +7,8 @@ import sys
 
 def main() -> None:
     from benchmarks import (analytical_validation, kernels_bench,
-                            roofline_report, serving_bench, table1_sweep,
-                            table2_baselines, table34_accelerators)
+                            roofline_report, table1_sweep, table2_baselines,
+                            table34_accelerators)
     only = sys.argv[1] if len(sys.argv) > 1 else None
     mods = {
         "table1": table1_sweep,
@@ -16,7 +16,6 @@ def main() -> None:
         "table34": table34_accelerators,
         "analytical": analytical_validation,
         "kernels": kernels_bench,
-        "serving": serving_bench,
         "roofline": roofline_report,
     }
     for name, mod in mods.items():

@@ -239,7 +239,7 @@ def shared_prompt_requests(seed: int, vocab: int):
 def engine_kw(**over):
     from repro.kernels import catalog
     kw = dict(n_slots=catalog.SLOTS, max_seq=catalog.MAX_SEQ,
-              cache_kind="paged", page_size=catalog.PAGE, chunk=catalog.CHUNK)
+              page_size=catalog.PAGE, chunk=catalog.CHUNK)
     kw.update(over)
     return kw
 

@@ -1,8 +1,8 @@
 """Serving driver: batched requests through the Scheduler/Runtime engine
-(token-budgeted chunked prefill interleaved with batched decode) on a
-reduced qwen2-style model — once monolithic, once chunked, once
-chunked+paged, and once with the prefix cache (cold then warm) — checking
-the generated tokens are identical every way (docs/serving.md).
+(token-budgeted chunked prefill into the paged pool, interleaved with
+batched decode) on a reduced qwen2-style model — once plain, and once with
+the prefix cache (cold then warm) — checking the generated tokens are
+identical every way (docs/serving.md), then the int8 KV cache's memory.
 
     PYTHONPATH=src python examples/serve_lm.py
 """
@@ -43,51 +43,39 @@ def main():
         return [Request(rid=i, tokens=list(p), max_new=16)
                 for i, p in enumerate(prompts)]
 
-    mono, _ = serve(params, cfg, reqs(), "monolithic",
-                    prefill_mode="monolithic")
-    chunked, _ = serve(params, cfg, reqs(), "chunked")
-    paged, _ = serve(params, cfg, reqs(), "chunked + paged",
-                     cache_kind="paged", page_size=16)
+    plain, _ = serve(params, cfg, reqs(), "chunked + paged")
     # prefix cache: the first pass publishes every prompt's full blocks on
     # retirement; the second pass (same prompts, same engine) aliases them
     # and skips the cached prefill outright — still token-identical.
     cold, eng = serve(params, cfg, reqs(), "prefix cache (cold)",
-                      cache_kind="paged", page_size=16, prefix_cache=True)
+                      prefix_cache=True)
     warm, _ = serve(params, cfg, reqs(), "prefix cache (warm)", engine=eng)
-    assert [r.out for r in mono] == [r.out for r in chunked], \
-        "chunked prefill must be token-identical"
-    assert [r.out for r in mono] == [r.out for r in paged], \
-        "paged cache must be token-identical"
-    assert [r.out for r in mono] == [r.out for r in cold] \
+    assert [r.out for r in plain] == [r.out for r in cold] \
         == [r.out for r in warm], "prefix cache must be token-identical"
     assert eng.prefix_hit_pages > 0, "warm pass must alias cached pages"
-    print("monolithic == chunked == chunked+paged == prefix-cached "
-          "(cold & warm), token for token")
+    print("plain == prefix-cached (cold & warm), token for token")
     print(f"  warm pass reused {eng.prefix_hit_pages} pages / "
           f"{eng.prefix_hit_tokens} prompt tokens from the prefix cache")
-    for r in mono[:4]:
+    for r in plain[:4]:
         print(f"  req {r.rid:2d} | prompt len {len(r.tokens):3d} -> {r.out}")
 
     # int8 quantized KV: same pool geometry, ~3.2x the live tokens per byte.
     # Quantization is lossy, so on THIS random-init model (near-tie logit
-    # margins) greedy tokens may flip at a few positions — the drift-bounded
-    # parity gate lives in benchmarks/serving_bench.py, which checks
-    # token-identical greedy on a trained model instead.  Here we assert the
-    # memory win and that errors stay at zero, and report the agreement.
+    # margins) greedy tokens may flip at a few positions.  Here we assert
+    # the memory win and that errors stay at zero, and report the
+    # agreement.
     q8, qeng = serve(params, cfg, reqs(), "chunked + paged int8",
-                     cache_kind="paged", page_size=16, kv_dtype="int8")
+                     kv_dtype="int8")
     assert all(r.error is None for r in q8)
     bytes_of = lambda e: sum(b.size * b.dtype.itemsize for b in
                              jax.tree_util.tree_leaves(e.caches))
-    _, fpeng = serve(params, cfg, reqs(), "chunked + paged fp",
-                     cache_kind="paged", page_size=16)
-    ratio = bytes_of(qeng) / bytes_of(fpeng)
+    ratio = bytes_of(qeng) / bytes_of(eng)
     assert ratio <= 0.55, f"int8 cache bytes ratio {ratio:.3f} not halved"
-    agree = np.mean([a == b for rf, rq in zip(mono, q8)
+    agree = np.mean([a == b for rf, rq in zip(plain, q8)
                      for a, b in zip(rf.out, rq.out)])
     print(f"int8 KV cache: {ratio:.2f}x the fp cache bytes, "
           f"{agree:.0%} token agreement with fp greedy on random-init "
-          f"weights (trained-model parity gated in serving_bench)")
+          f"weights")
 
 
 if __name__ == "__main__":

@@ -37,25 +37,17 @@ python -m repro.analysis
 echo "== examples/quickstart.py smoke =="
 python examples/quickstart.py
 
-# --- serving bench smoke: scheduler / chunked-prefill / prefix-cache
-# regressions fail here (the prefix rows assert warm==cold token parity,
-# pages actually saved, and the O(1)-executable census) ---------------------
-echo "== benchmarks/serving_bench.py smoke (tiny config) =="
-PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" SERVING_BENCH_TINY=1 \
-  python benchmarks/serving_bench.py
-
 # --- observability: launcher smoke with metrics + profiler spans ----------
 # Serve a small batch with the span-enabled Observer under a jax.profiler
 # trace, then validate both exports: the Perfetto file must hold one
 # engine.step span per engine step (numbered in order, none overlapping)
 # with every prefill-chunk/decode/sync/retire span inside one, and the
 # Prometheus exposition must pass the format checker
-# (docs/observability.md).  The tok/s overhead gate lives in the serving
-# bench's obs_off/obs_on rows above.
+# (docs/observability.md).
 echo "== observability =="
 OBS_TMP=$(mktemp -d)
 python -m repro.launch.serve --arch qwen2-7b --size shrink --requests 4 --slots 2 \
-  --max-new 4 --cache-kind paged --prefix-cache \
+  --max-new 4 --prefix-cache \
   --metrics-out "$OBS_TMP/metrics.prom" --trace-out "$OBS_TMP/trace"
 python - "$OBS_TMP" <<'EOF'
 import glob, gzip, json, sys
@@ -85,19 +77,15 @@ EOF
 rm -rf "$OBS_TMP"
 
 # --- multi-device: mesh-sharded serving ------------------------------------
-# Fresh processes with 8 forced host devices (the main suite and benches
-# above must keep their 1-device view — tests/conftest.py): the TP parity
-# matrix, the TP=2 retrace gate, and the tp1/tp2/tp4 sharded bench rows
-# (token-identical outputs, per-device KV bytes 1/TP, O(1) census).
+# Fresh processes with 8 forced host devices (the main suite above must
+# keep its 1-device view — tests/conftest.py): the TP parity matrix
+# (token-identical outputs, per-device KV bytes 1/TP) and the TP=2
+# retrace gate (O(1) census).
 echo "== multi-device (XLA_FLAGS=--xla_force_host_platform_device_count=8) =="
 XLA_FLAGS="--xla_force_host_platform_device_count=8" \
   python -m pytest -x -q tests/test_mesh_serving.py
 XLA_FLAGS="--xla_force_host_platform_device_count=8" \
   python -m repro.analysis --no-lint --no-kernel-check
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-  PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" \
-  SERVING_BENCH_TINY=1 SERVING_BENCH_MESH_ONLY=1 \
-  python benchmarks/serving_bench.py
 
 # --- full test suite -------------------------------------------------------
 exec python -m pytest -x -q "$@"

@@ -56,8 +56,7 @@ def run_kernel_check() -> int:
 
     from repro.analysis import kernel_check
     from repro.kernels.attention.mha import mha_backward, mha_forward
-    from repro.kernels.decode.chunk_prefill import (chunk_prefill,
-                                                    paged_chunk_prefill,
+    from repro.kernels.decode.chunk_prefill import (paged_chunk_prefill,
                                                     paged_chunk_prefill_int8)
     from repro.kernels.decode.decode_attn import (
         decode_attention, paged_decode_attention,
@@ -107,9 +106,6 @@ def run_kernel_check() -> int:
                              qarr(2, 1, 9, 4, 8), sarr(2, 1, 9, 1, 4), sarr(2, 1, 9, 1, 4),
                              pt, jnp.array([5, 9], jnp.int32), 1,
                              interpret=True)))
-        launches.append(("decode/chunk_prefill", lambda: chunk_prefill(
-            arr(2, 8, 8), arr(2, 16, 8), arr(2, 16, 8), 4, chunk=4,
-            block_k=8, interpret=True)))
         launches.append(("decode/paged_chunk_prefill",
                          lambda: paged_chunk_prefill(
                              arr(2, 1, 8, 8), arr(2, 1, 9, 4, 8),
@@ -187,8 +183,8 @@ def run_retrace() -> int:
     # the same executables — kv_dtype is a cache-structure choice, not a
     # static jit argument, so the census must stay O(1) here too
     q8 = ServingEngine(params, cfg, FamousConfig(impl="xla"),
-                       n_slots=2, max_seq=32, chunk=8,
-                       cache_kind="paged", page_size=8, kv_dtype="int8")
+                       n_slots=2, max_seq=32, chunk=8, page_size=8,
+                       kv_dtype="int8")
     q8.run(reqs(40))
     try:
         with retrace_guard(q8, label="warm int8 paged decode loop"):

@@ -73,7 +73,7 @@ class FamousConfig:
 
 # Where each kernel wrapper's operands keep their heads (or kv heads): the
 # dim index, or None for operands every device holds whole.
-HEADS = 2        # (B, S, H|KV, dh) activations and contiguous caches
+HEADS = 2        # (B, S, H|KV, dh) activations and ring caches
 POOL = 1         # (L, KV, n_pages, ...) the layer stack's page and scale pools
 REP = None       # page tables, lengths, offsets, layer indices, x
 
@@ -410,34 +410,14 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window=0, scale=None,
     return out.astype(q.dtype)
 
 
-def verify_attention(q, k_cache, v_cache, cache_len, *, scale=None,
-                     cfg: FamousConfig = FamousConfig()):
-    """Speculative-verify attention against a contiguous KV cache.
-
-    q: (B, W, H, dh) — the W verify tokens of each slot at absolute
-    positions ``cache_len[b] + j`` (their K/V already written); caches:
-    (B, S_max, KV, dh); cache_len: (B,) int32.  Query j attends keys at
-    positions ``<= cache_len[b] + j`` — W == 1 is exactly
-    :func:`decode_attention`, so a zero-draft slot verifies as a plain
-    decode.  The per-slot offsets are runtime operands: one executable
-    serves every draft-length mix (``W`` is the engine's static
-    ``draft_k + 1`` cap; short drafts ride as masked pad rows).
-
-    impl="pallas" flattens each (slot, verify position) pair into a row of
-    the decode kernel (per-row lengths — see kernels/decode/ops.py);
-    other impls run the dense masked oracle below.
-    """
+def _verify_dense(q, k, v, cache_len, scale):
+    """Dense masked speculative-verify attention over gathered (B, S, KV,
+    dh) views: query j of slot b, at position ``cache_len[b] + j``, attends
+    keys at positions ``<= cache_len[b] + j`` (W == 1 is a plain decode)."""
     B, W, H, dh = q.shape
-    Smax = k_cache.shape[1]
-    scale = float(scale) if scale is not None else 1.0 / math.sqrt(dh)
-    if cfg.impl == "pallas":
-        from repro.kernels.decode import ops as dec_ops
-        return _per_device(
-            cfg, functools.partial(dec_ops.verify_attention, scale=scale,
-                                   block_k=cfg.tile_k),
-            (HEADS, HEADS, HEADS, REP), HEADS, q, k_cache, v_cache, cache_len)
-    k = _broadcast_kv(k_cache, H)
-    v = _broadcast_kv(v_cache, H)
+    Smax = k.shape[1]
+    k = _broadcast_kv(k, H)
+    v = _broadcast_kv(v, H)
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale,
                    k.astype(jnp.float32))
     q_pos = cache_len[:, None] + jnp.arange(W)[None, :]         # (B, W)
@@ -483,10 +463,13 @@ def paged_verify_attention(q, k_pages, v_pages, page_table, cache_len, layer,
 
     q: (B, W, H, dh) at per-slot positions ``cache_len[b] + j``; pools: the
     layer stack's (L, KV, n_pages, page_size, dh); page_table: (B, n_p)
-    int32; layer: () int32, the layer read.  impl="pallas" flattens (slot,
-    verify position) pairs into rows of the scalar-prefetched page-table
-    decode kernel; other impls gather the table into a contiguous view and
-    reuse :func:`verify_attention`.
+    int32; layer: () int32, the layer read.  Query j attends keys at
+    positions ``<= cache_len[b] + j`` — W == 1 is exactly a decode, so a
+    zero-draft slot verifies as a plain decode; the per-slot offsets are
+    runtime operands, so one executable serves every draft-length mix.
+    impl="pallas" flattens (slot, verify position) pairs into rows of the
+    scalar-prefetched page-table decode kernel; other impls gather the
+    table into a contiguous view and run the dense masked form.
 
     With ``k_scale``/``v_scale`` (fp32 (L, KV, n_pages, 1, page_size)
     pools) the K/V pools are int8 and dequantized in-kernel (pallas) or via
@@ -499,7 +482,7 @@ def paged_verify_attention(q, k_pages, v_pages, page_table, cache_len, layer,
                              page_table, cache_len, layer, scale, k_scale,
                              v_scale)
     k, v = _gather_layer(k_pages, v_pages, page_table, layer, k_scale, v_scale)
-    return verify_attention(q, k, v, cache_len, scale=scale, cfg=cfg)
+    return _verify_dense(q, k, v, cache_len, scale)
 
 
 def attention_at_positions(q, k, v, q_pos, k_pos, *, window=0, scale=None):
@@ -528,31 +511,15 @@ def attention_at_positions(q, k, v, q_pos, k_pos, *, window=0, scale=None):
     return out.astype(q.dtype)
 
 
-def chunked_prefill_attention(q, k_cache, v_cache, q_offset, *, scale=None,
-                              cfg: FamousConfig = FamousConfig()):
-    """Chunked-prefill attention: a chunk of C query tokens at absolute
-    positions ``[q_offset, q_offset + C)`` attends causally to the resident
-    prefix *plus its own chunk*, both already written into the cache.
-
-    q: (B, C, H, dh); caches: (B, S_max, KV, dh) with the chunk's K/V rows
-    already written at ``[q_offset, q_offset + C)``.  ``q_offset`` is a
-    runtime scalar — one executable serves every (prompt length, chunk
-    index) pair, the paper's "reprogram loop bounds, never re-synthesise"
-    applied to prefill.  impl="pallas" streams key tiles through the
-    online-softmax kernel in kernels/decode; other impls run the dense
-    masked reference (the parity oracle).
-    """
+def _chunk_dense(q, k, v, q_offset, scale):
+    """Dense masked chunked-prefill attention over gathered (B, S, KV, dh)
+    views: chunk query i, at position ``q_offset + i``, attends keys at
+    positions ``<= q_offset + i`` (the resident prefix plus its own
+    chunk)."""
     B, C, H, dh = q.shape
-    Skv = k_cache.shape[1]
-    scale = float(scale) if scale is not None else 1.0 / math.sqrt(dh)
-    if cfg.impl == "pallas":
-        from repro.kernels.decode import ops as dec_ops
-        return _per_device(
-            cfg, functools.partial(dec_ops.chunk_prefill_attention,
-                                   scale=scale, block_k=cfg.tile_k),
-            (HEADS, HEADS, HEADS, REP), HEADS, q, k_cache, v_cache, q_offset)
-    k = _broadcast_kv(k_cache, H)
-    v = _broadcast_kv(v_cache, H)
+    Skv = k.shape[1]
+    k = _broadcast_kv(k, H)
+    v = _broadcast_kv(v, H)
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale,
                    k.astype(jnp.float32))
     q_pos = q_offset + jnp.arange(C)
@@ -572,11 +539,15 @@ def paged_chunked_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
     q: (B, C, H, dh) at positions ``[q_offset, q_offset + C)``; pools: the
     layer stack's (L, KV, n_pages, page_size, dh); page_table: (B, n_p)
     int32; layer: () int32.  The chunk's K/V must already be scattered
-    into the slot's pages of that layer.  impl="pallas"
-    reuses the scalar-prefetched page-table BlockSpec machinery of
-    ``paged_decode_attention``; other impls gather the table into a
-    contiguous view and run the dense chunked reference.  ``k_scale``/
-    ``v_scale`` select the int8-pool path (see paged_verify_attention).
+    into the slot's pages of that layer; the chunk attends causally to the
+    resident prefix *plus its own chunk*.  ``q_offset`` is a runtime
+    scalar — one executable serves every (prompt length, chunk index)
+    pair, the paper's "reprogram loop bounds, never re-synthesise" applied
+    to prefill.  impl="pallas" reuses the scalar-prefetched page-table
+    BlockSpec machinery of ``paged_decode_attention``; other impls gather
+    the table into a contiguous view and run the dense masked form.
+    ``k_scale``/``v_scale`` select the int8-pool path (see
+    paged_verify_attention).
     """
     B, C, H, dh = q.shape
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(dh)
@@ -585,7 +556,7 @@ def paged_chunked_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
                              v_pages, page_table, q_offset, layer, scale,
                              k_scale, v_scale)
     k, v = _gather_layer(k_pages, v_pages, page_table, layer, k_scale, v_scale)
-    return chunked_prefill_attention(q, k, v, q_offset, scale=scale, cfg=cfg)
+    return _chunk_dense(q, k, v, q_offset, scale)
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, cache_len, layer,

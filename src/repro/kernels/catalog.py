@@ -71,7 +71,7 @@ def _mha_reference_lse(q, k, v):
 
 def main_path_cases(cfg: ModelConfig) -> list[KernelCase]:
     """Every kernel the paged, chunked-prefill serving path (and the
-    contiguous and training paths next to it) launches, at ``cfg``'s
+    window-ring and training paths next to it) launches, at ``cfg``'s
     widths and the module's serving sizes."""
     KV, H, dh = cfg.num_kv_heads, cfg.num_heads, cfg.head_dim
     group = H // KV
@@ -127,12 +127,6 @@ def main_path_cases(cfg: ModelConfig) -> list[KernelCase]:
                 normal(ks[1], (rows, S, dh)), normal(ks[2], (rows, S, dh)),
                 lens(ks[3], rows))
 
-    def chunk_contig(key):
-        ks = jax.random.split(key, 4)
-        return (normal(ks[0], (1, chunk, H, dh)),
-                normal(ks[1], (1, S, KV, dh)), normal(ks[2], (1, S, KV, dh)),
-                offset(ks[3]))
-
     def mha_fwd(key):
         ks = jax.random.split(key, 3)
         return (normal(ks[0], (H, S, dh)), normal(ks[1], (KV, S, dh)),
@@ -169,10 +163,6 @@ def main_path_cases(cfg: ModelConfig) -> list[KernelCase]:
                    dec_ref.paged_chunk_prefill_reference_int8),
         KernelCase("decode", decode, decode_attn.decode_attention,
                    dec_ref.decode_reference),
-        KernelCase("chunk_prefill", chunk_contig,
-                   lambda *a: dec_ops.chunk_prefill_attention(
-                       *a, interpret=False),
-                   dec_ref.chunk_prefill_reference),
         KernelCase("mha_forward_lse", mha_fwd,
                    lambda q, k, v: mha.mha_forward(q, k, v, causal=True,
                                                    return_lse=True),

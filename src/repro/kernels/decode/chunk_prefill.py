@@ -2,8 +2,8 @@
 
 A fixed-shape chunk of C query tokens at absolute positions
 ``[q_offset, q_offset + C)`` attends causally to the resident prefix plus
-its own chunk, already written into the KV cache — contiguous per-slot
-stripes or a shared page pool.  One executable serves every (prompt
+its own chunk, already written into the slot's pages of the shared page
+pool.  One executable serves every (prompt
 length, chunk index) pair: the offset arrives as a runtime scalar and the
 page table is scalar-prefetched, exactly like ``paged_decode_attention``
 in decode_attn.py — the paper's "reprogram loop bounds, never
@@ -15,11 +15,10 @@ query head ``g`` at chunk position ``c`` — so a single K/V tile DMA feeds
 every grouped query head and every chunk position at once (FAMOUS's
 shared-K-BRAM PE grouping).
 
-Correctness-over-speed note: the contiguous kernel's grid covers every
-key tile of the cache and relies on the ``k_pos <= q_pos`` mask; tiles
-entirely beyond the chunk contribute nothing.  Skipping them needs a
-dynamic grid (offset-dependent) — one executable per offset — which is
-exactly what this refactor removes.
+Correctness-over-speed note: the grid covers every page of the slot's
+table and relies on the ``k_pos <= q_pos`` mask; pages entirely beyond the
+chunk contribute nothing.  Skipping them needs a dynamic grid
+(offset-dependent) — one executable per offset.
 """
 from __future__ import annotations
 
@@ -33,71 +32,6 @@ from jax.experimental import pallas as pl
 from repro.kernels import pallas_compat as pc
 from repro.kernels.decode.decode_attn import (NEG_INF, layer_operand,
                                               online_softmax_step)
-
-
-def _chunk_prefill_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
-                          m_ref, l_ref, *, scale: float, block_k: int,
-                          n_k: int, chunk: int):
-    ik = pl.program_id(1)
-
-    @pl.when(ik == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    q = q_ref[0].astype(jnp.float32) * scale          # (group*C, dh)
-    k = k_ref[0].astype(jnp.float32)                  # (block_k, dh)
-    v = v_ref[0].astype(jnp.float32)
-    off = off_ref[0, 0]
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    c = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) % chunk
-    ok = k_pos <= off + c                              # causal incl. own chunk
-    online_softmax_step(s, ok, v, acc_ref, m_ref, l_ref)
-
-    @pl.when(ik == n_k - 1)
-    def _flush():
-        o_ref[0, ...] = (acc_ref[...]
-                         / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
-
-
-def chunk_prefill(q, k_cache, v_cache, q_offset, *, chunk: int,
-                  scale: float | None = None, block_k: int = 512,
-                  interpret: bool = False):
-    """q: (BKV, group*C, dh) with row = g*C + c; caches: (BKV, Skv, dh);
-    q_offset: () int32 runtime scalar.  Returns (BKV, group*C, dh)."""
-    BKV, rows, dh = q.shape
-    Skv = k_cache.shape[1]
-    scale = scale if scale is not None else 1.0 / math.sqrt(dh)
-    block_k = min(block_k, Skv)
-    assert Skv % block_k == 0, (Skv, block_k)
-    assert rows % chunk == 0, (rows, chunk)
-    n_k = Skv // block_k
-    off = jnp.asarray(q_offset, jnp.int32).reshape(1, 1)
-    kernel = functools.partial(_chunk_prefill_kernel, scale=float(scale),
-                               block_k=block_k, n_k=n_k, chunk=chunk)
-    return pc.pallas_call(
-        kernel,
-        grid=(BKV, n_k),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, ik: (0, 0), memory_space=pc.SMEM),
-            pl.BlockSpec((1, rows, dh), lambda b, ik: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, dh), lambda b, ik: (b, ik, 0)),
-            pl.BlockSpec((1, block_k, dh), lambda b, ik: (b, ik, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, rows, dh), lambda b, ik: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((BKV, rows, dh), q.dtype),
-        scratch_shapes=[
-            pc.VMEM((rows, dh), jnp.float32),
-            pc.VMEM((rows, 1), jnp.float32),
-            pc.VMEM((rows, 1), jnp.float32),
-        ],
-        compiler_params=pc.compiler_params("parallel", "arbitrary"),
-        interpret=interpret,
-    )(off, q, k_cache, v_cache)
 
 
 def _paged_chunk_prefill_kernel(off_ref, layer_ref, pt_ref, q_ref, k_ref,

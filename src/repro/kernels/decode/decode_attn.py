@@ -11,7 +11,8 @@ runtime-programmable "sequence length register" of the paper's µB.
 
 Two cache layouts share the online-softmax inner loop:
 
-  * ``decode_attention``       — contiguous (BKV, Skv, dh) per-slot caches.
+  * ``decode_attention``       — per-slot (BKV, Skv, dh) caches: the
+    sliding-window layers' ring buffers.
   * ``paged_decode_attention`` — the layer stack's kv-head-major page
     pools (L, KV, n_pages, page_size, dh), left in HBM; a scalar-prefetched
     layer index and per-slot page table aim the kernel's own page DMAs (no

@@ -1,17 +1,18 @@
 """Jitted wrappers for the decode / chunked-prefill attention kernels:
 (B, S, H, dh) model layout to the kernels' GQA-flattened row layouts.
 
-The contiguous wrappers flatten **kv-major** — row ``kv * B + b``, not
-``b * KV + kv`` — so the merged row dim is a concatenation of contiguous
-per-kv-head blocks.  Under tensor parallelism the caches shard over the
-kv-head dim; kv-major keeps each device's rows a contiguous slab of the
-flattened operand (b-major would interleave shards token-by-token), so
-GSPMD partitions the reshape instead of all-gathering around it.  Row
-order is otherwise irrelevant: rows are independent, and the inverse
-transpose restores the exact (B, S, H, dh) layout, bit for bit.  The
-paged wrappers keep (B, KV, group, dh) unflattened — already shardable —
-and take the layer stack's pools (L, KV, n_pages, page_size, dh) with the
-layer to read as a runtime operand, so no caller slices a layer out."""
+The ring-buffer wrapper (:func:`decode_attention`) flattens **kv-major** —
+row ``kv * B + b``, not ``b * KV + kv`` — so the merged row dim is a
+concatenation of contiguous per-kv-head blocks.  Under tensor parallelism
+the caches shard over the kv-head dim; kv-major keeps each device's rows a
+contiguous slab of the flattened operand (b-major would interleave shards
+token-by-token), so GSPMD partitions the reshape instead of all-gathering
+around it.  Row order is otherwise irrelevant: rows are independent, and
+the inverse transpose restores the exact (B, S, H, dh) layout, bit for
+bit.  The paged wrappers keep (B, KV, group, dh) unflattened — already
+shardable — and take the layer stack's pools (L, KV, n_pages, page_size,
+dh) with the layer to read as a runtime operand, so no caller slices a
+layer out."""
 from __future__ import annotations
 
 import functools
@@ -95,51 +96,17 @@ def paged_decode_attention_int8(q, k_pages, v_pages, k_scale, v_scale,
     return out.reshape(B, 1, H, dh)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "block_k", "interpret"))
-def verify_attention(q, k_cache, v_cache, cache_len, *, scale=None,
-                     block_k=512, interpret=None):
-    """Speculative verify on the decode kernel: q: (B, W, H, dh) at per-slot
-    positions ``cache_len[b] + j`` (K/V already written); caches:
-    (B, Skv, KV, dh); cache_len: (B,) int32.  Returns (B, W, H, dh).
-
-    Each (slot, verify position) pair becomes its own kernel row with
-    length ``cache_len[b] + j + 1`` — the decode kernel already supports
-    per-row lengths, so verify needs no new Pallas code, only this
-    flattening (which broadcasts each slot's cache W ways; acceptable for
-    the small ``W = draft_k + 1`` the engine uses).
-    """
-    B, W, H, dh = q.shape
-    Skv, KV = k_cache.shape[1], k_cache.shape[2]
-    group = H // KV
-    interpret = pc.interpret_default() if interpret is None else interpret
-    # (B, W, H, dh) -> (B, W, KV, group, dh) -> kv-major (KV*B*W, group, dh)
-    qf = (q.reshape(B, W, KV, group, dh).transpose(2, 0, 1, 3, 4)
-          .reshape(KV * B * W, group, dh))
-    kf = jnp.broadcast_to(k_cache.transpose(2, 0, 1, 3)[:, :, None],
-                          (KV, B, W, Skv, dh)).reshape(KV * B * W, Skv, dh)
-    vf = jnp.broadcast_to(v_cache.transpose(2, 0, 1, 3)[:, :, None],
-                          (KV, B, W, Skv, dh)).reshape(KV * B * W, Skv, dh)
-    # pad rows past a slot's real draft may exceed Skv — clip (their
-    # output is discarded by the engine's accept loop anyway)
-    lens = jnp.minimum(cache_len[:, None] + jnp.arange(W, dtype=jnp.int32)
-                       + 1, Skv)
-    out = decode_attn.decode_attention(qf, kf, vf,
-                                       jnp.tile(lens.reshape(-1), KV),
-                                       window=0, scale=scale,
-                                       block_k=block_k, interpret=interpret)
-    return (out.reshape(KV, B, W, group, dh).transpose(1, 2, 0, 3, 4)
-            .reshape(B, W, H, dh))
-
-
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_verify_attention(q, k_pages, v_pages, page_table, cache_len, layer,
                            *, scale=None, interpret=None):
     """Paged speculative verify: q: (B, W, H, dh); pools:
     (L, KV, n_pages, page_size, dh); page_table: (B, n_p) int32; cache_len:
-    (B,) int32; layer: () int32.  Returns (B, W, H, dh).  Same flattening as
-    :func:`verify_attention`, on the scalar-prefetched page-table kernel —
-    only the page *table* is repeated per verify position (a few ints per
-    row), never the pool itself."""
+    (B,) int32; layer: () int32.  Returns (B, W, H, dh).  Each (slot,
+    verify position) pair becomes its own row of the scalar-prefetched
+    page-table decode kernel, with length ``cache_len[b] + j + 1`` (clipped
+    at the table's end; the engine discards those pad rows) — only the page
+    *table* is repeated per verify position (a few ints per row), never the
+    pool itself."""
     B, W, H, dh = q.shape
     KV, ps = k_pages.shape[1], k_pages.shape[3]
     n_p = page_table.shape[1]
@@ -174,28 +141,6 @@ def paged_verify_attention_int8(q, k_pages, v_pages, k_scale, v_scale,
         qf, k_pages, v_pages, k_scale, v_scale, pt, lens, layer,
         scale=scale, interpret=interpret)
     return out.reshape(B, W, H, dh)
-
-
-@functools.partial(jax.jit, static_argnames=("scale", "block_k", "interpret"))
-def chunk_prefill_attention(q, k_cache, v_cache, q_offset, *, scale=None,
-                            block_k=512, interpret=None):
-    """q: (B, C, H, dh) at positions [q_offset, q_offset+C); caches:
-    (B, Skv, KV, dh) with the chunk rows already written; q_offset: ()
-    int32 runtime scalar.  Returns (B, C, H, dh)."""
-    B, C, H, dh = q.shape
-    Skv, KV = k_cache.shape[1], k_cache.shape[2]
-    group = H // KV
-    interpret = pc.interpret_default() if interpret is None else interpret
-    # (B, C, H, dh) -> (KV, B, group, C, dh) -> kv-major (KV*B, group*C, dh)
-    qf = (q.reshape(B, C, KV, group, dh).transpose(2, 0, 3, 1, 4)
-          .reshape(KV * B, group * C, dh))
-    kf = k_cache.transpose(2, 0, 1, 3).reshape(KV * B, Skv, dh)
-    vf = v_cache.transpose(2, 0, 1, 3).reshape(KV * B, Skv, dh)
-    out = chunk_kernels.chunk_prefill(qf, kf, vf, q_offset, chunk=C,
-                                      scale=scale, block_k=block_k,
-                                      interpret=interpret)
-    return (out.reshape(KV, B, group, C, dh).transpose(1, 3, 0, 2, 4)
-            .reshape(B, C, H, dh))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))

@@ -9,7 +9,6 @@ and extract memory/cost/collective data for EXPERIMENTS.md §Dry-run/§Roofline.
     PYTHONPATH=src python -m repro.launch.dryrun --all --mesh both
 """
 import argparse
-import functools
 import json
 import time
 import traceback
@@ -77,21 +76,24 @@ def lower_serve(cfg, shape, mesh, rules=None, fcfg=None, dtype=jnp.bfloat16):
     tok_sh = shd.batch_sharding(mesh, dec_specs["tokens"].ndim, rules,
                                 dec_specs["tokens"].shape)
     len_sh = shd.batch_sharding(mesh, 1, rules, dec_specs["cache_len"].shape)
+    pt_sh = shd.replicated(mesh)    # the engine's host-built page table
     logits_sh = shd.sharding_for_axes(
         mesh, ("batch", "vocab"), rules,
         (shape.global_batch, cfg.vocab_size))
 
-    def serve_step(params, tokens, caches, cache_len):
+    def serve_step(params, tokens, caches, cache_len, page_table):
         return transformer.decode_step(params, tokens, caches, cache_len,
-                                       cfg, fcfg)
+                                       cfg, fcfg, page_table=page_table)
 
     with jax.set_mesh(mesh), use_rules(rules):
         jitted = jax.jit(serve_step,
-                         in_shardings=(params_sh, tok_sh, cache_sh, len_sh),
+                         in_shardings=(params_sh, tok_sh, cache_sh, len_sh,
+                                       pt_sh),
                          out_shardings=((logits_sh, cache_sh)),
                          donate_argnums=2)
         return jitted.lower(params_shapes, dec_specs["tokens"],
-                            dec_specs["caches"], dec_specs["cache_len"])
+                            dec_specs["caches"], dec_specs["cache_len"],
+                            dec_specs["page_table"])
 
 
 def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str = OUT_DIR,
@@ -151,7 +153,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str = OUT_DIR
 
 def lower_prefill(cfg, shape, mesh, rules=None, fcfg=None,
                   dtype=jnp.bfloat16):
-    """Inference prefill: forward to last-token logits + cache build."""
+    """Inference prefill: the full-sequence forward to last-token logits."""
     fcfg = fcfg or FamousConfig(impl="xla")
     from repro.models import module
     spec = transformer.model_spec(cfg)
@@ -159,26 +161,22 @@ def lower_prefill(cfg, shape, mesh, rules=None, fcfg=None,
     params_sh = shd.tree_shardings(mesh, module.logical_axes(spec), rules,
                                    params_shapes)
     in_specs = specs_lib.train_input_specs(cfg, shape, dtype)
-    cache_shapes = transformer.make_caches(cfg, shape.global_batch,
-                                           shape.seq_len, dtype,
-                                           shapes_only=True)
-    cache_sh = shd.tree_shardings(mesh, transformer.cache_axes(cfg), rules,
-                                  cache_shapes)
     in_sh = shd.batch_sharding(mesh, in_specs["inputs"].ndim, rules,
                                in_specs["inputs"].shape)
     logits_sh = shd.sharding_for_axes(
         mesh, ("batch", "vocab"), rules,
         (shape.global_batch, cfg.vocab_size))
 
-    def prefill_step(params, inputs, caches):
-        return transformer.prefill(params, inputs, caches, cfg, fcfg)
+    def prefill_step(params, inputs):
+        x = transformer.forward(params, inputs, cfg, fcfg, remat=False,
+                                return_hidden=True)
+        return transformer.logits_fn(params, x[:, -1:], cfg)[:, 0]
 
     with jax.set_mesh(mesh), use_rules(rules):
         jitted = jax.jit(prefill_step,
-                         in_shardings=(params_sh, in_sh, cache_sh),
-                         out_shardings=(logits_sh, cache_sh),
-                         donate_argnums=2)
-        return jitted.lower(params_shapes, in_specs["inputs"], cache_shapes)
+                         in_shardings=(params_sh, in_sh),
+                         out_shardings=logits_sh)
+        return jitted.lower(params_shapes, in_specs["inputs"])
 
 
 def all_cells() -> list[tuple[str, str]]:

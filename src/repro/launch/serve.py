@@ -98,12 +98,6 @@ def main():
     ap.add_argument("--max-seq", type=int, default=128)
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--prefill-mode", choices=("chunked", "monolithic"),
-                    default="chunked",
-                    help="chunked: fixed-shape prefill chunks interleaved "
-                         "with decode under the token budget (O(1) "
-                         "executables); monolithic: whole-prompt prefill at "
-                         "admission (legacy comparison baseline)")
     ap.add_argument("--chunk", type=int, default=32,
                     help="prefill chunk length (must divide max-seq)")
     ap.add_argument("--token-budget", type=int, default=0,
@@ -116,18 +110,14 @@ def main():
                          "(0 = greedy)")
     ap.add_argument("--top-k", type=int, default=0,
                     help="per-request top-k filter (0 = full vocab)")
-    ap.add_argument("--cache-kind", choices=("contiguous", "paged"),
-                    default="contiguous",
-                    help="KV-cache layout: per-slot max_seq stripes, or a "
-                         "shared page pool with per-slot page tables")
     ap.add_argument("--page-size", type=int, default=16,
-                    help="tokens per KV page (paged mode)")
+                    help="tokens per KV page (must divide max-seq)")
     ap.add_argument("--n-pages", type=int, default=0,
-                    help="pool pages incl. the null page (paged mode); "
-                         "0 = full contiguous-equivalent capacity — pass "
-                         "less to oversubscribe")
+                    help="pool pages incl. the null page; 0 = enough for "
+                         "every slot at max-seq — pass less to "
+                         "oversubscribe")
     ap.add_argument("--kv-dtype", choices=("fp", "int8"), default="fp",
-                    help="KV-cache element type (paged only): int8 stores "
+                    help="KV-cache element type: int8 stores "
                          "quantized pages plus per-token-per-kv-head fp32 "
                          "scale pools and dequantizes inside the attention "
                          "kernels — ~3.2x the live-token capacity per byte "
@@ -135,9 +125,8 @@ def main():
                          "docs/serving.md)")
     ap.add_argument("--prefix-cache", action="store_true",
                     help="share identical full prompt blocks across "
-                         "requests via refcounted pages (paged + chunked "
-                         "only; recurrent/hybrid archs fall back to cold "
-                         "prefill)")
+                         "requests via refcounted pages (recurrent/hybrid "
+                         "archs fall back to cold prefill)")
     ap.add_argument("--speculative", action="store_true",
                     help="draft-model-free speculative decoding: a host-side "
                          "prompt-lookup drafter proposes tokens, one batched "
@@ -189,10 +178,8 @@ def main():
     engine = build_engine(args.arch, size=args.size, seed=args.seed,
                           mesh=mesh, observer=obs,
                           n_slots=args.slots, max_seq=args.max_seq,
-                          cache_kind=args.cache_kind,
                           page_size=args.page_size,
                           n_pages=args.n_pages or None,
-                          prefill_mode=args.prefill_mode,
                           chunk=args.chunk,
                           token_budget=args.token_budget,
                           prefix_cache=args.prefix_cache,
@@ -205,14 +192,13 @@ def main():
               f"{mesh.devices.size} of {jax.device_count()} devices; "
               f"kv/state cache {engine.cache_bytes_per_device()} "
               f"bytes/device")
-    if engine.paged:
-        cache_bytes = sum(b.size * b.dtype.itemsize for b in
-                          jax.tree_util.tree_leaves(engine.caches))
-        pc = engine.pcfg
-        print(f"kv cache: paged dtype={engine.kv_dtype}, "
-              f"{pc.n_pages} pages x {pc.page_size} tokens "
-              f"({cache_bytes // pc.n_pages} bytes/page incl. scales), "
-              f"live-token capacity={(pc.n_pages - 1) * pc.page_size}")
+    cache_bytes = sum(b.size * b.dtype.itemsize for b in
+                      jax.tree_util.tree_leaves(engine.caches))
+    pc = engine.pcfg
+    print(f"kv cache: paged dtype={engine.kv_dtype}, "
+          f"{pc.n_pages} pages x {pc.page_size} tokens "
+          f"({cache_bytes // pc.n_pages} bytes/page incl. scales), "
+          f"live-token capacity={(pc.n_pages - 1) * pc.page_size}")
     rng = np.random.default_rng(args.seed)
     # --prefix-cache demo: every request shares a "system prompt" head, the
     # workload prefix caching exists for (otherwise prompts are disjoint)
@@ -242,8 +228,7 @@ def main():
     print(f"served {len(done)} requests, {tok} tokens in {dt:.2f}s "
           f"({tok/dt:.1f} tok/s), executables: prefill={census['prefill']} "
           f"decode={census['decode']} verify={census['verify']} "
-          f"clear={census['clear']} "
-          f"(mode={args.prefill_mode}, cache={engine.cache_kind})")
+          f"clear={census['clear']}")
     if args.speculative:
         print(f"speculative: active={engine.speculative_active}, "
               f"draft_k={args.draft_k}, "
@@ -251,10 +236,9 @@ def main():
               f"(rate {engine.acceptance_rate:.2f}), "
               f"{engine.accepted_per_step:.2f} tokens/verify-step over "
               f"{engine.spec_steps} steps")
-    if engine.paged:
-        print(f"page pool: {engine.pcfg.n_pages} pages x "
-              f"{engine.pcfg.page_size} tokens, "
-              f"{engine.alloc.free_pages} free after drain")
+    print(f"page pool: {engine.pcfg.n_pages} pages x "
+          f"{engine.pcfg.page_size} tokens, "
+          f"{engine.alloc.free_pages} free after drain")
     if args.prefix_cache:
         print(f"prefix cache: active={engine.prefix_cache_active}, "
               f"{engine.prefix_hit_pages} pages / "

@@ -1,8 +1,7 @@
 """Attention block: GQA dense MHA built on the FAMOUS core, with KV caching
-(full or sliding-window ring buffer) for serving."""
+for serving: global layers in shared page pools, sliding-window layers in
+per-slot ring buffers."""
 from __future__ import annotations
-
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,27 +32,24 @@ def attn_spec(cfg: ModelConfig) -> dict:
     return spec
 
 
-def make_attn_cache(cfg: ModelConfig, batch: int, max_seq: int, window: int,
-                    dtype) -> dict:
-    """KV cache. Sliding-window layers use an O(window) ring buffer."""
-    slots = min(max_seq, window) if window else max_seq
-    kv, dh = cfg.num_kv_heads, cfg.head_dim
-    shape = (batch, slots, kv, dh)
-    return {
-        "k": jnp.zeros(shape, dtype),
-        "v": jnp.zeros(shape, dtype),
-    }
-
-
-def attn_cache_shape(cfg: ModelConfig, batch: int, max_seq: int, window: int,
+def ring_cache_shape(cfg: ModelConfig, batch: int, max_seq: int,
                      dtype) -> dict:
-    slots = min(max_seq, window) if window else max_seq
-    kv, dh = cfg.num_kv_heads, cfg.head_dim
-    sds = jax.ShapeDtypeStruct((batch, slots, kv, dh), dtype)
+    """A sliding-window layer's per-slot ring buffer: position p of slot b
+    lives at ``[b, p % ring]``, ``ring = min(window, max_seq)``."""
+    ring = min(max_seq, cfg.window)
+    sds = jax.ShapeDtypeStruct((batch, ring, cfg.num_kv_heads, cfg.head_dim),
+                               dtype)
     return {"k": sds, "v": sds}
 
 
-ATTN_CACHE_AXES = {"k": ("batch", None, "kv_heads", "head_dim"),
+def make_ring_cache(cfg: ModelConfig, batch: int, max_seq: int,
+                    dtype) -> dict:
+    """Zero-filled :func:`ring_cache_shape` buffers."""
+    return jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype),
+                                  ring_cache_shape(cfg, batch, max_seq, dtype))
+
+
+RING_CACHE_AXES = {"k": ("batch", None, "kv_heads", "head_dim"),
                    "v": ("batch", None, "kv_heads", "head_dim")}
 
 # Paged pools have no slot axis and are kv-head-major —
@@ -173,92 +169,52 @@ def apply_attn(p: dict, x: jax.Array, cfg: ModelConfig,
     return jnp.einsum("bshe,hed->bsd", out, p["wo"].astype(out.dtype))
 
 
-def apply_attn_prefill(p: dict, x: jax.Array, cache: dict, cfg: ModelConfig,
-                       fcfg: famous.FamousConfig, *, window: int = 0):
-    """Prefill: full attention + populate the KV cache. Returns (out, cache)."""
-    S = x.shape[1]
-    positions = jnp.arange(S)
-    q, k, v = _project(p, x, cfg, fcfg, positions)
-    out = famous.attention(q, k, v, causal=cfg.causal, window=window, cfg=fcfg)
-    slots = cache["k"].shape[1]
-    if slots >= S:
-        cache = {
-            "k": jax.lax.dynamic_update_slice_in_dim(cache["k"], k, 0, axis=1),
-            "v": jax.lax.dynamic_update_slice_in_dim(cache["v"], v, 0, axis=1),
-        }
-    else:  # ring buffer keeps the last `slots` positions at pos % slots
-        tail_k, tail_v = k[:, S - slots:], v[:, S - slots:]
-        shift = S % slots  # slot of the oldest kept position
-        idx = (jnp.arange(slots) + shift) % slots
-        inv = jnp.argsort(idx)
-        cache = {"k": tail_k[:, inv], "v": tail_v[:, inv]}
-    o = jnp.einsum("bshe,hed->bsd", out, p["wo"].astype(out.dtype))
-    return o, cache
-
-
 def apply_attn_chunk(p: dict, x: jax.Array, cache: dict, slot, offset,
-                     n_valid, cfg: ModelConfig, fcfg: famous.FamousConfig, *,
-                     window: int = 0):
-    """Chunked prefill for one slot of the *batched* cache.
+                     n_valid, cfg: ModelConfig, fcfg: famous.FamousConfig):
+    """Chunked prefill of a sliding-window layer for one slot of the
+    *batched* ring buffers.
 
     x: (1, C, D) — the chunk at absolute positions [offset, offset+C)
-    (``offset`` a runtime scalar); cache: {"k","v"} (n_slots, S|ring, kv,
-    dh).  Writes the chunk's K/V straight into the slot (no batch-1
-    round-trip) and attends against resident prefix + own chunk.  Pad
-    positions at the chunk tail write junk K/V beyond the prompt, which is
-    never read: causal masking excludes them during prefill and decode
-    overwrites position n-1 onwards.  Returns (out (1, C, D), new cache).
+    (``offset`` a runtime scalar); cache: {"k","v"} (n_slots, ring, kv,
+    dh).  Gathers the pre-chunk ring in *position order* (positions
+    offset-ring .. offset-1; ring slot = pos % ring; negative /
+    not-yet-written positions are masked by attention_at_positions),
+    attends over [gathered ring ‖ chunk], then writes the chunk's last
+    min(n_valid, ring) real positions into the ring.  Returns
+    (out (1, C, D), new cache).
     """
     C = x.shape[1]
     positions = offset + jnp.arange(C)
     q, k, v = _project(p, x, cfg, fcfg, positions)
-    if not window:
-        ck = jax.lax.dynamic_update_slice(
-            cache["k"], k.astype(cache["k"].dtype), (slot, offset, 0, 0))
-        cv = jax.lax.dynamic_update_slice(
-            cache["v"], v.astype(cache["v"].dtype), (slot, offset, 0, 0))
-        k_slot = jax.lax.dynamic_slice(ck, (slot, 0, 0, 0),
-                                       (1,) + ck.shape[1:])
-        v_slot = jax.lax.dynamic_slice(cv, (slot, 0, 0, 0),
-                                       (1,) + cv.shape[1:])
-        out = famous.chunked_prefill_attention(q, k_slot, v_slot, offset,
-                                               cfg=fcfg)
-        cache = {"k": ck, "v": cv}
-    else:
-        # Ring buffer: gather the pre-chunk ring in *position order*
-        # (positions offset-ring .. offset-1; ring slot = pos % ring;
-        # negative / not-yet-written positions are masked by
-        # attention_at_positions), attend over [gathered ring ‖ chunk],
-        # then write the chunk's last min(C, ring) positions into the ring.
-        ring = cache["k"].shape[1]
-        kv, dh = cache["k"].shape[2], cache["k"].shape[3]
-        row_k = jax.lax.dynamic_slice(cache["k"], (slot, 0, 0, 0),
-                                      (1, ring, kv, dh))[0]
-        row_v = jax.lax.dynamic_slice(cache["v"], (slot, 0, 0, 0),
-                                      (1, ring, kv, dh))[0]
-        prev_pos = offset - ring + jnp.arange(ring)
-        order = prev_pos % ring
-        keys = jnp.concatenate([row_k[order][None].astype(k.dtype), k], axis=1)
-        vals = jnp.concatenate([row_v[order][None].astype(v.dtype), v], axis=1)
-        k_pos = jnp.concatenate([prev_pos, positions])
-        out = famous.attention_at_positions(q, keys, vals, positions, k_pos,
-                                            window=window)
-        # Write only the last min(n_valid, ring) *real* chunk positions —
-        # pad-tail junk must not clobber live window slots, and positions
-        # older than the final ring window would alias newer ones.  Masked
-        # writes are redirected to an out-of-bounds index and dropped; the
-        # surviving indices are distinct, so scatter order is irrelevant.
-        c_arr = jnp.arange(C)
-        write_ok = (c_arr < n_valid) & (c_arr >= n_valid - ring)
-        idx = jnp.where(write_ok, positions % ring, ring)
-        row_k = row_k.at[idx].set(k[0].astype(row_k.dtype), mode="drop")
-        row_v = row_v.at[idx].set(v[0].astype(row_v.dtype), mode="drop")
-        cache = {
-            "k": jax.lax.dynamic_update_slice(cache["k"], row_k[None],
-                                              (slot, 0, 0, 0)),
-            "v": jax.lax.dynamic_update_slice(cache["v"], row_v[None],
-                                              (slot, 0, 0, 0)),
-        }
+    ring = cache["k"].shape[1]
+    kv, dh = cache["k"].shape[2], cache["k"].shape[3]
+    row_k = jax.lax.dynamic_slice(cache["k"], (slot, 0, 0, 0),
+                                  (1, ring, kv, dh))[0]
+    row_v = jax.lax.dynamic_slice(cache["v"], (slot, 0, 0, 0),
+                                  (1, ring, kv, dh))[0]
+    prev_pos = offset - ring + jnp.arange(ring)
+    order = prev_pos % ring
+    keys = jnp.concatenate([row_k[order][None].astype(k.dtype), k], axis=1)
+    vals = jnp.concatenate([row_v[order][None].astype(v.dtype), v], axis=1)
+    k_pos = jnp.concatenate([prev_pos, positions])
+    out = famous.attention_at_positions(q, keys, vals, positions, k_pos,
+                                        window=cfg.window)
+    # Write only the last min(n_valid, ring) *real* chunk positions —
+    # pad-tail junk must not clobber live window slots, and positions
+    # older than the final ring window would alias newer ones.  Masked
+    # writes are redirected to an out-of-bounds index and dropped; the
+    # surviving indices are distinct, so scatter order is irrelevant.
+    c_arr = jnp.arange(C)
+    write_ok = (c_arr < n_valid) & (c_arr >= n_valid - ring)
+    idx = jnp.where(write_ok, positions % ring, ring)
+    row_k = row_k.at[idx].set(k[0].astype(row_k.dtype), mode="drop")
+    row_v = row_v.at[idx].set(v[0].astype(row_v.dtype), mode="drop")
+    cache = {
+        "k": jax.lax.dynamic_update_slice(cache["k"], row_k[None],
+                                          (slot, 0, 0, 0)),
+        "v": jax.lax.dynamic_update_slice(cache["v"], row_v[None],
+                                          (slot, 0, 0, 0)),
+    }
     o = jnp.einsum("bshe,hed->bsd", out, p["wo"].astype(out.dtype))
     return o, cache
 
@@ -292,15 +248,14 @@ def apply_attn_chunk_paged(p: dict, x: jax.Array, cache: dict, layer,
 
 
 def apply_attn_decode(p: dict, x: jax.Array, cache: dict, cache_len,
-                      cfg: ModelConfig, fcfg: famous.FamousConfig, *,
-                      window: int = 0):
-    """One-token decode. x: (B, 1, D); cache_len: (B,) valid entries BEFORE
-    this token. Returns (out, new_cache)."""
-    B = x.shape[0]
+                      cfg: ModelConfig, fcfg: famous.FamousConfig):
+    """One-token decode of a sliding-window layer against its per-slot ring
+    buffers. x: (B, 1, D); cache_len: (B,) valid entries BEFORE this token.
+    Returns (out, new_cache)."""
     positions = cache_len[:, None]  # (B, 1) absolute positions
     q, k, v = _project(p, x, cfg, fcfg, positions)
-    slots = cache["k"].shape[1]
-    slot = (cache_len % slots) if window else cache_len
+    ring = cache["k"].shape[1]
+    slot = cache_len % ring
 
     def write(buf, new):
         return jax.vmap(
@@ -308,37 +263,8 @@ def apply_attn_decode(p: dict, x: jax.Array, cache: dict, cache_len,
         )(buf, new, slot)
 
     cache = {"k": write(cache["k"], k), "v": write(cache["v"], v)}
-    valid = jnp.minimum(cache_len + 1, slots) if window else cache_len + 1
+    valid = jnp.minimum(cache_len + 1, ring)
     out = famous.decode_attention(q, cache["k"], cache["v"], valid, cfg=fcfg)
-    o = jnp.einsum("bshe,hed->bsd", out, p["wo"].astype(out.dtype))
-    return o, cache
-
-
-def apply_attn_verify(p: dict, x: jax.Array, cache: dict, cache_len,
-                      cfg: ModelConfig, fcfg: famous.FamousConfig):
-    """Speculative verify: W tokens per slot in one forward.  x: (B, W, D)
-    at absolute positions ``cache_len[b] + j``; cache_len: (B,) valid
-    entries BEFORE the first verify token.  Returns (out (B, W, D), cache).
-
-    The W tokens' K/V scatter to their per-slot positions; positions past
-    ``max_seq`` (pad rows of slots near capacity) are dropped, not
-    clamped — a clamped write would corrupt live entries.  Rejected draft
-    positions need no rollback: their K/V stay as junk past the accepted
-    ``cache_len``, masked by every later causal read and overwritten by
-    the next verify/decode writes before they ever become visible.
-    """
-    B, W = x.shape[:2]
-    positions = cache_len[:, None] + jnp.arange(W)      # (B, W)
-    q, k, v = _project(p, x, cfg, fcfg, positions)
-    b_idx = jnp.arange(B)[:, None]
-    cache = {
-        "k": cache["k"].at[b_idx, positions].set(
-            k.astype(cache["k"].dtype), mode="drop"),
-        "v": cache["v"].at[b_idx, positions].set(
-            v.astype(cache["v"].dtype), mode="drop"),
-    }
-    out = famous.verify_attention(q, cache["k"], cache["v"], cache_len,
-                                  cfg=fcfg)
     o = jnp.einsum("bshe,hed->bsd", out, p["wo"].astype(out.dtype))
     return o, cache
 
@@ -356,7 +282,9 @@ def apply_attn_verify_paged(p: dict, x: jax.Array, cache: dict, layer,
     a clamped gather would alias a live page and corrupt it).  Rollback of
     rejected tokens is the allocator's job (``PageAllocator.shrink``
     returns the tail pages grown for them); the junk K/V they leave in the
-    kept pages is masked/overwritten exactly as in the contiguous case.
+    kept pages lies past the accepted ``cache_len``, masked by every later
+    causal read and overwritten by the next verify/decode writes before it
+    ever becomes visible.
     """
     B, W = x.shape[:2]
     positions = cache_len[:, None] + jnp.arange(W)      # (B, W)

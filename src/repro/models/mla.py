@@ -15,10 +15,10 @@ the output is taken in latent space and lifted by ``W_UV`` then ``Wo``, so
 K and V are never expanded per head.  Training (:func:`apply_mla`) runs
 the plain form above; the two agree (tests/test_mla.py).
 
-Cache layouts: contiguous ``{"c": (batch, max_seq, width)}``, or a paged
-pool ``{"c": (1, n_pages, page_size, width)}`` (one "kv head", so the paged
-decode kernel's block walk serves it unchanged; see kernels/decode), which
-the serving steps take stacked over the layers and address by layer.  The
+The cache is a paged pool ``{"c": (1, n_pages, page_size, width)}`` (one
+"kv head", so the paged decode kernel's block walk serves it unchanged; see
+kernels/decode), which the serving steps take stacked over the layers and
+address by layer.  The
 stored width is the row's rounded up to the 128-lane tile (576 -> 640):
 Mosaic copies no narrower page.  Rows and queries carry zeros there, which
 add nothing to any score.
@@ -37,7 +37,6 @@ from repro.models.module import ParamSpec
 
 NORM_EPS = 1e-6
 
-CACHE_AXES = {"c": ("batch", None, None)}
 PAGED_CACHE_AXES = {"c": (None, None, None, None)}
 
 
@@ -205,11 +204,6 @@ def apply_mla(p, x, cfg: ModelConfig, fcfg: famous.FamousConfig, *,
 # ---------------------------------------------------------------------------
 
 
-def cache_shape(cfg: ModelConfig, batch: int, max_seq: int, dtype) -> dict:
-    return {"c": jax.ShapeDtypeStruct((batch, max_seq, stored_width(cfg)),
-                                      dtype)}
-
-
 def paged_cache_shape(cfg: ModelConfig, n_pages: int, page_size: int,
                       dtype) -> dict:
     return {"c": jax.ShapeDtypeStruct((1, n_pages, page_size,
@@ -220,10 +214,6 @@ def _zeros(tree):
     return jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype), tree)
 
 
-def make_cache(cfg, batch, max_seq, dtype):
-    return _zeros(cache_shape(cfg, batch, max_seq, dtype))
-
-
 def make_paged_cache(cfg, n_pages, page_size, dtype):
     return _zeros(paged_cache_shape(cfg, n_pages, page_size, dtype))
 
@@ -231,38 +221,6 @@ def make_paged_cache(cfg, n_pages, page_size, dtype):
 def _causal(q_pos, n_keys):
     """(B, Sq, n_keys) mask: key j visible to the query at q_pos iff j <= it."""
     return jnp.arange(n_keys)[None, None, :] <= q_pos[..., None]
-
-
-# ---------------------------------------------------------------------------
-# contiguous cache: whole-prompt prefill and decode (the layer stack's own
-# prefill/decode_step; the engine serves latent attention paged)
-# ---------------------------------------------------------------------------
-
-
-def apply_mla_prefill(p, x, cache, cfg: ModelConfig, fcfg):
-    """Whole-prompt prefill into a contiguous latent cache."""
-    B, S = x.shape[:2]
-    positions = jnp.arange(S)
-    rows = latent_rows(p, x, positions, cfg)
-    c = jax.lax.dynamic_update_slice_in_dim(cache["c"],
-                                            rows.astype(cache["c"].dtype),
-                                            0, axis=1)
-    q = absorbed_queries(p, x, positions, cfg)
-    ok = jnp.broadcast_to(_causal(positions, S), (B, S, S))
-    o = latent_attention(q, rows, ok, softmax_scale(cfg), cfg.kv_lora_rank)
-    return absorbed_output(p, o, cfg), {"c": c}
-
-
-def apply_mla_decode(p, x, cache, cache_len, cfg: ModelConfig, fcfg):
-    """One token per slot against a contiguous latent cache."""
-    positions = cache_len[:, None]
-    rows = latent_rows(p, x, positions, cfg)
-    c = jax.vmap(lambda b, n, s: jax.lax.dynamic_update_slice_in_dim(
-        b, n, s, axis=0))(cache["c"], rows.astype(cache["c"].dtype), cache_len)
-    q = absorbed_queries(p, x, positions, cfg)
-    ok = _causal(positions, c.shape[1])
-    o = latent_attention(q, c, ok, softmax_scale(cfg), cfg.kv_lora_rank)
-    return absorbed_output(p, o, cfg), {"c": c}
 
 
 # ---------------------------------------------------------------------------

@@ -6,20 +6,25 @@ O(layers) — essential for the 64-layer/1T-param dry-runs), plus an explicit
 tail for patterns that do not divide the layer count (recurrentgemma's 26 = 8
 × (R,R,A) + (R,R)).
 
-Three entry points:
-  * ``forward``        — full-sequence logits (training / encoder).
-  * ``prefill``        — forward + build per-layer caches (serving).
-  * ``decode_step``    — one token against the caches (serving decode;
-    contiguous per-slot caches, or paged pools when given a page table).
+Entry points:
+  * ``forward``        — full-sequence logits (training / encoder, and the
+    cache-free oracle the serving steps are tested against).
+  * ``prefill_chunk``  — one fixed-shape prompt chunk of one slot into the
+    serving caches (the only way a prompt enters them).
+  * ``decode_step`` / ``verify_step`` — one token (or a draft) per slot
+    against the serving caches.
 
-Plus slot-granular cache surgery for the serving engine
-(``write_prefill_to_slot`` / ``clear_slot``), which keeps knowledge of the
-cache tree's structure out of serve/engine.py.
+Serving caches: global (``ATTN``) and latent (``MLA``) layers keep their
+rows in shared page pools addressed through the page table each step
+receives; window (``LOCAL_ATTN``) rings and recurrent state are per-slot
+buffers.  ``clear_slot`` is the slot-granular cache surgery the serving
+engine needs, which keeps knowledge of the cache tree's structure out of
+serve/engine.py.
 """
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -207,33 +212,29 @@ def logits_fn(params, x, cfg: ModelConfig):
 
 
 # ---------------------------------------------------------------------------
-# serving: caches, prefill, decode
+# serving: caches and the decode step
 # ---------------------------------------------------------------------------
 
 
 def _block_cache(kind: str, cfg: ModelConfig, batch: int, max_seq: int, dtype,
-                 shapes_only: bool = False, cache_kind: str = "contiguous",
-                 page_size: int = 0, n_pages: int = 0, kv_dtype: str = "fp"):
+                 shapes_only: bool, page_size: int, n_pages: int,
+                 kv_dtype: str):
     if kind == MLA:
         if kv_dtype != "fp":
             raise ValueError("latent attention (MLA) caches hold fp rows; "
                              f"kv_dtype={kv_dtype!r} is not supported")
-        if cache_kind == "paged":
-            fn = mla.paged_cache_shape if shapes_only else mla.make_paged_cache
-            return fn(cfg, n_pages, page_size, dtype)
-        fn = mla.cache_shape if shapes_only else mla.make_cache
-        return fn(cfg, batch, max_seq, dtype)
-    if kind == ATTN and cache_kind == "paged":
+        fn = mla.paged_cache_shape if shapes_only else mla.make_paged_cache
+        return fn(cfg, n_pages, page_size, dtype)
+    if kind == ATTN:
         # global-attention layers share a page pool; sliding-window and
-        # recurrent layers are already O(window)/O(1) per slot and keep
-        # their per-slot buffers even in paged mode.
+        # recurrent layers are O(window)/O(1) per slot and keep per-slot
+        # buffers
         fn = (attention.paged_attn_cache_shape if shapes_only
               else attention.make_paged_attn_cache)
         return fn(cfg, n_pages, page_size, dtype, kv_dtype)
-    if kind in (ATTN, LOCAL_ATTN):
-        window = cfg.window if kind == LOCAL_ATTN else 0
-        fn = attention.attn_cache_shape if shapes_only else attention.make_attn_cache
-        return fn(cfg, batch, max_seq, window, dtype)
+    if kind == LOCAL_ATTN:
+        fn = attention.ring_cache_shape if shapes_only else attention.make_ring_cache
+        return fn(cfg, batch, max_seq, dtype)
     if kind == RGLRU:
         fn = rglru.rglru_cache_shape if shapes_only else rglru.make_rglru_cache
         return fn(cfg, batch, dtype)
@@ -253,38 +254,32 @@ def _stack_cache_tree(unit_caches: dict, n: int, shapes_only: bool):
 
 
 def make_caches(cfg: ModelConfig, batch: int, max_seq: int, dtype,
-                shapes_only: bool = False, *, cache_kind: str = "contiguous",
-                page_size: int = 0, n_pages: int = 0,
+                shapes_only: bool = False, *, page_size: int, n_pages: int,
                 kv_dtype: str = "fp") -> dict:
-    """Build the per-layer decode caches.
+    """Build the per-layer serving caches.
 
-    cache_kind="contiguous": every attention layer gets a per-slot
-    ``(batch, max_seq | window, kv, dh)`` buffer (the seed baseline).
-    cache_kind="paged": global-attention layers instead share a
-    ``(kv, n_pages, page_size, dh)`` page pool addressed through the page
-    table that ``decode_step`` receives at call time; memory then scales
-    with live tokens, not ``batch x max_seq`` (see serve/paged.py).
-    kv_dtype="int8" (paged only) stores those pools as int8 with fp32
-    per-token scale pools riding the same page ids (see
-    attention.make_paged_attn_cache); writes quantize, kernels dequantize
-    in VMEM.
+    Global-attention and latent layers share ``(kv, n_pages, page_size,
+    dh)`` page pools (``(1, n_pages, page_size, row)`` for latent rows)
+    addressed through the page table the serving steps receive at call
+    time; memory then scales with live tokens, not ``batch x max_seq``
+    (see serve/paged.py).  Window rings ``(batch, min(window, max_seq),
+    kv, dh)`` and recurrent state stay per slot.  kv_dtype="int8" stores
+    the attention pools as int8 with fp32 per-token scale pools riding the
+    same page ids (see attention.make_paged_attn_cache); writes quantize,
+    kernels dequantize in VMEM.
     """
-    assert cache_kind in ("contiguous", "paged"), cache_kind
     assert kv_dtype in ("fp", "int8"), kv_dtype
-    if kv_dtype == "int8":
-        assert cache_kind == "paged", "kv_dtype='int8' requires paged caches"
-    if cache_kind == "paged":
-        assert page_size > 0 and n_pages > 0, (page_size, n_pages)
+    assert page_size > 0 and n_pages > 0, (page_size, n_pages)
     unit = {f"pos{i}": _block_cache(k, cfg, batch, max_seq, dtype, shapes_only,
-                                    cache_kind, page_size, n_pages, kv_dtype)
+                                    page_size, n_pages, kv_dtype)
             for i, k in enumerate(cfg.pattern_unit)}
     caches: dict[str, Any] = {
         "blocks": _stack_cache_tree(unit, cfg.num_units, shapes_only)}
     for name, kinds in (("tail", cfg.tail_layers), ("head", cfg.head_layers)):
         for i, k in enumerate(kinds):
             caches[f"{name}{i}"] = _block_cache(
-                k, cfg, batch, max_seq, dtype, shapes_only, cache_kind,
-                page_size, n_pages, kv_dtype)
+                k, cfg, batch, max_seq, dtype, shapes_only, page_size,
+                n_pages, kv_dtype)
     return caches
 
 
@@ -299,23 +294,19 @@ def param_axes(cfg: ModelConfig) -> dict:
     return module.logical_axes(model_spec(cfg))
 
 
-def cache_axes(cfg: ModelConfig, cache_kind: str = "contiguous",
-               kv_dtype: str = "fp") -> dict:
-    """Logical-axis tree matching ``make_caches(cfg, ..., cache_kind=,
-    kv_dtype=)``: contiguous attention caches expose ("batch", seq,
-    "kv_heads", "head_dim"); paged pools drop the slot axis and (for int8)
-    add the scale-pool leaves, so the tree structure tracks the cache
-    structure exactly."""
+def cache_axes(cfg: ModelConfig, kv_dtype: str = "fp") -> dict:
+    """Logical-axis tree matching ``make_caches(cfg, ..., kv_dtype=)``:
+    page pools have no slot axis and (for int8) add the scale-pool leaves;
+    rings and recurrent state expose their "batch" axis."""
     def block_axes(kind):
         if kind == MLA:
-            return (mla.PAGED_CACHE_AXES if cache_kind == "paged"
-                    else mla.CACHE_AXES)
-        if kind == ATTN and cache_kind == "paged":
+            return mla.PAGED_CACHE_AXES
+        if kind == ATTN:
             return (attention.PAGED_ATTN_CACHE_AXES_INT8
                     if kv_dtype == "int8"
                     else attention.PAGED_ATTN_CACHE_AXES)
-        if kind in (ATTN, LOCAL_ATTN):
-            return attention.ATTN_CACHE_AXES
+        if kind == LOCAL_ATTN:
+            return attention.RING_CACHE_AXES
         if kind == RGLRU:
             return rglru.RGLRU_CACHE_AXES
         return rwkv6.RWKV_CACHE_AXES
@@ -332,47 +323,20 @@ def cache_axes(cfg: ModelConfig, cache_kind: str = "contiguous",
     return axes
 
 
-def _apply_block_prefill(kind, p, x, cache, cfg, fcfg):
-    n = functools.partial(layers.apply_norm, kind=cfg.norm)
-    if kind == MLA:
-        return _mla_block(p, x, cfg, fcfg, lambda h: mla.apply_mla_prefill(
-            p["attn"], h, cache, cfg, fcfg))
-    if kind in (ATTN, LOCAL_ATTN):
-        window = cfg.window if kind == LOCAL_ATTN else 0
-        a, cache = attention.apply_attn_prefill(p["attn"], n(p["ln1"], x),
-                                                cache, cfg, fcfg, window=window)
-        x = x + a
-        return x + _apply_ffn(p["ffn"], n(p["ln2"], x), cfg), cache
-    if kind == RGLRU:
-        a, cache = rglru.apply_rglru(p["rec"], n(p["ln1"], x), cfg, cache)
-        x = x + a
-        return x + _apply_ffn(p["ffn"], n(p["ln2"], x), cfg), cache
-    if kind == RWKV6:
-        a, c_tm = rwkv6.apply_rwkv_time_mix(p["tm"], n(p["ln1"], x), cfg,
-                                            cache={k: cache[k] for k in
-                                                   ("s", "x_tm")})
-        x = x + a
-        h = n(p["ln2"], x)
-        y, x_cm = rwkv6.apply_channel_mix(p["cm"], h, cfg)
-        cache = {"s": c_tm["s"], "x_tm": c_tm["x_tm"], "x_cm": h[:, -1]}
-        return x + y, cache
-    raise ValueError(kind)
+def _pooled(kind: str) -> bool:
+    """Whether a layer of ``kind`` keeps its cache in a shared page pool:
+    global and latent attention.  The serving steps carry pools through the
+    layer scan whole and address them by layer; every other cache is per
+    slot."""
+    return kind in (ATTN, MLA)
 
 
-def _pooled(kind: str, page_table) -> bool:
-    """Whether a layer of ``kind`` keeps its cache in a shared page pool in
-    this step: global and latent attention, when a page table is given.
-    The serving steps carry pools through the layer scan whole and address
-    them by layer; every other cache is per slot."""
-    return page_table is not None and kind in (ATTN, MLA)
-
-
-def _unstacked(block_fn, page_table):
+def _unstacked(block_fn):
     """``block_fn(kind, p, x, cache, layer, sink)`` for a head or tail
     layer, whose cache has no layer dim: a pool rides as a stack of one
     (a reshape, no copy) at layer 0."""
     def fn(kind, p, x, cache, sink=None):
-        if not _pooled(kind, page_table):
+        if not _pooled(kind):
             return block_fn(kind, p, x, cache, None, sink)
         x, new = block_fn(kind, p, x,
                           jax.tree_util.tree_map(lambda a: a[None], cache), 0,
@@ -381,7 +345,7 @@ def _unstacked(block_fn, page_table):
     return fn
 
 
-def _scan_units(block_fn, x, params, caches, cfg: ModelConfig, page_table):
+def _scan_units(block_fn, x, params, caches, cfg: ModelConfig):
     """Run the scanned unit stack of a serving step:
     ``block_fn(kind, p, x, cache, layer, sink) -> (x, new cache)`` for each
     layer of each unit.  Returns (x, new block caches, the sum of each
@@ -392,11 +356,11 @@ def _scan_units(block_fn, x, params, caches, cfg: ModelConfig, page_table):
     rows into the stack in place and its kernels read it at the scanned
     ``layer`` index, so no iteration slices a pool in or stacks one out
     (the caches are donated, so the carry is the input buffer).  Per-slot
-    caches (contiguous, ring, recurrent state) scan as xs/ys: a layer's
-    slice of them is read whole anyway, and ``layer`` is None for them."""
+    caches (window rings, recurrent state) scan as xs/ys: a layer's slice
+    of them is read whole anyway, and ``layer`` is None for them."""
     blocks = caches["blocks"]
     pooled = {f"pos{i}" for i, k in enumerate(cfg.pattern_unit)
-              if _pooled(k, page_table)}
+              if _pooled(k)}
     pools = {k: v for k, v in blocks.items() if k in pooled}
     per_slot = {k: v for k, v in blocks.items() if k not in pooled}
 
@@ -421,29 +385,22 @@ def _scan_units(block_fn, x, params, caches, cfg: ModelConfig, page_table):
 
 
 def _apply_block_decode(kind, p, x, cache, layer, cache_len, cfg, fcfg,
-                        page_table=None, active=None, counts=None):
+                        page_table, active=None, counts=None):
     n = functools.partial(layers.apply_norm, kind=cfg.norm)
     if kind == MLA:
-        def attend(h):
-            if page_table is not None:
-                return mla.apply_mla_decode_paged(p["attn"], h, cache, layer,
-                                                  page_table, cache_len, cfg,
-                                                  fcfg)
-            return mla.apply_mla_decode(p["attn"], h, cache, cache_len, cfg,
-                                        fcfg)
         valid = None if active is None else active[:, None]
-        return _mla_block(p, x, cfg, fcfg, attend, valid, counts)
-    if kind == ATTN and page_table is not None:
+        return _mla_block(p, x, cfg, fcfg, lambda h: mla.apply_mla_decode_paged(
+            p["attn"], h, cache, layer, page_table, cache_len, cfg, fcfg),
+            valid, counts)
+    if kind == ATTN:
         a, cache = attention.apply_attn_decode_paged(
             p["attn"], n(p["ln1"], x), cache, layer, page_table, cache_len,
             cfg, fcfg)
         x = x + a
         return x + _apply_ffn(p["ffn"], n(p["ln2"], x), cfg), cache
-    if kind in (ATTN, LOCAL_ATTN):
-        window = cfg.window if kind == LOCAL_ATTN else 0
+    if kind == LOCAL_ATTN:
         a, cache = attention.apply_attn_decode(p["attn"], n(p["ln1"], x),
-                                               cache, cache_len, cfg, fcfg,
-                                               window=window)
+                                               cache, cache_len, cfg, fcfg)
         x = x + a
         return x + _apply_ffn(p["ffn"], n(p["ln2"], x), cfg), cache
     if kind == RGLRU:
@@ -461,34 +418,6 @@ def _apply_block_decode(kind, p, x, cache, layer, cache_len, cfg, fcfg,
         cache = {"s": c_tm["s"], "x_tm": c_tm["x_tm"], "x_cm": h[:, -1]}
         return x + y, cache
     raise ValueError(kind)
-
-
-def prefill(params, inputs, caches, cfg: ModelConfig,
-            fcfg: FamousConfig = FamousConfig(), compute_dtype=None):
-    """Returns (last-position logits (B, vocab), new caches)."""
-    dtype = compute_dtype or params["final_norm"]["scale"].dtype
-    x = _embed_inputs(params, inputs, cfg, dtype)
-    x, head_caches = _apply_heads(
-        params, x, caches, cfg, lambda kind, p, x, c: _apply_block_prefill(
-            kind, p, x, c, cfg, fcfg))
-
-    def unit_body(x, scanned):
-        unit_params, unit_cache = scanned
-        new_caches = {}
-        for i, kind in enumerate(cfg.pattern_unit):
-            key = f"pos{i}"
-            x, new_caches[key] = _apply_block_prefill(
-                kind, unit_params[key], x, unit_cache[key], cfg, fcfg)
-        return x, new_caches
-
-    x, new_block_caches = jax.lax.scan(
-        unit_body, x, (params["blocks"], caches["blocks"]))
-    new_caches = {"blocks": new_block_caches, **head_caches}
-    for i, kind in enumerate(cfg.tail_layers):
-        x, new_caches[f"tail{i}"] = _apply_block_prefill(
-            kind, params[f"tail{i}"], x, caches[f"tail{i}"], cfg, fcfg)
-    x = layers.apply_norm(params["final_norm"], x, cfg.norm)
-    return logits_fn(params, x[:, -1:], cfg)[:, 0], new_caches
 
 
 def _mask_state_update(kind: str, new_cache, old_cache, active):
@@ -511,18 +440,16 @@ def _mask_state_update(kind: str, new_cache, old_cache, active):
 
 
 def decode_step(params, tokens, caches, cache_len, cfg: ModelConfig,
-                fcfg: FamousConfig = FamousConfig(), compute_dtype=None,
-                page_table=None, active=None, moe_counts: bool = False):
+                fcfg: FamousConfig = FamousConfig(), compute_dtype=None, *,
+                page_table, active=None, moe_counts: bool = False):
     """tokens: (B,) int32 (or (B, D) embeddings); cache_len: (B,).
-    page_table: optional (B, pages_per_slot) int32 — when given, global
-    attention layers treat their caches as shared page pools (see
-    ``make_caches(cache_kind="paged")``); when None, caches are the
-    contiguous per-slot baseline.  active: optional (B,) bool — slots not
-    decoding this step (free, or mid-chunked-prefill) keep their recurrent
-    state untouched, and their tokens take no held expert's rows.
-    Returns (logits (B, vocab), new caches), and with ``moe_counts`` also
-    the held-expert layers' row counts summed over layers
-    (``moe.apply_moe_serving``'s ``counts``)."""
+    page_table: (B, pages_per_slot) int32, addressing the global and
+    latent layers' page pools (see :func:`make_caches`).  active: optional
+    (B,) bool — slots not decoding this step (free, or mid-chunked-prefill)
+    keep their recurrent state untouched, and their tokens take no held
+    expert's rows.  Returns (logits (B, vocab), new caches), and with
+    ``moe_counts`` also the held-expert layers' row counts summed over
+    layers (``moe.apply_moe_serving``'s ``counts``)."""
     dtype = compute_dtype or params["final_norm"]["scale"].dtype
     inputs = tokens[:, None] if tokens.ndim == 1 else tokens[:, None, :]
     x = _embed_inputs(params, inputs, cfg, dtype)
@@ -534,10 +461,10 @@ def decode_step(params, tokens, caches, cache_len, cfg: ModelConfig,
                                      sink if moe_counts else None)
         return x, _mask_state_update(kind, new, cache, active)
 
-    one = _unstacked(block, page_table)
+    one = _unstacked(block)
     x, head_caches = _apply_heads(params, x, caches, cfg, one)
     x, new_block_caches, unit_counts = _scan_units(block, x, params, caches,
-                                                   cfg, page_table)
+                                                   cfg)
     new_caches = {"blocks": new_block_caches, **head_caches}
     for i, kind in enumerate(cfg.tail_layers):
         x, new_caches[f"tail{i}"] = one(kind, params[f"tail{i}"], x,
@@ -550,7 +477,7 @@ def decode_step(params, tokens, caches, cache_len, cfg: ModelConfig,
 
 
 def _apply_block_verify(kind, p, x, cache, layer, cache_len, cfg, fcfg,
-                        page_table=None):
+                        page_table):
     n = functools.partial(layers.apply_norm, kind=cfg.norm)
     if kind != ATTN:
         # Sliding-window rings write position p at slot p % ring — a verify
@@ -560,27 +487,24 @@ def _apply_block_verify(kind, p, x, cache, layer, cache_len, cfg, fcfg,
         # back to plain decode for these stacks (speculative_active False).
         raise ValueError(
             f"verify_step only supports global-attention layers, got {kind}")
-    if page_table is not None:
-        a, cache = attention.apply_attn_verify_paged(
-            p["attn"], n(p["ln1"], x), cache, layer, page_table, cache_len,
-            cfg, fcfg)
-    else:
-        a, cache = attention.apply_attn_verify(
-            p["attn"], n(p["ln1"], x), cache, cache_len, cfg, fcfg)
+    a, cache = attention.apply_attn_verify_paged(
+        p["attn"], n(p["ln1"], x), cache, layer, page_table, cache_len,
+        cfg, fcfg)
     x = x + a
     return x + _apply_ffn(p["ffn"], n(p["ln2"], x), cfg), cache
 
 
 def verify_step(params, tokens, caches, cache_len, cfg: ModelConfig,
-                fcfg: FamousConfig = FamousConfig(), compute_dtype=None,
-                page_table=None):
+                fcfg: FamousConfig = FamousConfig(), compute_dtype=None, *,
+                page_table):
     """Speculative verify: decode W tokens per slot in ONE forward.
 
     tokens: (B, W) int32 — row b is ``[last_token, draft_1..draft_{W-1}]``
     at absolute positions ``cache_len[b] + j`` (pad rows past a short
     draft are ignored by the caller); cache_len: (B,) valid cache entries
     BEFORE the first token, a runtime operand — one executable serves
-    every draft-length mix.  Returns (logits (B, W, vocab), new caches):
+    every draft-length mix; page_table: (B, pages_per_slot) int32.
+    Returns (logits (B, W, vocab), new caches):
     ``logits[b, j]`` is the next-token distribution after consuming
     ``tokens[b, :j+1]``, exactly what j+1 sequential ``decode_step`` calls
     would produce (causal attention makes the parallel and sequential
@@ -597,10 +521,9 @@ def verify_step(params, tokens, caches, cache_len, cfg: ModelConfig,
         return _apply_block_verify(kind, p, x, cache, layer, cache_len, cfg,
                                    fcfg, page_table)
 
-    x, new_block_caches, _ = _scan_units(block, x, params, caches, cfg,
-                                         page_table)
+    x, new_block_caches, _ = _scan_units(block, x, params, caches, cfg)
     new_caches = {"blocks": new_block_caches}
-    one = _unstacked(block, page_table)
+    one = _unstacked(block)
     for i, kind in enumerate(cfg.tail_layers):
         x, new_caches[f"tail{i}"] = one(kind, params[f"tail{i}"], x,
                                         caches[f"tail{i}"])
@@ -640,17 +563,16 @@ def _apply_block_chunk(kind, p, x, cache, layer, slot, offset, n_valid, cfg,
         return _mla_block(p, x, cfg, fcfg, lambda h: mla.apply_mla_chunk_paged(
             p["attn"], h, cache, layer, page_table, slot, offset, cfg, fcfg),
             valid)
-    if kind == ATTN and page_table is not None:
+    if kind == ATTN:
         a, cache = attention.apply_attn_chunk_paged(
             p["attn"], n(p["ln1"], x), cache, layer, page_table, slot, offset,
             cfg, fcfg)
         x = x + a
         return x + _apply_ffn(p["ffn"], n(p["ln2"], x), cfg), cache
-    if kind in (ATTN, LOCAL_ATTN):
-        window = cfg.window if kind == LOCAL_ATTN else 0
+    if kind == LOCAL_ATTN:
         a, cache = attention.apply_attn_chunk(
             p["attn"], n(p["ln1"], x), cache, slot, offset, n_valid, cfg,
-            fcfg, window=window)
+            fcfg)
         x = x + a
         return x + _apply_ffn(p["ffn"], n(p["ln2"], x), cfg), cache
     if kind == RGLRU:
@@ -675,21 +597,21 @@ def _apply_block_chunk(kind, p, x, cache, layer, slot, offset, n_valid, cfg,
 
 
 def prefill_chunk(params, tokens, caches, slot, offset, n_valid,
-                  cfg: ModelConfig, fcfg: FamousConfig = FamousConfig(),
-                  page_table=None, compute_dtype=None):
+                  cfg: ModelConfig, fcfg: FamousConfig = FamousConfig(), *,
+                  page_table, compute_dtype=None):
     """One fixed-shape prefill chunk for a single slot of the batched caches.
 
     tokens: (1, C) int32 at absolute positions [offset, offset+C); only the
     first ``n_valid`` are real (the pad tail's state updates are masked to
-    the identity, and its junk K/V is never read).  Writes K/V — contiguous
-    stripe, ring buffer, or page pool — and recurrent state for ``slot``
-    directly into the batched caches, replacing the old
-    build-batch-1-then-scatter round trip, and carries recurrent state
-    across chunks (``offset == 0`` starts from zero state).  ``slot``,
-    ``offset`` and ``n_valid`` are runtime scalars: ONE executable serves
-    every (slot, prompt length, chunk index) triple.  Returns the new
-    caches only — prefill logits are dead weight (generation restarts by
-    decoding the last prompt token), so the LM head is never computed.
+    the identity, and its junk K/V is never read).  Writes K/V or latent
+    rows into the slot's pages of the page pools, the window ring, and
+    recurrent state for ``slot`` directly into the batched caches, and
+    carries recurrent state across chunks (``offset == 0`` starts from zero
+    state).  ``slot``, ``offset`` and ``n_valid`` are runtime scalars: ONE
+    executable serves every (slot, prompt length, chunk index) triple.
+    Returns the new caches only — prefill logits are dead weight
+    (generation restarts by decoding the last prompt token), so the LM
+    head is never computed.
     """
     dtype = compute_dtype or params["final_norm"]["scale"].dtype
     x = _embed_inputs(params, tokens, cfg, dtype)
@@ -698,10 +620,9 @@ def prefill_chunk(params, tokens, caches, slot, offset, n_valid,
         return _apply_block_chunk(kind, p, x, cache, layer, slot, offset,
                                   n_valid, cfg, fcfg, page_table)
 
-    one = _unstacked(block, page_table)
+    one = _unstacked(block)
     x, head_caches = _apply_heads(params, x, caches, cfg, one)
-    x, new_block_caches, _ = _scan_units(block, x, params, caches, cfg,
-                                         page_table)
+    x, new_block_caches, _ = _scan_units(block, x, params, caches, cfg)
     new_caches = {"blocks": new_block_caches, **head_caches}
     for i, kind in enumerate(cfg.tail_layers):
         x, new_caches[f"tail{i}"] = one(kind, params[f"tail{i}"], x,
@@ -714,87 +635,11 @@ def prefill_chunk(params, tokens, caches, slot, offset, n_valid,
 # ---------------------------------------------------------------------------
 
 
-def _write_slot(dst, src, slot, axis):
-    return jax.lax.dynamic_update_slice_in_dim(
-        dst, src.astype(dst.dtype), slot, axis=axis)
-
-
-def _scatter_pages(pool, kv_seq, page_ids):
-    """Scatter a contiguous (.., max_seq, kv, dh) K/V stripe into pool pages.
-
-    pool: (..., kv, n_pages, page_size, dh); kv_seq: (..., max_seq, kv, dh);
-    page_ids: (pages_per_slot,) int32, NULL-padded past the slot's live pages
-    (the null page absorbs the padded chunks).  max_seq == pages_per_slot *
-    page_size by construction (engine asserts max_seq % page_size == 0).
-    """
-    n_p = page_ids.shape[0]
-    ps = pool.shape[-2]
-    lead = kv_seq.shape[:-3]
-    kv, dh = kv_seq.shape[-2:]
-    chunks = jnp.moveaxis(kv_seq.reshape(lead + (n_p, ps, kv, dh)), -2, -4)
-    idx = (slice(None),) * (len(lead) + 1) + (page_ids,)
-    return pool.at[idx].set(chunks.astype(pool.dtype))
-
-
-def _scatter_paged_kv(dst: dict, src_k, src_v, page_ids) -> dict:
-    """Scatter a slot's contiguous fp K/V stripes into a paged ATTN pool,
-    quantizing at write time when the pool is int8 (scale leaves present).
-    Scale rows scatter with the *same* page_ids as their int8 rows, so the
-    scale pool needs no allocator bookkeeping of its own."""
-    if "k_scale" in dst:
-        from repro.core import quant as quant_lib
-        kq, ks = quant_lib.quantize(src_k, axis=-1)
-        vq, vs = quant_lib.quantize(src_v, axis=-1)
-        # (..., kv, 1, ps) scale rows scatter as a dh=ps page of one row
-        scatter_s = lambda pool, s: _scatter_pages(     # noqa: E731
-            pool.swapaxes(-1, -2), s, page_ids).swapaxes(-1, -2)
-        return {"k": _scatter_pages(dst["k"], kq, page_ids),
-                "v": _scatter_pages(dst["v"], vq, page_ids),
-                "k_scale": scatter_s(dst["k_scale"], ks),
-                "v_scale": scatter_s(dst["v_scale"], vs)}
-    return {n: _scatter_pages(dst[n], s, page_ids)
-            for n, s in (("k", src_k), ("v", src_v))}
-
-
-def write_prefill_to_slot(caches, one, slot, cfg: ModelConfig,
-                          page_ids=None) -> dict:
-    """Write a single-sequence prefill cache ``one`` (batch=1, contiguous)
-    into slot ``slot`` of the batched ``caches``.
-
-    Contiguous mode (page_ids=None): every leaf is a dynamic-update-slice on
-    its slot axis (1 for the stacked block caches, 0 for tails).  Paged mode:
-    global-attention K/V additionally reshape into page_size chunks and
-    scatter to the slot's pages; all other leaves write their slot row as
-    before.
-    """
-    def write_tree(dst, src, axis):
-        return jax.tree_util.tree_map(
-            lambda d, s: _write_slot(d, s, slot, axis), dst, src)
-
-    out: dict[str, Any] = {"blocks": {}}
-    for i, kind in enumerate(cfg.pattern_unit):
-        key = f"pos{i}"
-        dst, src = caches["blocks"][key], one["blocks"][key]
-        if page_ids is not None and kind == ATTN:
-            out["blocks"][key] = _scatter_paged_kv(dst, src["k"][:, 0],
-                                                   src["v"][:, 0], page_ids)
-        else:
-            out["blocks"][key] = write_tree(dst, src, 1)
-    for i, kind in enumerate(cfg.tail_layers):
-        key = f"tail{i}"
-        if page_ids is not None and kind == ATTN:
-            out[key] = _scatter_paged_kv(caches[key], one[key]["k"][0],
-                                         one[key]["v"][0], page_ids)
-        else:
-            out[key] = write_tree(caches[key], one[key], 0)
-    return out
-
-
-def clear_slot(caches, slot, cfg: ModelConfig, paged: bool = False) -> dict:
+def clear_slot(caches, slot, cfg: ModelConfig) -> dict:
     """Zero slot ``slot``'s per-slot cache state (stale-state hygiene for
-    length-1 admissions that skip prefill).  In paged mode global-attention
-    pools are left alone: the slot's pages were already freed and any stale
-    page content is unreachable (the page table row is null and reads are
+    length-1 admissions that skip prefill).  Page pools are left alone:
+    the slot's pages were already freed and any stale page content is
+    unreachable (the page table row is null and reads are
     cache_len-masked)."""
     def zero_tree(tree, axis):
         def z(buf):
@@ -802,16 +647,15 @@ def clear_slot(caches, slot, cfg: ModelConfig, paged: bool = False) -> dict:
             return buf.at[idx].set(jnp.zeros((), buf.dtype))
         return jax.tree_util.tree_map(z, tree)
 
-    pooled = (ATTN, MLA) if paged else ()
     out: dict[str, Any] = {"blocks": {}}
     for i, kind in enumerate(cfg.pattern_unit):
         key = f"pos{i}"
-        if kind in pooled:
+        if _pooled(kind):
             out["blocks"][key] = caches["blocks"][key]
         else:
             out["blocks"][key] = zero_tree(caches["blocks"][key], 1)
     for key, kind in ([(f"tail{i}", k) for i, k in enumerate(cfg.tail_layers)]
                       + [(f"head{i}", k)
                          for i, k in enumerate(cfg.head_layers)]):
-        out[key] = caches[key] if kind in pooled else zero_tree(caches[key], 0)
+        out[key] = caches[key] if _pooled(kind) else zero_tree(caches[key], 0)
     return out

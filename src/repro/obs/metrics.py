@@ -16,10 +16,10 @@ Histograms use fixed log-spaced buckets (default ``LOG_BUCKETS``:
 two histograms of the same schema are mergeable and a quantile is
 reconstructible from the bucket counts alone —
 :meth:`Histogram.quantile` does the same linear-within-bucket
-interpolation as PromQL's ``histogram_quantile``.  The serving benchmark
-reports its TTFT/TPOT percentiles through this exact class
-(:meth:`Histogram.of`), so bench rows and live metrics can never
-disagree about what a percentile means.
+interpolation as PromQL's ``histogram_quantile``, and
+:meth:`Histogram.of` builds one from a finished list of samples, so
+offline percentiles and live metrics can never disagree about what a
+percentile means.
 
 :func:`validate_prometheus_text` is the golden-format checker used by the
 tests and the CI observability stage: it re-parses an exposition dump and

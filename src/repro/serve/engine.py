@@ -28,18 +28,16 @@ budgeted prefill chunks first, then one batched decode across the
 decoding slots.  A long prompt thus prefills between other requests'
 decode steps (no head-of-line blocking), and prompts are no longer
 limited to what one prefill call can hold — only by cache capacity
-(``max_seq``).
+(``max_seq``).  Chunked prefill is the only way a prompt enters the
+caches.
 
-``prefill_mode="monolithic"`` keeps the legacy whole-prompt-at-admission
-path (pow-2 bucketed, exact-length for recurrent archs) as the
-comparison baseline for parity tests and benchmarks.
-
-KV-cache layout remains a config switch (``cache_kind``): ``"contiguous"``
-per-slot stripes or the ``"paged"`` shared pool with host-side
+Global and latent attention keep their K/V in one shared page pool per
+layer, addressed through per-slot page tables, with host-side
 :class:`~repro.serve.paged.PageAllocator` admission control (see
-docs/serving.md and serve/paged.py).  ``prefix_cache=True`` (paged +
-chunked only) additionally aliases identical full prompt blocks across
-requests through the allocator's refcounted content-hash index — admission
+docs/serving.md and serve/paged.py); window rings and recurrent state are
+per-slot buffers.  ``prefix_cache=True`` additionally aliases identical
+full prompt blocks across requests through the allocator's refcounted
+content-hash index — admission
 maps cached blocks straight into the page table and prefill starts at the
 first uncached token; retirement publishes the request's prompt blocks
 onto the cached-free LRU.  Architectures with per-slot recurrent or ring
@@ -63,7 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ATTN, LOCAL_ATTN, MLA, ModelConfig
+from repro.configs.base import ATTN, MLA, ModelConfig
 from repro.core.famous import FamousConfig
 from repro.core.flexible import next_pow2
 from repro.kernels.decode.decode_attn import pages_per_block
@@ -75,7 +73,7 @@ from repro.serve import sampling
 from repro.serve.draft import PromptLookupDrafter
 from repro.serve.paged import (PageAllocator, PagedCacheConfig,
                                PagePoolExhausted, block_hashes)
-from repro.serve.scheduler import (DECODE, FREE, PREFILL, Scheduler,
+from repro.serve.scheduler import (DECODE, FREE, Scheduler,
                                    SchedulerConfig)
 
 
@@ -127,9 +125,8 @@ class ServingEngine:
 
     def __init__(self, params, cfg: ModelConfig, fcfg: FamousConfig,
                  n_slots: int = 4, max_seq: int = 256, dtype=jnp.float32,
-                 cache_kind: str = "contiguous", page_size: int = 16,
-                 n_pages: Optional[int] = None,
-                 prefill_mode: str = "chunked", chunk: int = 32,
+                 cache_kind: str = "paged", page_size: int = 16,
+                 n_pages: Optional[int] = None, chunk: int = 32,
                  token_budget: int = 0, prefix_cache: bool = False,
                  speculative: bool = False, draft_k: int = 4,
                  drafter=None, kv_dtype: str = "fp",
@@ -153,29 +150,29 @@ class ServingEngine:
         never migrate between steps.  Logits stay replicated, so sampling
         and the host bookkeeping loop are untouched.  ``mesh=None`` (the
         default) is the unsharded single-device baseline, bit-identical to
-        the pre-mesh engine."""
-        assert cache_kind in ("contiguous", "paged"), cache_kind
-        assert prefill_mode in ("chunked", "monolithic"), prefill_mode
+        the pre-mesh engine.
+
+        ``cache_kind`` must be ``"paged"``, the one cache layout; the
+        keyword stays only because ``bench/harness.py`` passes it, until a
+        benchmark change drops the argument."""
+        if cache_kind != "paged":
+            raise ValueError(f"cache_kind={cache_kind!r}: the paged pool is "
+                             f"the only cache layout")
         assert kv_dtype in ("fp", "int8"), kv_dtype
         self.obs = observer if observer is not None else NULL_OBSERVER
-        if kv_dtype == "int8":
-            assert cache_kind == "paged", "kv_dtype='int8' requires paged cache"
         self.params = params
         self.cfg = cfg
         self.fcfg = fcfg
         self.n_slots = n_slots
         self.max_seq = max_seq
         self.dtype = dtype
-        self.cache_kind = cache_kind
         self.kv_dtype = kv_dtype
-        self.paged = cache_kind == "paged"
-        self.chunked = prefill_mode == "chunked"
         self.chunk = min(chunk, max_seq)
-        if self.chunked:
-            # pads stay inside the cache (positions < ceil(target/C)*C <=
-            # max_seq) and the wkv6 chunked form needs S % min(64, S) == 0
-            assert max_seq % self.chunk == 0, (max_seq, self.chunk)
-            assert self.chunk <= 64 or self.chunk % 64 == 0, self.chunk
+        # pads stay inside the cache (positions < ceil(target/C)*C <=
+        # max_seq) and the wkv6 chunked form needs S % min(64, S) == 0
+        assert max_seq % self.chunk == 0, (max_seq, self.chunk)
+        assert self.chunk <= 64 or self.chunk % 64 == 0, self.chunk
+        assert max_seq % page_size == 0, (max_seq, page_size)
         # -- speculative decoding -------------------------------------------
         # The verify step writes K/V at positions [cache_len, cache_len+W)
         # and rolls back *by bookkeeping only* — rejected positions hold
@@ -187,13 +184,11 @@ class ServingEngine:
         # to plain decode explicitly (`speculative_active` False).
         assert draft_k >= 1, draft_k
         # latent attention (kv_lora_rank > 0) serves from a paged pool of
-        # fp latent rows on one device, by chunked prefill and plain decode
+        # fp latent rows on one device, by plain decode
         self.latent = cfg.kv_lora_rank > 0
         if self.latent:
             for refused, what in ((kv_dtype != "fp", f"kv_dtype={kv_dtype!r}"),
                                   (speculative, "speculative decoding"),
-                                  (not self.chunked, "monolithic prefill"),
-                                  (cache_kind != "paged", "a contiguous cache"),
                                   (mesh is not None, "a device mesh")):
                 if refused:
                     raise ValueError(f"{cfg.name}: latent attention (MLA) "
@@ -211,25 +206,21 @@ class ServingEngine:
             chunk=self.chunk, token_budget=token_budget,
             decode_width=(draft_k + 1) if self.speculative_active else 1),
             observer=self.obs)
-        if self.paged:
-            assert max_seq % page_size == 0, (max_seq, page_size)
-            if n_pages is None:  # drop-in capacity; pass n_pages to oversubscribe
-                n_pages = PagedCacheConfig.default_pool(n_slots, max_seq,
-                                                        page_size)
-            self.pcfg = PagedCacheConfig(page_size=page_size, n_pages=n_pages)
-            self.alloc = PageAllocator(self.pcfg, n_slots, max_seq,
-                                       observer=self.obs)
-            # tokens per block of the paged decode kernel's walk (a latent
-            # pool is one kv head of stored latent rows)
-            self._kv_block_tokens = page_size * pages_per_block(
-                self.alloc.pages_per_slot, page_size, cfg.num_kv_heads,
-                mla.stored_width(cfg) if self.latent else cfg.head_dim,
-                jnp.int8 if kv_dtype == "int8" else dtype)
-            self.caches = transformer.make_caches(
-                cfg, n_slots, max_seq, dtype, cache_kind="paged",
-                page_size=page_size, n_pages=n_pages, kv_dtype=kv_dtype)
-        else:
-            self.caches = transformer.make_caches(cfg, n_slots, max_seq, dtype)
+        if n_pages is None:  # drop-in capacity; pass n_pages to oversubscribe
+            n_pages = PagedCacheConfig.default_pool(n_slots, max_seq,
+                                                    page_size)
+        self.pcfg = PagedCacheConfig(page_size=page_size, n_pages=n_pages)
+        self.alloc = PageAllocator(self.pcfg, n_slots, max_seq,
+                                   observer=self.obs)
+        # tokens per block of the paged decode kernel's walk (a latent
+        # pool is one kv head of stored latent rows)
+        self._kv_block_tokens = page_size * pages_per_block(
+            self.alloc.pages_per_slot, page_size, cfg.num_kv_heads,
+            mla.stored_width(cfg) if self.latent else cfg.head_dim,
+            jnp.int8 if kv_dtype == "int8" else dtype)
+        self.caches = transformer.make_caches(
+            cfg, n_slots, max_seq, dtype, page_size=page_size,
+            n_pages=n_pages, kv_dtype=kv_dtype)
         # -- mesh placement -------------------------------------------------
         # Params and caches are committed to their NamedShardings once, here;
         # the executables below pin cache (and logits) outputs with
@@ -266,23 +257,19 @@ class ServingEngine:
                 shardlib.tree_shardings(mesh, transformer.param_axes(cfg),
                                         rules, self.params))
             cshard = shardlib.tree_shardings(
-                mesh, transformer.cache_axes(cfg, cache_kind, kv_dtype),
+                mesh, transformer.cache_axes(cfg, kv_dtype),
                 rules, self.caches)
             self.caches = jax.device_put(self.caches, cshard)
             repl = shardlib.replicated(mesh)
             self._jit_kw_caches = {"out_shardings": cshard}
             self._jit_kw_logits = {"out_shardings": (repl, cshard)}
         # -- prefix cache ---------------------------------------------------
-        # Aliasing cached prompt blocks requires (a) paged storage, (b) a
-        # chunked prefill that can start at the first uncached token, and
-        # (c) an architecture whose *entire* prefix state lives in the page
-        # pool.  Sliding-window rings and recurrent state (RG-LRU, RWKV) are
-        # per-slot and not content-addressable, so hybrid/recurrent patterns
-        # fall back to cold prefill explicitly (`prefix_cache_active` False).
-        if prefix_cache:
-            assert cache_kind == "paged", "prefix_cache requires paged cache"
-            assert prefill_mode == "chunked", \
-                "prefix_cache requires chunked prefill (runtime offsets)"
+        # Aliasing cached prompt blocks requires an architecture whose
+        # *entire* prefix state lives in the page pool (chunked prefill then
+        # starts at the first uncached token).  Sliding-window rings and
+        # recurrent state (RG-LRU, RWKV) are per-slot and not
+        # content-addressable, so hybrid/recurrent patterns fall back to
+        # cold prefill explicitly (`prefix_cache_active` False).
         self.prefix_shareable = all(
             k in (ATTN, MLA) for k in tuple(cfg.pattern_unit)
             + tuple(cfg.tail_layers) + tuple(cfg.head_layers))
@@ -303,7 +290,6 @@ class ServingEngine:
         self._pt_version = -1          # device page-table cache key
         self._pt_device = None
         # -- the executables ----------------------------------------------
-        self._prefill_exec: dict[int, callable] = {}    # monolithic only
         self._prefill_chunk_exec = jax.jit(
             _named(transformer.prefill_chunk, cfg=cfg, fcfg=fcfg),
             donate_argnums=2, **self._jit_kw_caches)
@@ -322,15 +308,12 @@ class ServingEngine:
             _named(transformer.verify_step, cfg=cfg, fcfg=fcfg),
             donate_argnums=2, **self._jit_kw_logits)
         self._clear = jax.jit(
-            _named(transformer.clear_slot, cfg=cfg, paged=self.paged),
+            _named(transformer.clear_slot, cfg=cfg),
             donate_argnums=0, **self._jit_kw_caches)
         self._sample = jax.jit(sampling.sample_tokens,
                                static_argnames=("k_cap",))
         self._sample_verify = jax.jit(sampling.verify_tokens,
                                       static_argnames=("k_cap",))
-        # recurrent state cannot absorb junk pad tokens -> the monolithic
-        # path prefills those archs at exact length (chunked masks pads)
-        self.bucketed = all(k in (ATTN, LOCAL_ATTN) for k in cfg.pattern_unit)
         # the observer pulls the executable census through this source on
         # every snapshot/exposition, so repro_engine_compilations{exec=...}
         # and `engine.compilations` can never disagree (and retrace_guard
@@ -338,28 +321,11 @@ class ServingEngine:
         self.obs.register_census(lambda: self.compilations)
 
     # -- compiled helpers ---------------------------------------------------
-    def _prefill_fn(self, length: int):
-        """Monolithic path: one executable per padded prompt length."""
-        if length not in self._prefill_exec:
-            def fn(params, tokens, caches, slot, page_ids):
-                one = transformer.make_caches(self.cfg, 1, self.max_seq,
-                                              self.dtype)
-                _, one = transformer.prefill(params, tokens, one, self.cfg,
-                                             self.fcfg)
-                return transformer.write_prefill_to_slot(
-                    caches, one, slot, self.cfg,
-                    page_ids=page_ids if self.paged else None)
-
-            self._prefill_exec[length] = jax.jit(fn, **self._jit_kw_caches)
-        return self._prefill_exec[length]
-
     @property
     def prefill_compilations(self) -> int:
-        """Compiled prefill executables: O(1) chunked, O(buckets|lengths)
-        monolithic."""
-        if self.chunked:
-            return _jit_cache_size(self._prefill_chunk_exec)
-        return len(self._prefill_exec)
+        """Compiled prefill executables: the one chunk executable, for any
+        prompt-length mix."""
+        return _jit_cache_size(self._prefill_chunk_exec)
 
     @property
     def compilations(self) -> dict:
@@ -426,7 +392,7 @@ class ServingEngine:
         return hashes, min(len(hashes), (n - 1) // ps)
 
     def add_request(self, req: Request) -> int:
-        """Admit a request into a free slot.  Paged mode reserves the full
+        """Admit a request into a free slot.  It reserves the full
         sequence's prompt pages first; on :class:`PagePoolExhausted` the
         engine state is untouched (clean admission control).  With the
         prefix cache active, every full prompt block that hits the index is
@@ -434,13 +400,12 @@ class ServingEngine:
         the scheduler then starts chunked prefill at the first uncached
         token (the runtime-offset chunk executable needs no new compile).
 
-        Chunked mode does **no prefill here** — the scheduler doles the
-        prompt out as budget-sized chunks inside :meth:`step`, interleaved
-        with everyone else's decode.  Monolithic mode prefills the whole
-        prompt now (legacy comparison path).  A preempted request
-        (non-empty ``req.out``) resumes identically either way: its full
-        prefix (prompt + generated-so-far) is re-prefilled — minus any
-        cached head — and decode continues token-identically.
+        Admission does **no prefill** — the scheduler doles the prompt out
+        as budget-sized chunks inside :meth:`step`, interleaved with
+        everyone else's decode.  A preempted request (non-empty
+        ``req.out``) resumes identically: its full prefix (prompt +
+        generated-so-far) is re-prefilled — minus any cached head — and
+        decode continues token-identically.
         """
         slot = self.sched.free_slot()
         assert slot is not None, "no free slot"
@@ -459,25 +424,24 @@ class ServingEngine:
         served."""
         n = len(seq)
         n_cached = 0
-        if self.paged:
-            if self.prefix_cache_active:
-                hashes, cap = self._prefix_hashes(req, n)
-                hits = self.alloc.lookup(hashes[:cap])
-                self.prefix_lookups += 1
-                self.obs.on_prefix_lookup(req.rid, len(hits),
-                                          len(hits) * self.pcfg.page_size)
-                if hits:
-                    self.alloc.map_prefix(slot, hits)
-                    n_cached = len(hits) * self.pcfg.page_size
-                    self.prefix_hit_pages += len(hits)
-                    self.prefix_hit_tokens += n_cached
-                self._slot_hashes[slot] = hashes
-            try:
-                self.alloc.grow(slot, n)  # PagePoolExhausted if oversize
-            except PagePoolExhausted:
-                self.alloc.free(slot)     # roll back any mapped prefix
-                self._slot_hashes[slot] = None
-                raise
+        if self.prefix_cache_active:
+            hashes, cap = self._prefix_hashes(req, n)
+            hits = self.alloc.lookup(hashes[:cap])
+            self.prefix_lookups += 1
+            self.obs.on_prefix_lookup(req.rid, len(hits),
+                                      len(hits) * self.pcfg.page_size)
+            if hits:
+                self.alloc.map_prefix(slot, hits)
+                n_cached = len(hits) * self.pcfg.page_size
+                self.prefix_hit_pages += len(hits)
+                self.prefix_hit_tokens += n_cached
+            self._slot_hashes[slot] = hashes
+        try:
+            self.alloc.grow(slot, n)  # PagePoolExhausted if oversize
+        except PagePoolExhausted:
+            self.alloc.free(slot)     # roll back any mapped prefix
+            self._slot_hashes[slot] = None
+            raise
         state = self.sched.bind(slot, req, n, cached=n_cached)
         self._slot_seq[slot] = seq
         now = _clock()
@@ -485,18 +449,6 @@ class ServingEngine:
             req.t_submit = now
         if req.t_admit is None:
             req.t_admit = now
-        if not self.chunked and state == PREFILL:
-            m = n - 1
-            plen = min(next_pow2(m), self.max_seq) if self.bucketed else m
-            toks = np.zeros((1, plen), np.int32)
-            toks[0, :m] = seq[:m]
-            page_ids = (jnp.asarray(self.alloc.page_table[slot]) if self.paged
-                        else jnp.zeros((0,), jnp.int32))
-            fn = self._prefill_fn(plen)
-            self.caches = fn(self.params, jnp.asarray(toks), self.caches,
-                             jnp.int32(slot), page_ids)
-            self.sched.mark_prefilled(slot)
-            state = DECODE
         if state == DECODE and self.sched.slots[slot].target == 0:
             # nothing to prefill: clear any stale per-slot state
             self.caches = self._clear(self.caches, jnp.int32(slot))
@@ -532,8 +484,7 @@ class ServingEngine:
         self.cache_len[slot] = 0
         self._slot_seq[slot] = None
         self._slot_hashes[slot] = None
-        if self.paged:
-            self.alloc.free(slot)
+        self.alloc.free(slot)
         self.obs.on_retire(req, slot)
         self._failed.append(req)
 
@@ -567,7 +518,7 @@ class ServingEngine:
     def step(self):
         """Execute one scheduler plan: budgeted prefill chunks, then one
         batched decode across the decoding slots.  Returns the requests
-        that finished (or, paged mode, failed) this step."""
+        that finished (or failed) this step."""
         with self.obs.span("engine.step", step_num=self.sched.step_count):
             finished = []
             self.obs.on_step(
@@ -586,7 +537,7 @@ class ServingEngine:
 
     def _prefill_chunks(self, chunks: list) -> None:
         """The plan's prefill chunks (fixed shape; one executable)."""
-        pt = self._page_table() if self.paged else None
+        pt = self._page_table()
         for ch in chunks:
             with self.obs.phase("prefill_chunk", slot=ch.slot,
                                 rid=self.sched.slots[ch.slot].req.rid,
@@ -594,11 +545,10 @@ class ServingEngine:
                 seq = self._slot_seq[ch.slot]
                 toks = np.zeros((1, self.chunk), np.int32)
                 toks[0, :ch.n] = seq[ch.start:ch.start + ch.n]
-                kw = {"page_table": pt} if self.paged else {}
                 self.caches = self._prefill_chunk_exec(
                     self.params, jnp.asarray(toks), self.caches,
                     jnp.int32(ch.slot), jnp.int32(ch.start),
-                    jnp.int32(ch.n), **kw)
+                    jnp.int32(ch.n), page_table=pt)
             self.cache_len[ch.slot] = ch.start + ch.n
             if self.sched.on_chunk(ch.slot, ch.n):
                 # prefill complete: decode restarts at the last token,
@@ -608,18 +558,15 @@ class ServingEngine:
     def _span_costs(self, active: list, width: int = 1) -> dict:
         """The launch's costly span arguments: ``ctx_tokens``, the cache
         entries the decoding slots attend over, summed (each slot's cache
-        plus the token it decodes), and, paged, ``kv_blocks``, the blocks
-        the paged decode kernel walks: one row per (slot, verify position)
-        of length ``cache_len + j + 1``, ``ceil(length / block)`` blocks
-        a row and kv head."""
-        args = {"ctx_tokens": int(self.cache_len[active].sum())
-                + len(active)}
-        if self.paged:
-            lens = np.minimum(self.cache_len[active][:, None]
-                              + np.arange(1, width + 1), self.max_seq)
-            args["kv_blocks"] = int(-(-lens // self._kv_block_tokens).sum()
-                                    ) * self.cfg.num_kv_heads
-        return args
+        plus the token it decodes), and ``kv_blocks``, the blocks the paged
+        decode kernel walks: one row per (slot, verify position) of length
+        ``cache_len + j + 1``, ``ceil(length / block)`` blocks a row and kv
+        head."""
+        lens = np.minimum(self.cache_len[active][:, None]
+                          + np.arange(1, width + 1), self.max_seq)
+        return {"ctx_tokens": int(self.cache_len[active].sum()) + len(active),
+                "kv_blocks": int(-(-lens // self._kv_block_tokens).sum()
+                                 ) * self.cfg.num_kv_heads}
 
     def _sampling_operands(self, active):
         """Per-slot sampling operands (host numpy, materialized once)."""
@@ -648,18 +595,17 @@ class ServingEngine:
             self.sched.release(i)
             self._slot_seq[i] = None
             self.cache_len[i] = 0
-            if self.paged:
-                if self.prefix_cache_active and self._slot_hashes[i]:
-                    # publish-on-retire: the slot's full prompt blocks
-                    # (now completely written) become index entries; its
-                    # pages drop to refcount 0 in free() below but stay
-                    # warm on the cached-free LRU for future hits
-                    self.alloc.publish(i, self._slot_hashes[i])
-                self._slot_hashes[i] = None
-                self.alloc.free(i)  # refcounts drop; pool or LRU
+            if self.prefix_cache_active and self._slot_hashes[i]:
+                # publish-on-retire: the slot's full prompt blocks (now
+                # completely written) become index entries; its pages drop
+                # to refcount 0 in free() below but stay warm on the
+                # cached-free LRU for future hits
+                self.alloc.publish(i, self._slot_hashes[i])
+            self._slot_hashes[i] = None
+            self.alloc.free(i)  # refcounts drop; pool or LRU
 
     def _decode_plain(self, active: list, finished: list) -> None:
-        if self.paged and active:
+        if active:
             active = self._grow_active(active)
             finished.extend(self._failed)
             self._failed.clear()
@@ -677,10 +623,10 @@ class ServingEngine:
             act = np.zeros((self.n_slots,), bool)
             act[active] = True
             act_dev = jnp.asarray(act)
-            kw = {"page_table": self._page_table()} if self.paged else {}
             logits, self.caches, *moe_rows = self._decode(
                 self.params, jnp.asarray(self.last_token), self.caches,
-                jnp.asarray(self.cache_len), active=act_dev, **kw)
+                jnp.asarray(self.cache_len), active=act_dev,
+                page_table=self._page_table())
             temps, topks, seeds, idxs = self._sampling_operands(active)
             if temps.any():
                 # k_cap: pow-2 roundup of the largest requested top-k, so
@@ -736,9 +682,9 @@ class ServingEngine:
         """One verify step: draft on the host, verify all slots' drafts in
         ONE fixed-shape forward (width ``draft_k + 1``), accept each
         slot's longest matching prefix plus the model's bonus/correction
-        token, and roll back the rest by bookkeeping (contiguous: junk
-        K/V past ``cache_len`` is masked/overwritten; paged: tail pages
-        grown for rejected tokens shrink back to the pool)."""
+        token, and roll back the rest by bookkeeping (junk K/V past
+        ``cache_len`` is masked/overwritten, and tail pages grown for
+        rejected tokens shrink back to the pool)."""
         W = self.draft_k + 1
         drafts: dict[int, list] = {}
         for i in list(active):
@@ -747,7 +693,7 @@ class ServingEngine:
             except Exception as e:   # a poisoned request fails alone
                 self._fail_slot(i, f"drafter failed: {type(e).__name__}: {e}")
                 active.remove(i)
-        if self.paged and active:
+        if active:
             # baseline growth (next token's page) keeps plain-decode
             # semantics: preempt youngest-first, fail a lone un-backable
             # sequence.  Draft pages on top are OPPORTUNISTIC — a draft is
@@ -772,17 +718,14 @@ class ServingEngine:
             d = drafts.get(i, [])
             if d:
                 toks[i, 1:1 + len(d)] = d
-        kw = {"page_table": self._page_table()} if self.paged else {}
         with self.obs.phase("verify", slots=len(active),
                             drafted=sum(len(d) for d in drafts.values())
                             ) as sp:
             if self.obs.spans_on:
                 sp.set_metadata(**self._span_costs(active, W))
-            logits, self.caches = self._verify(self.params,
-                                               jnp.asarray(toks),
-                                               self.caches,
-                                               jnp.asarray(self.cache_len),
-                                               **kw)
+            logits, self.caches = self._verify(
+                self.params, jnp.asarray(toks), self.caches,
+                jnp.asarray(self.cache_len), page_table=self._page_table())
             temps, topks, seeds, idxs = self._sampling_operands(active)
             if temps.any():
                 k_cap = next_pow2(max(int(topks.max()), 1))
@@ -834,21 +777,18 @@ class ServingEngine:
                 req.t_first = now
             for _ in range(n_acc):
                 self.sched.on_decode_token(i)
-            if self.paged:
-                # rollback: return the pages grown for rejected draft
-                # tokens (a draft cut at a page boundary must not leak)
-                self.alloc.shrink(i, int(self.cache_len[i]))
+            # rollback: return the pages grown for rejected draft tokens
+            # (a draft cut at a page boundary must not leak)
+            self.alloc.shrink(i, int(self.cache_len[i]))
             self._maybe_retire(i, req, now, finished)
 
     # -- admission control ----------------------------------------------------
     def _admissible(self, req: Request) -> bool:
-        """Paged admission control: admit only if the sequence's pages are
-        free right now (retiring sequences release pages continuously, so
+        """Admission control: admit only if the sequence's pages are free
+        right now (retiring sequences release pages continuously, so
         deferred requests drain from the pending queue).  Raises
         :class:`PagePoolExhausted` for requests no pool state could ever
         admit."""
-        if not self.paged:
-            return True
         n = len(req.tokens) + len(req.out)
         if n > self.max_seq:
             raise PagePoolExhausted(
@@ -903,9 +843,8 @@ class ServingEngine:
             req = self.sched.release(slot)
             self.cache_len[slot] = 0
             self._slot_seq[slot] = None
-            if self.paged:
-                self._slot_hashes[slot] = None
-                self.alloc.free(slot)
+            self._slot_hashes[slot] = None
+            self.alloc.free(slot)
             req.error = req.error or (
                 f"evicted mid-flight at max_steps={max_steps}")
             done.append(req)

@@ -154,12 +154,6 @@ class Scheduler:
         self.obs.on_admit(req.rid, slot, n_tokens, info.done)
         return info.state
 
-    def mark_prefilled(self, slot: int) -> None:
-        """Monolithic path: the whole prompt prefilled at admission."""
-        info = self.slots[slot]
-        info.done = info.target
-        info.state = DECODE
-
     def on_chunk(self, slot: int, n: int) -> bool:
         """Record ``n`` prefilled tokens; True when prefill completed (the
         slot flips to DECODE and starts decoding next step)."""
@@ -242,8 +236,8 @@ class Scheduler:
     def fairness(self, rid) -> dict:
         """Per-request accounting: queueing delay, TTFT in steps, work done,
         prefix-cache savings (``cached_tokens``), preemption count — the
-        host-side ledger behind the TTFT/TPOT percentiles in
-        benchmarks/serving_bench.py."""
+        host-side ledger behind the per-request lines that
+        ``repro.launch.serve`` prints."""
         st = dict(self.stats.get(rid, {}))
         if "enqueue_step" in st and "first_token_step" in st:
             st["ttft_steps"] = st["first_token_step"] - st["enqueue_step"]

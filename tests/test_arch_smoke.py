@@ -6,10 +6,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs.base import SMOKE_SHAPES, get_config, list_configs, shrink
+from repro.configs.base import get_config, list_configs, shrink
 from repro.core.famous import FamousConfig
 from repro.models import frontends, module, transformer
-from repro.optim import adamw
 from repro.train import step as step_lib
 
 ARCHS = [a for a in list_configs()]
@@ -53,8 +52,9 @@ def test_one_train_step(arch):
 
 @pytest.mark.parametrize("arch", [a for a in ARCHS
                                   if not get_config(a).is_encoder_only])
-def test_decode_consistency(arch):
-    """prefill(first half) + decode(second half) == full forward logits."""
+def test_decode_consistency(arch, paged_prefill):
+    """Chunked prefill of the first 7 tokens into the serving caches, then
+    decode of the next 5, give the full forward's logits."""
     cfg = shrink(get_config(arch))
     if cfg.frontend:
         pytest.skip("frontend-stub archs decode from embeddings; covered by "
@@ -65,13 +65,12 @@ def test_decode_consistency(arch):
     toks = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0,
                               cfg.vocab_size)
     full = transformer.forward(params, toks, cfg, remat=False)
-    caches = transformer.make_caches(cfg, B, S, jnp.float32)
-    lg, caches = transformer.prefill(params, toks[:, :8], caches, cfg)
-    errs = [np.abs(np.asarray(lg) - np.asarray(full[:, 7])).max()]
-    clen = jnp.full((B,), 8, jnp.int32)
-    for t in range(8, 12):
+    caches, pt = paged_prefill(params, cfg, toks[:, :7], max_seq=S)
+    errs = []
+    clen = jnp.full((B,), 7, jnp.int32)
+    for t in range(7, 12):
         lg, caches = transformer.decode_step(params, toks[:, t], caches,
-                                             clen, cfg)
+                                             clen, cfg, page_table=pt)
         clen = clen + 1
         errs.append(np.abs(np.asarray(lg) - np.asarray(full[:, t])).max())
     tol = 5e-2 if cfg.num_experts else 5e-4  # MoE capacity-drop variance

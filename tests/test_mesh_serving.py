@@ -1,5 +1,5 @@
 """Mesh-sharded serving parity: a TP engine must be *token-identical* to
-the unsharded baseline across cache kinds, kernel impls and architectures.
+the unsharded baseline across KV dtypes, kernel impls and architectures.
 
 The suite runs single-device by default (conftest sets no XLA_FLAGS), so
 only the TP=1 bit-identity test executes; the TP>=2 matrix skips unless
@@ -64,15 +64,14 @@ def _reqs(cfg, sampled=False):
     return reqs
 
 
-def _run(arch, mesh=None, impl="xla", cache_kind="contiguous",
-         sampled=False, **kw):
+def _run(arch, mesh=None, impl="xla", kv_dtype="fp", sampled=False, **kw):
     cfg, params = _cfg_params(arch)
     with warnings.catch_warnings():
         # hybrid kv=1 on a TP mesh replicates with a RuntimeWarning — that
         # fallback path is intentional here, not a failure
         warnings.simplefilter("ignore", RuntimeWarning)
         eng = ServingEngine(params, cfg, FamousConfig(impl=impl), n_slots=2,
-                            max_seq=32, chunk=8, cache_kind=cache_kind,
+                            max_seq=32, chunk=8, kv_dtype=kv_dtype,
                             page_size=8, mesh=mesh, **kw)
         done = eng.run(_reqs(cfg, sampled))
     assert all(r.error is None for r in done), [r.error for r in done]
@@ -82,10 +81,10 @@ def _run(arch, mesh=None, impl="xla", cache_kind="contiguous",
 _BASE: dict = {}
 
 
-def _baseline(arch, impl="xla", cache_kind="contiguous", sampled=False, **kw):
-    key = (arch, impl, cache_kind, sampled, tuple(sorted(kw)))
+def _baseline(arch, impl="xla", kv_dtype="fp", sampled=False, **kw):
+    key = (arch, impl, kv_dtype, sampled, tuple(sorted(kw)))
     if key not in _BASE:
-        _BASE[key] = _run(arch, None, impl, cache_kind, sampled, **kw)[0]
+        _BASE[key] = _run(arch, None, impl, kv_dtype, sampled, **kw)[0]
     return _BASE[key]
 
 
@@ -104,12 +103,12 @@ def test_tp1_mesh_bit_identical():
 
 @pytest.mark.parametrize("arch", ["attn", "hybrid"])
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-@pytest.mark.parametrize("cache_kind", ["contiguous", "paged"])
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
 @pytest.mark.parametrize("tp", [2, 4])
-def test_tp_parity_matrix(tp, cache_kind, impl, arch):
+def test_tp_parity_matrix(tp, kv_dtype, impl, arch):
     _need(tp)
-    base = _baseline(arch, impl, cache_kind)
-    outs, eng = _run(arch, make_serving_mesh(tp=tp), impl, cache_kind)
+    base = _baseline(arch, impl, kv_dtype)
+    outs, eng = _run(arch, make_serving_mesh(tp=tp), impl, kv_dtype)
     assert outs == base
     # census identical to the unsharded engine: sharding must not fork
     # executables (retrace_guard's O(1)-compilations contract)
@@ -120,9 +119,8 @@ def test_tp_parity_matrix(tp, cache_kind, impl, arch):
 @pytest.mark.parametrize("tp", [2, 4])
 def test_tp_parity_seeded_sampling(tp):
     _need(tp)
-    base = _baseline("attn", cache_kind="paged", sampled=True)
-    outs, _ = _run("attn", make_serving_mesh(tp=tp), cache_kind="paged",
-                   sampled=True)
+    base = _baseline("attn", sampled=True)
+    outs, _ = _run("attn", make_serving_mesh(tp=tp), sampled=True)
     assert outs == base
 
 
@@ -132,8 +130,7 @@ def test_tp_prefix_cache_and_speculative(tp):
     with both on, a TP engine stays token-identical and the allocator
     invariants hold after the drain."""
     _need(tp)
-    kw = dict(cache_kind="paged", prefix_cache=True, speculative=True,
-              draft_k=3)
+    kw = dict(prefix_cache=True, speculative=True, draft_k=3)
     base = _baseline("attn", **kw)
     outs, eng = _run("attn", mesh=make_serving_mesh(tp=tp), **kw)
     assert outs == base
@@ -148,7 +145,7 @@ def test_tp_retrace_guard(tp):
     _need(tp)
     cfg, params = _cfg_params("attn")
     eng = ServingEngine(params, cfg, FamousConfig(impl="xla"), n_slots=2,
-                        max_seq=32, chunk=8, cache_kind="paged", page_size=8,
+                        max_seq=32, chunk=8, page_size=8,
                         mesh=make_serving_mesh(tp=tp))
     eng.run(_reqs(cfg))
     rng = np.random.default_rng(7)
@@ -160,14 +157,15 @@ def test_tp_retrace_guard(tp):
 
 
 @pytest.mark.parametrize("tp", [2, 4])
-@pytest.mark.parametrize("cache_kind", ["contiguous", "paged"])
-def test_cache_bytes_per_device_shrink(tp, cache_kind):
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+def test_cache_bytes_per_device_shrink(tp, kv_dtype):
     """The KV bytes resident per device must be exactly 1/TP of the
-    unsharded engine's (the attn config's caches are all kv-head-sharded
-    leaves, so the ratio is exact, not approximate)."""
+    unsharded engine's (the attn config's caches, int8 scale pools
+    included, are all kv-head-sharded leaves, so the ratio is exact, not
+    approximate)."""
     _need(tp)
-    _, base_eng = _run("attn", cache_kind=cache_kind)
-    _, eng = _run("attn", make_serving_mesh(tp=tp), cache_kind=cache_kind)
+    _, base_eng = _run("attn", kv_dtype=kv_dtype)
+    _, eng = _run("attn", make_serving_mesh(tp=tp), kv_dtype=kv_dtype)
     assert eng.cache_bytes_per_device() * tp == base_eng.cache_bytes_per_device()
 
 
@@ -179,8 +177,8 @@ def test_pallas_head_axis_follows_rules(kv_rule, want):
     from repro.parallel.sharding import SERVE_TP_RULES
     cfg, params = _cfg_params("attn")
     rules = {**SERVE_TP_RULES, "kv_heads": kv_rule}
-    kw = dict(n_slots=2, max_seq=32, chunk=8, cache_kind="paged",
-              page_size=8, mesh=make_serving_mesh(tp=1),
+    kw = dict(n_slots=2, max_seq=32, chunk=8, page_size=8,
+              mesh=make_serving_mesh(tp=1),
               sharding_rules=rules)
     if want is None:
         with pytest.raises(ValueError, match="one mesh axis"):
@@ -190,18 +188,18 @@ def test_pallas_head_axis_follows_rules(kv_rule, want):
         assert eng.fcfg.head_axis == want
 
 
-@pytest.mark.parametrize("cache_kind", ["contiguous", "paged"])
-def test_pallas_heads_on_rule_axis(cache_kind):
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+def test_pallas_heads_on_rule_axis(kv_dtype):
     """Rules that put the heads on the "data" axis move the Pallas
     launches' split there too: still token-identical to one device."""
     _need(2)
     from repro.parallel.sharding import SERVE_TP_RULES
     rules = {**SERVE_TP_RULES, "batch": None, "heads": "data",
              "kv_heads": "data", "mlp": "data"}
-    base = _baseline("attn", "pallas", cache_kind)
+    base = _baseline("attn", "pallas", kv_dtype)
     outs, eng = _run("attn", make_serving_mesh(tp=1, dp=2), "pallas",
-                     cache_kind, sharding_rules=rules)
+                     kv_dtype, sharding_rules=rules)
     assert eng.fcfg.head_axis == "data"
     assert outs == base
     assert eng.cache_bytes_per_device() * 2 == \
-        _run("attn", cache_kind=cache_kind)[1].cache_bytes_per_device()
+        _run("attn", kv_dtype=kv_dtype)[1].cache_bytes_per_device()

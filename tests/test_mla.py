@@ -11,11 +11,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs.base import get_config, shrink
+from repro.configs.base import MLA, get_config, shrink
 from repro.core.famous import FamousConfig
 from repro.kernels.decode import decode_attn
 from repro.kernels.decode import ref as dec_ref
-from repro.models import mla, module, moe, transformer
+from repro.models import layers, mla, module, moe, transformer
 from repro.serve.engine import Request, ServingEngine
 
 FCFG = FamousConfig(impl="xla")
@@ -46,21 +46,20 @@ def test_yarn_constants_as_published():
                                   rel=1e-6)
 
 
-def test_absorbed_and_plain_forms_agree():
-    """Prefill then decode through the latent cache (absorbed form) give
-    the full-sequence plain form's logits."""
+def test_absorbed_and_plain_forms_agree(paged_prefill):
+    """Chunked prefill then decode through the paged latent pool (absorbed
+    form) give the full-sequence plain form's logits."""
     cfg = _cfg()
     params = _params(cfg)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 20), 0,
                               cfg.vocab_size)
     full = transformer.forward(params, toks, cfg, remat=False)
-    caches = transformer.make_caches(cfg, 2, 32, jnp.float32)
-    lg, caches = transformer.prefill(params, toks[:, :12], caches, cfg)
-    got = [lg]
-    clen = jnp.full((2,), 12, jnp.int32)
-    for t in range(12, 20):
+    caches, pt = paged_prefill(params, cfg, toks[:, :11], max_seq=32)
+    got = []
+    clen = jnp.full((2,), 11, jnp.int32)
+    for t in range(11, 20):
         lg, caches = transformer.decode_step(params, toks[:, t], caches,
-                                             clen, cfg)
+                                             clen, cfg, page_table=pt)
         clen = clen + 1
         got.append(lg)
     want = full[:, 11:20]
@@ -209,12 +208,9 @@ def test_grouped_matmul_kernel_matches_ragged_dot():
 
 
 @pytest.mark.parametrize("kw,what", [
-    ({"cache_kind": "paged", "kv_dtype": "int8"}, "kv_dtype='int8'"),
-    ({"cache_kind": "paged", "speculative": True}, "speculative decoding"),
-    ({"cache_kind": "paged", "prefill_mode": "monolithic"},
-     "monolithic prefill"),
-    ({}, "a contiguous cache"),
-], ids=["int8_kv", "speculation", "monolithic", "contiguous"])
+    ({"kv_dtype": "int8"}, "kv_dtype='int8'"),
+    ({"speculative": True}, "speculative decoding"),
+], ids=["int8_kv", "speculation"])
 def test_latent_attention_refuses_what_it_does_not_support(kw, what):
     cfg = _cfg()
     with pytest.raises(ValueError, match=what):
@@ -223,29 +219,28 @@ def test_latent_attention_refuses_what_it_does_not_support(kw, what):
 
 def test_latent_pool_refuses_int8():
     with pytest.raises(ValueError, match="int8"):
-        transformer.make_caches(_cfg(), 2, 64, jnp.float32,
-                                cache_kind="paged", page_size=16, n_pages=9,
-                                kv_dtype="int8")
+        transformer.make_caches(_cfg(), 2, 64, jnp.float32, page_size=16,
+                                n_pages=9, kv_dtype="int8")
 
 
 @pytest.mark.parametrize("step", ["decode", "chunk"])
-def test_latent_step_writes_only_its_rows_into_each_layer(step):
+def test_latent_step_writes_only_its_rows_into_each_layer(step,
+                                                          identity_layout):
     """One decode step, or one prefill chunk, through the latent pools of
     all three layers (the dense head's own pool and the two-layer stack
     the scan carries): every (layer, page, offset) it does not write is
-    bit-identical after it, and the rows it writes are the latent rows the
-    same tokens get layer by layer through contiguous caches (decode: the
-    same history, gathered from the pools; the chunk, at offset 0: the
-    whole-prompt prefill), so a row written into the wrong layer, or a
-    layer that reads another's pool, fails."""
+    bit-identical after it, and the rows it writes are the right latent
+    rows — decode: those the same step writes with the same history laid
+    out under another page table; the chunk, at offset 0: the rows of the
+    plain form's layer inputs, layer by layer — so a row written into the
+    wrong page or layer, or a layer that reads another's pool, fails."""
     cfg = _cfg()
     params = _params(cfg, seed=4)
     B, ps, n_p = 2, 4, 4
     S, n_pages = n_p * ps, 1 + B * n_p
     pt = jnp.asarray(np.random.default_rng(0).permutation(
         np.arange(1, n_pages)).reshape(B, n_p), jnp.int32)
-    paged = transformer.make_caches(cfg, B, S, jnp.float32,
-                                    cache_kind="paged", page_size=ps,
+    paged = transformer.make_caches(cfg, B, S, jnp.float32, page_size=ps,
                                     n_pages=n_pages)
     leaves, treedef = jax.tree_util.tree_flatten(paged)
     paged = treedef.unflatten([
@@ -258,39 +253,45 @@ def test_latent_step_writes_only_its_rows_into_each_layer(step):
         toks = jnp.asarray([3, 7], jnp.int32)
         clen = jnp.asarray([5, 10], jnp.int32)
         act = jnp.ones((B,), bool)
-        gather = lambda pool: pool[0][pt].reshape(B, S, -1)  # noqa: E731
-        contig = {"head0": {"c": gather(paged["head0"]["c"])},
-                  "blocks": {"pos0": {"c": jax.vmap(gather)(
-                      paged["blocks"]["pos0"]["c"])}}}
+        ident = 1 + jnp.arange(B * n_p, dtype=jnp.int32).reshape(B, n_p)
+        moved = {"head0": {"c": identity_layout(
+                     paged["head0"]["c"][None], pt)[0]},
+                 "blocks": {"pos0": {"c": identity_layout(
+                     paged["blocks"]["pos0"]["c"], pt)}}}
         _, new = transformer.decode_step(params, toks, paged, clen, cfg,
                                          FCFG, page_table=pt, active=act)
-        _, ref = transformer.decode_step(params, toks, contig, clen, cfg,
-                                         FCFG, active=act)
-        rows = [(b, int(n), b, int(n)) for b, n in enumerate(clen)]
-        ref_rows = jnp.concatenate([ref["head0"]["c"][None],
-                                    ref["blocks"]["pos0"]["c"]])
+        _, ref = transformer.decode_step(params, toks, moved, clen, cfg,
+                                         FCFG, page_table=ident, active=act)
+        rows = [(b, int(n)) for b, n in enumerate(clen)]
+        ref_rows = {(b, p): stack(ref)[:, int(ident[b, p // ps]), p % ps]
+                    for b, p in rows}
     else:
         slot, C = 1, 8
         toks = jnp.asarray([[5, 9, 2, 11, 4, 8, 1, 6]], jnp.int32)
         new = transformer.prefill_chunk(
             params, toks, paged, jnp.int32(slot), jnp.int32(0), jnp.int32(C),
             cfg, FCFG, page_table=pt)
-        _, ref = transformer.prefill(
-            params, toks, transformer.make_caches(cfg, 1, C, jnp.float32),
-            cfg, FCFG)
-        rows = [(slot, p, 0, p) for p in range(C)]
-        ref_rows = jnp.concatenate([ref["head0"]["c"][None],
-                                    ref["blocks"]["pos0"]["c"]])
+        # the plain form, layer by layer: each layer's rows of its input
+        x = layers.embed_lookup(params["embed"], toks, jnp.float32)
+        pos = jnp.arange(C)
+        want = []
+        for p in [params["head0"]] + [
+                jax.tree_util.tree_map(lambda a: a[u], params["blocks"]["pos0"])
+                for u in range(cfg.num_units)]:
+            h = layers.apply_norm(p["ln1"], x, cfg.norm)
+            want.append(mla.latent_rows(p["attn"], h, pos, cfg)[0])
+            x = transformer.apply_block(MLA, p, x, cfg, FCFG)
+        rows = [(slot, p) for p in range(C)]
+        ref_rows = {(slot, p): jnp.stack(want)[:, p] for p in range(C)}
     old, new = stack(paged), stack(new)              # (3, n_pages, ps, row)
     written = np.zeros(old.shape[:3], bool)
-    for b, p, _, _ in rows:
+    for b, p in rows:
         written[:, int(pt[b, p // ps]), p % ps] = True
     np.testing.assert_array_equal(np.asarray(new)[~written],
                                   np.asarray(old)[~written])
-    for b, p, rb, rp in rows:
+    for b, p in rows:
         np.testing.assert_allclose(new[:, int(pt[b, p // ps]), p % ps],
-                                   ref_rows[:, rb, rp], rtol=1e-5,
-                                   atol=1e-5)
+                                   ref_rows[b, p], rtol=1e-5, atol=1e-5)
 
 
 def test_engine_serves_through_the_paged_latent_pool():
@@ -305,7 +306,7 @@ def test_engine_serves_through_the_paged_latent_pool():
     prompts = [head + list(rng.integers(0, cfg.vocab_size, n))
                for n in (3, 11)] + [list(rng.integers(0, cfg.vocab_size, 9))]
     eng = ServingEngine(params, cfg, FCFG, n_slots=2, max_seq=64, chunk=16,
-                        cache_kind="paged", page_size=8, prefix_cache=True)
+                        page_size=8, prefix_cache=True)
     reqs = [Request(rid=i, tokens=p, max_new=5)
             for i, p in enumerate(prompts)]
     # the first request publishes the head's pages on retiring

@@ -258,8 +258,8 @@ def _paired_runs():
     cfg, _ = _cfg_params()
     spans = SpanLog()
     obs = Observer(span=spans)
-    off = _engine(observer=None, cache_kind="paged", page_size=8)
-    on = _engine(observer=obs, cache_kind="paged", page_size=8)
+    off = _engine(observer=None, page_size=8)
+    on = _engine(observer=obs, page_size=8)
     done_off = sorted(off.run(_requests(cfg)), key=lambda r: r.rid)
     done_on = sorted(on.run(_requests(cfg)), key=lambda r: r.rid)
     return done_off, done_on, obs, on, spans
@@ -343,7 +343,7 @@ def _profiled(observer, tmp_path):
     plane's ``engine.*`` events as (name, start, end, stats)."""
     from jax.profiler import ProfileData
     cfg, _ = _cfg_params()
-    eng = _engine(observer=observer, cache_kind="paged", page_size=8)
+    eng = _engine(observer=observer, page_size=8)
     eng.run(_requests(cfg, n=2, seed=3))           # compile outside it
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
@@ -429,7 +429,7 @@ def test_preempted_request_keeps_its_first_admission():
     obs = Observer()
     # 5 allocatable pages of 4: both 7-token prompts admit, decode growth
     # then forces the younger out
-    eng = _engine(observer=obs, cache_kind="paged", page_size=4, n_pages=6)
+    eng = _engine(observer=obs, page_size=4, n_pages=6)
     marks, sizes = {}, []
     add = eng.add_request
 
@@ -466,7 +466,7 @@ def test_decode_span_holds_the_launched_slots_under_preemption():
     cfg, _ = _cfg_params()
     log = SpanLog()
     obs = Observer(span=log)
-    eng = _engine(observer=obs, cache_kind="paged", page_size=4, n_pages=6)
+    eng = _engine(observer=obs, page_size=4, n_pages=6)
     rng = np.random.default_rng(5)
     eng.run([Request(rid=i, tokens=list(rng.integers(0, cfg.vocab_size, 7)),
                      max_new=8) for i in range(2)])
@@ -488,8 +488,7 @@ def test_launch_span_counts_the_paged_kernels_blocks():
     from repro.kernels.decode.decode_attn import pages_per_block
     cfg, params = _cfg_params()
     eng = ServingEngine(params, cfg, FamousConfig(impl="xla"), n_slots=3,
-                        max_seq=1024, chunk=CHUNK, cache_kind="paged",
-                        page_size=16)
+                        max_seq=1024, chunk=CHUNK, page_size=16)
     bt = eng._kv_block_tokens
     kv = cfg.num_kv_heads
     assert bt == 16 * pages_per_block(64, 16, kv, cfg.head_dim,
@@ -500,8 +499,6 @@ def test_launch_span_counts_the_paged_kernels_blocks():
     rows = [1023, 1024, 1024]                     # W = 3, clipped
     assert eng._span_costs([2], 3)["kv_blocks"] \
         == sum(-(-n // bt) for n in rows) * kv
-    contiguous = _engine()
-    assert set(contiguous._span_costs([0])) == {"ctx_tokens"}
 
 
 @pytest.mark.parametrize("metrics", [False, True],
@@ -513,7 +510,7 @@ def test_costly_span_args_wait_for_a_span_factory(metrics):
     cfg, _ = _cfg_params()
     for kw in ({}, {"speculative": True, "draft_k": 2}):
         eng = _engine(observer=Observer() if metrics else None,
-                      cache_kind="paged", page_size=8, **kw)
+                      page_size=8, **kw)
         assert eng.speculative_active == bool(kw)
 
         def refuse(*a):
@@ -539,7 +536,7 @@ def _lowered_modules():
 
         setattr(eng, attr, call)
 
-    plain = _engine(cache_kind="paged", page_size=8)
+    plain = _engine(page_size=8)
     spec = _engine(speculative=True, draft_k=2)
     for attr in ("_prefill_chunk_exec", "_decode", "_clear", "_sample"):
         spy(plain, attr)
@@ -585,7 +582,7 @@ def test_observer_census_and_retrace_guard_sources():
 def test_observer_prefix_cache_hit_counters():
     cfg, _ = _cfg_params()
     obs = Observer()
-    eng = _engine(observer=obs, cache_kind="paged", page_size=8,
+    eng = _engine(observer=obs, page_size=8,
                   prefix_cache=True)
     shared = 16   # two full pages of shared head
     eng.run(_requests(cfg, seed=11, shared_head=shared))
@@ -676,7 +673,7 @@ def test_serving_stack_has_one_clock_call_site():
     root = os.path.join(os.path.dirname(__file__), "..")
     offenders = []
     scan = ["src/repro/serve", "src/repro/obs", "src/repro/launch/serve.py",
-            "benchmarks/serving_bench.py", "examples/serve_lm.py"]
+            "examples/serve_lm.py"]
     for rel in scan:
         path = os.path.join(root, rel)
         files = ([os.path.join(path, f) for f in os.listdir(path)
@@ -712,7 +709,7 @@ def _latent_runs():
     for obs in (None, Observer(span=SpanLog())):
         eng = ServingEngine(params, cfg, FamousConfig(impl="xla"),
                             n_slots=2, max_seq=MAX_SEQ, chunk=CHUNK,
-                            cache_kind="paged", page_size=8, observer=obs)
+                            page_size=8, observer=obs)
         done = sorted(eng.run(_requests(cfg, n=4, seed=3)),
                       key=lambda r: r.rid)
         runs.append((eng, obs, done))
@@ -755,8 +752,7 @@ def test_latent_decode_span_counts_the_latent_kernels_blocks():
     # one latent row per token: ceil(length / block) blocks a row, no
     # kv-head factor
     eng = ServingEngine(on.params, cfg, FamousConfig(impl="xla"), n_slots=3,
-                        max_seq=1024, chunk=CHUNK, cache_kind="paged",
-                        page_size=16)
+                        max_seq=1024, chunk=CHUNK, page_size=16)
     bt = eng._kv_block_tokens
     assert bt == 16 * pages_per_block(64, 16, 1, mla.stored_width(cfg),
                                       jnp.float32) and bt < 1024

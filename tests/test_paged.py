@@ -1,6 +1,9 @@
 """Paged KV cache: allocator invariants, page-table kernel parity against
-the gather-based reference, and token-identical paged-vs-contiguous serving
-on mixed-length request batches (including slot reuse and pool exhaustion)."""
+the gather-based reference, and paged serving token-identical to the
+cache-free forward on mixed-length request batches (including slot reuse
+and pool exhaustion)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -191,21 +194,28 @@ def test_paged_verify_kernel_matches_reference():
     np.testing.assert_allclose(out, want, atol=1e-5)
 
 
-def test_chunk_prefill_kernel_matches_reference():
-    """Contiguous chunked-prefill kernel vs the dense oracle, across
-    offsets (first / middle / last chunk of a prompt)."""
-    B, C, H, KV, dh = 2, 8, 4, 2, 16
-    Skv = 64
-    ks = jax.random.split(jax.random.PRNGKey(3), 3)
-    q = jax.random.normal(ks[0], (B, C, H, dh), jnp.float32)
-    kc = jax.random.normal(ks[1], (B, Skv, KV, dh), jnp.float32)
-    vc = jax.random.normal(ks[2], (B, Skv, KV, dh), jnp.float32)
+@pytest.mark.parametrize("group", [1, 4])
+@pytest.mark.parametrize("off", [24, 13], ids=["page_boundary", "mid_page"])
+def test_paged_chunk_prefill_kernel_at_offsets(off, group):
+    """The paged chunked-prefill kernel vs the gather-based oracle for a
+    chunk that starts on a page boundary or mid-page (its rows then span
+    three pages of 8), with one or four query heads per kv head."""
+    B, C, KV, dh = 2, 8, 2, 16
+    ps, n_p = 8, 8
+    n_pages = 1 + B * n_p
+    ks = jax.random.split(jax.random.PRNGKey(5 + off), 3)
+    q = jax.random.normal(ks[0], (B, C, KV * group, dh), jnp.float32)
+    kp = _stack(jax.random.normal(ks[1], (KV, n_pages, ps, dh), jnp.float32))
+    vp = _stack(jax.random.normal(ks[2], (KV, n_pages, ps, dh), jnp.float32))
+    pt = jnp.asarray(np.random.default_rng(off).permutation(
+        np.arange(1, n_pages)).reshape(B, n_p), jnp.int32)
     from repro.kernels.decode import ops as dec_ops
-    for off in (0, 8, 24):
-        out = dec_ops.chunk_prefill_attention(q, kc, vc, jnp.int32(off),
-                                              block_k=16, interpret=True)
-        want = dec_ref.chunk_prefill_reference(q, kc, vc, jnp.int32(off))
-        np.testing.assert_allclose(out, want, atol=1e-5)
+    out = dec_ops.paged_chunk_prefill_attention(q, kp, vp, pt, jnp.int32(off),
+                                                LAYER, interpret=True)
+    want = dec_ref.paged_chunk_prefill_reference(q, kp, vp, pt,
+                                                 jnp.int32(off), LAYER)
+    assert bool(jnp.isfinite(out).all())
+    np.testing.assert_allclose(out, want, atol=1e-5)
 
 
 def test_paged_chunk_prefill_kernel_matches_reference():
@@ -288,33 +298,28 @@ def test_paged_int8_chunk_prefill_kernel_matches_oracle():
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_paged_matches_contiguous_decode(impl):
-    """Scattering a contiguous cache into pages and reading it back through
-    the page table reproduces dense decode attention exactly."""
-    B, KV, H, dh = 2, 2, 4, 16
-    ps, n_p = 8, 4
-    Smax = ps * n_p
-    ks = jax.random.split(jax.random.PRNGKey(1), 3)
-    q = jax.random.normal(ks[0], (B, 1, H, dh), jnp.float32)
-    kc = jax.random.normal(ks[1], (B, Smax, KV, dh), jnp.float32)
-    vc = jax.random.normal(ks[2], (B, Smax, KV, dh), jnp.float32)
-    lens = jnp.asarray([7, 29], jnp.int32)
-    # lay each sequence's pages out in a shuffled shared pool
-    rng = np.random.default_rng(1)
-    ids = rng.permutation(np.arange(1, 1 + B * n_p)).reshape(B, n_p)
-    n_pages = 1 + B * n_p
-    kp = jnp.zeros((KV, n_pages, ps, dh), jnp.float32)
-    vp = jnp.zeros((KV, n_pages, ps, dh), jnp.float32)
-    kp = kp.at[:, ids].set(kc.reshape(B, n_p, ps, KV, dh)
-                           .transpose(3, 0, 1, 2, 4))
-    vp = vp.at[:, ids].set(vc.reshape(B, n_p, ps, KV, dh)
-                           .transpose(3, 0, 1, 2, 4))
-    pt = jnp.asarray(ids, jnp.int32)
+def test_paged_matches_contiguous_decode(impl, paged_prefill):
+    """Prompts chunk-prefilled into pages scattered over a shuffled pool,
+    then decoded through the page table, give the full-sequence
+    (contiguous) forward's logits at every decoded position."""
+    cfg = shrink(get_config("qwen2-7b"))
+    params = _params(cfg)
+    B, P, ps, n_p = 2, 13, 8, 4
+    toks = jax.random.randint(jax.random.PRNGKey(2), (B, P + 4), 0,
+                              cfg.vocab_size)
+    full = transformer.forward(params, toks, cfg, remat=False)
+    ids = np.random.default_rng(1).permutation(np.arange(1, 1 + B * n_p))
     fcfg = FamousConfig(impl=impl)
-    paged = famous.paged_decode_attention(q, _stack(kp), _stack(vp), pt, lens,
-                                          LAYER, cfg=fcfg)
-    dense = famous.decode_attention(q, kc, vc, lens, cfg=fcfg)
-    np.testing.assert_allclose(paged, dense, atol=1e-5)
+    caches, pt = paged_prefill(params, cfg, toks[:, :P], max_seq=ps * n_p,
+                               page_size=ps, fcfg=fcfg,
+                               page_table=jnp.asarray(ids.reshape(B, n_p),
+                                                      jnp.int32))
+    clen = jnp.full((B,), P, jnp.int32)
+    for t in range(P, P + 4):           # the last opens a new page
+        lg, caches = transformer.decode_step(params, toks[:, t], caches,
+                                             clen, cfg, fcfg, page_table=pt)
+        np.testing.assert_allclose(lg, full[:, t], atol=5e-4)
+        clen = clen + 1
 
 
 # ---------------------------------------------------------------------------
@@ -338,39 +343,40 @@ def _filled(tree, key):
     return treedef.unflatten([fill(a, k) for a, k in zip(leaves, keys)])
 
 
-def _contiguous_history(pools, pt):
-    """The contiguous caches (layers, B, n_p * ps, KV, dh) holding each
-    slot's pages of each layer of ``pools`` in table order, dequantized."""
-    n = pools["k"].shape[0]
-    if "k_scale" in pools:
-        return {name: jnp.stack([dec_ref.gather_pages_int8(
-            pools[name], pools[name + "_scale"], pt, l) for l in range(n)])
+def _fp_pools(pools):
+    """The K/V pools of a cache tree as fp, dequantized when int8."""
+    if "k_scale" not in pools:
+        return {name: pools[name] for name in ("k", "v")}
+    return {name: pools[name].astype(jnp.float32)
+            * pools[name + "_scale"].swapaxes(-1, -2)
             for name in ("k", "v")}
-    return {name: jnp.stack([dec_ref.gather_pages(pools[name], pt, l)
-                             for l in range(n)]) for name in ("k", "v")}
 
 
 @pytest.mark.parametrize("step", ["decode", "chunk"])
 @pytest.mark.parametrize("kv", ["fp", "int8"])
-def test_paged_step_writes_only_its_rows_into_each_layer(kv, step):
+def test_paged_step_writes_only_its_rows_into_each_layer(kv, step,
+                                                         identity_layout):
     """One decode step, or one prefill chunk, on a 3-layer stack of pools
-    (Pallas kernels, interpreted): every (layer, kv head, page, offset) it
-    does not write is bit-identical after it, and the rows it writes are
-    the K/V the same step computes layer by layer through contiguous
-    per-slot caches holding the same history (int8: within a quantization
-    step), so a row written into the wrong layer, or a layer that reads
-    another's pool, fails."""
+    (Pallas kernels, interpreted, over a shuffled page table): every
+    (layer, kv head, page, offset) it does not write is bit-identical
+    after it, and the rows it writes are the K/V the same step computes
+    through XLA over fp pools holding the same history (dequantized) laid
+    out under another page table (int8: within a quantization step), so a
+    row written into the wrong page or layer, or a kernel that reads
+    another layer's pool, fails."""
     cfg = shrink(get_config("qwen2-7b"), num_layers=3)
     params = _params(cfg)
     B, ps, n_p = 2, 4, 4
     n_pages = 1 + B * n_p
     pt = jnp.asarray(np.random.default_rng(0).permutation(
         np.arange(1, n_pages)).reshape(B, n_p), jnp.int32)
+    ident = 1 + jnp.arange(B * n_p, dtype=jnp.int32).reshape(B, n_p)
     paged = _filled(transformer.make_caches(
-        cfg, B, n_p * ps, jnp.float32, cache_kind="paged", page_size=ps,
-        n_pages=n_pages, kv_dtype=kv), jax.random.PRNGKey(1))
-    contig = {"blocks": {"pos0": _contiguous_history(
-        paged["blocks"]["pos0"], pt)}}
+        cfg, B, n_p * ps, jnp.float32, page_size=ps, n_pages=n_pages,
+        kv_dtype=kv), jax.random.PRNGKey(1))
+    moved = {"blocks": {"pos0": {
+        name: identity_layout(pool, pt)
+        for name, pool in _fp_pools(paged["blocks"]["pos0"]).items()}}}
     pcfg = FamousConfig(impl="pallas")
     if step == "decode":
         toks = jnp.asarray([3, 7], jnp.int32)
@@ -378,8 +384,8 @@ def test_paged_step_writes_only_its_rows_into_each_layer(kv, step):
         act = jnp.ones((B,), bool)
         _, new = transformer.decode_step(params, toks, paged, clen, cfg, pcfg,
                                          page_table=pt, active=act)
-        _, ref = transformer.decode_step(params, toks, contig, clen, cfg,
-                                         FCFG, active=act)
+        _, ref = transformer.decode_step(params, toks, moved, clen, cfg,
+                                         FCFG, page_table=ident, active=act)
         rows = [(b, int(n)) for b, n in enumerate(clen)]
     else:
         slot, offset, C = 1, 4, 8
@@ -387,8 +393,8 @@ def test_paged_step_writes_only_its_rows_into_each_layer(kv, step):
         args = (jnp.int32(slot), jnp.int32(offset), jnp.int32(6))
         new = transformer.prefill_chunk(params, toks, paged, *args, cfg, pcfg,
                                         page_table=pt)
-        ref = transformer.prefill_chunk(params, toks, contig, *args, cfg,
-                                        FCFG)
+        ref = transformer.prefill_chunk(params, toks, moved, *args, cfg,
+                                        FCFG, page_table=ident)
         rows = [(slot, p) for p in range(offset, offset + C)]
     old, new, ref = (c["blocks"]["pos0"] for c in (paged, new, ref))
     written = np.zeros(old["k"].shape[:4], bool)       # (L, KV, n_pages, ps)
@@ -401,15 +407,16 @@ def test_paged_step_writes_only_its_rows_into_each_layer(kv, step):
     for b, p in rows:
         at = (slice(None), slice(None), int(pt[b, p // ps]), p % ps)
         for name in ("k", "v"):
-            got, want = new[name][at], ref[name][:, b, p]   # (L, KV, dh)
+            got = new[name][at]                            # (L, KV, dh)
+            want = ref[name][:, :, int(ident[b, p // ps]), p % ps]
             if kv == "fp":
                 np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
             else:
                 # in quantization steps: layer 0's input is the same on
                 # both paths, so its rows round within half a step; later
-                # layers' inputs drift, as the paged step attends over the
-                # rows it wrote quantized and the contiguous one over them
-                # exact, by less than another half
+                # layers' inputs drift, as the int8 step attends over the
+                # rows it wrote quantized and the fp one over them exact,
+                # by less than another half
                 scale = new[name + "_scale"][at[:3] + (0, p % ps)]
                 steps = jnp.abs(got * scale[..., None] - want) / scale[..., None]
                 assert float(steps[0].max()) <= 0.5 + 1e-4
@@ -431,7 +438,7 @@ def _engine_outputs(params, cfg, prompts, max_new, **engine_kw):
     return [r.out for r in done]
 
 
-def test_paged_engine_token_identical_mixed_lengths():
+def test_paged_engine_token_identical_mixed_lengths(greedy_reference):
     """6 mixed-length requests through 2 slots: slot reuse after retirement,
     decode-time page growth across page boundaries, length-1 admission."""
     cfg = shrink(get_config("qwen2-7b"))
@@ -439,9 +446,9 @@ def test_paged_engine_token_identical_mixed_lengths():
     rng = np.random.default_rng(0)
     prompts = [list(rng.integers(0, cfg.vocab_size, size=n))
                for n in (5, 9, 17, 3, 33, 1)]
-    base = _engine_outputs(params, cfg, prompts, 6, n_slots=2, max_seq=64)
+    base = [greedy_reference(params, cfg, p, 6) for p in prompts]
     paged = _engine_outputs(params, cfg, prompts, 6, n_slots=2, max_seq=64,
-                            cache_kind="paged", page_size=8)
+                            page_size=8)
     assert base == paged
 
 
@@ -451,10 +458,9 @@ def test_paged_engine_pallas_kernel_path():
     rng = np.random.default_rng(2)
     prompts = [list(rng.integers(0, cfg.vocab_size, size=n)) for n in (5, 12)]
     xla = _engine_outputs(params, cfg, prompts, 4, n_slots=2, max_seq=32,
-                          cache_kind="paged", page_size=8)
+                          page_size=8)
     pallas = _engine_outputs(params, cfg, prompts, 4, n_slots=2, max_seq=32,
-                             cache_kind="paged", page_size=8,
-                             fcfg=FamousConfig(impl="pallas"))
+                             page_size=8, fcfg=FamousConfig(impl="pallas"))
     assert xla == pallas
 
 
@@ -466,20 +472,82 @@ def test_paged_engine_int8_kernel_path_matches_xla():
     rng = np.random.default_rng(7)
     prompts = [list(rng.integers(0, cfg.vocab_size, size=n)) for n in (5, 12)]
     xla = _engine_outputs(params, cfg, prompts, 4, n_slots=2, max_seq=32,
-                          cache_kind="paged", page_size=8, kv_dtype="int8")
+                          page_size=8, kv_dtype="int8")
     pallas = _engine_outputs(params, cfg, prompts, 4, n_slots=2, max_seq=32,
-                             cache_kind="paged", page_size=8,
-                             kv_dtype="int8",
+                             page_size=8, kv_dtype="int8",
                              fcfg=FamousConfig(impl="pallas"))
     assert xla == pallas
 
 
-def test_kv_int8_requires_paged_cache():
+def _train_chain(params, cfg):
+    """``params`` taught a 32-token successor cycle by SGD, and the cycle
+    unrolled: a model whose top-1 logit margins exceed int8's drift
+    (random-init logits are near-ties that flip under any lossy cache)."""
+    rng = np.random.default_rng(5)
+    sub = np.sort(rng.choice(cfg.vocab_size, 32, replace=False))
+    cyc = rng.permutation(32)
+    succ = {int(sub[cyc[i]]): int(sub[cyc[(i + 1) % 32]]) for i in range(32)}
+    chain = [int(sub[cyc[0]])]
+    while len(chain) < 512 + 33:
+        chain.append(succ[chain[-1]])
+    chain = np.array(chain, np.int32)
+
+    @jax.jit
+    def sgd_step(p, batch):
+        def loss_fn(p):
+            logits = transformer.forward(p, batch[:, :-1], cfg, FCFG)
+            lp = jax.nn.log_softmax(logits, -1)
+            return -jnp.take_along_axis(lp, batch[:, 1:, None], -1).mean()
+
+        loss, g = jax.value_and_grad(loss_fn)(p)
+        return jax.tree_util.tree_map(lambda a, b: a - b, p, g), loss
+
+    for _ in range(120):
+        offs = rng.integers(0, 512, size=8)
+        params, loss = sgd_step(
+            params, jnp.asarray(np.stack([chain[o:o + 33] for o in offs])))
+    assert float(loss) < 0.2, f"chain model failed to train: loss {loss}"
+    return params, chain
+
+
+def test_int8_kv_keeps_a_trained_models_greedy_tokens(paged_prefill):
+    """On a trained model, int8 KV pages serve the fp pages' greedy tokens,
+    and the decode logits drift from fp's by at most 0.5, far below the
+    trained model's top-1 margins."""
     cfg = shrink(get_config("qwen2-7b"))
-    params = _params(cfg)
-    with pytest.raises(AssertionError):
-        ServingEngine(params, cfg, FCFG, n_slots=2, max_seq=32,
-                      kv_dtype="int8")          # contiguous cache
+    params, chain = _train_chain(_params(cfg), cfg)
+    rng = np.random.default_rng(11)
+    prompts = []
+    for _ in range(6):
+        n, off = int(rng.integers(4, 49)), int(rng.integers(0, 400))
+        prompts.append([int(t) for t in chain[off:off + n]])
+    outs = {kvd: _engine_outputs(params, cfg, prompts, 8, n_slots=4,
+                                 max_seq=64, chunk=16, kv_dtype=kvd)
+            for kvd in ("fp", "int8")}
+    assert outs["fp"] == outs["int8"]
+    # the drift, through the steps themselves: prefill all but the last
+    # prompt token, then decode 8 greedy tokens
+    step = jax.jit(functools.partial(transformer.decode_step, cfg=cfg,
+                                     fcfg=FCFG))
+    drift = 0.0
+    for prompt in prompts[:3]:
+        logits = {}
+        for kvd in ("fp", "int8"):
+            caches, pt = paged_prefill(params, cfg,
+                                       jnp.asarray([prompt[:-1]], jnp.int32),
+                                       max_seq=64, page_size=16, chunk=16,
+                                       kv_dtype=kvd)
+            tok, clen, rows = prompt[-1], len(prompt) - 1, []
+            for _ in range(8):
+                lg, caches = step(params, jnp.asarray([tok], jnp.int32),
+                                  caches, jnp.asarray([clen], jnp.int32),
+                                  page_table=pt)
+                rows.append(np.asarray(lg[0]))
+                tok, clen = int(np.argmax(rows[-1])), clen + 1
+            logits[kvd] = np.stack(rows)
+        assert (logits["fp"].argmax(-1) == logits["int8"].argmax(-1)).all()
+        drift = max(drift, float(np.abs(logits["fp"] - logits["int8"]).max()))
+    assert drift <= 0.5, drift
 
 
 def test_int8_preemption_keeps_scales_in_lockstep():
@@ -492,25 +560,49 @@ def test_int8_preemption_keeps_scales_in_lockstep():
     rng = np.random.default_rng(9)
     prompts = [list(rng.integers(0, cfg.vocab_size, size=7)) for _ in range(2)]
     base = _engine_outputs(params, cfg, prompts, 8, n_slots=2, max_seq=32,
-                           cache_kind="paged", page_size=4,
-                           kv_dtype="int8")
+                           page_size=4, kv_dtype="int8")
     paged = _engine_outputs(params, cfg, prompts, 8, n_slots=2, max_seq=32,
-                            cache_kind="paged", page_size=4, n_pages=6,
-                            kv_dtype="int8")
+                            page_size=4, n_pages=6, kv_dtype="int8")
     assert base == paged
 
 
-def test_paged_engine_hybrid_arch():
-    """Hybrid recurrent/local arch under cache_kind="paged": recurrent state
-    and ring buffers keep their per-slot buffers, outputs unchanged."""
+def test_paged_engine_hybrid_arch(greedy_reference):
+    """Hybrid recurrent/local arch: recurrent state and ring buffers keep
+    their per-slot buffers beside the global layers' pool, and serving
+    gives the forward's tokens."""
     cfg = shrink(get_config("recurrentgemma-2b"))
     params = _params(cfg)
     rng = np.random.default_rng(3)
     prompts = [list(rng.integers(0, cfg.vocab_size, size=n)) for n in (7, 3, 11)]
-    base = _engine_outputs(params, cfg, prompts, 4, n_slots=2, max_seq=64)
+    base = [greedy_reference(params, cfg, p, 4) for p in prompts]
     paged = _engine_outputs(params, cfg, prompts, 4, n_slots=2, max_seq=64,
-                            cache_kind="paged", page_size=16)
+                            page_size=16)
     assert base == paged
+
+
+def test_paged_engine_pure_recurrent_small_pool(greedy_reference):
+    """A pure-recurrent arch keeps nothing in the pool, yet the allocator
+    still admits by pages: a pool of 2 pages of 8 (4 fewer than a full
+    one) queues and preempts requests, and serving gives the forward's
+    tokens."""
+    cfg = shrink(get_config("rwkv6-1.6b"))
+    params = _params(cfg)
+    rng = np.random.default_rng(12)
+    prompts = [list(rng.integers(0, cfg.vocab_size, size=n))
+               for n in (6, 3, 9)]
+    base = [greedy_reference(params, cfg, p, 4) for p in prompts]
+    n_pages = 3
+    assert n_pages < PagedCacheConfig.default_pool(2, 24, 8)
+    engine = ServingEngine(params, cfg, FCFG, n_slots=2, max_seq=24,
+                           page_size=8, n_pages=n_pages, chunk=8)
+    reqs = [Request(rid=i, tokens=list(p), max_new=4)
+            for i, p in enumerate(prompts)]
+    done = sorted(engine.run(reqs), key=lambda r: r.rid)
+    assert [r.out for r in done] == base
+    assert all(r.error is None for r in done)
+    # the first request's growth into its second page preempted the second
+    assert engine.sched.fairness(1).get("preemptions") == 1
+    engine.alloc.assert_invariants()
 
 
 def test_engine_admission_control():
@@ -519,7 +611,7 @@ def test_engine_admission_control():
     # 3-page pool (n_pages=4 incl. null): a 20-token prompt needs 3 pages of
     # 8 -> admissible; a second request then cannot be admitted.
     engine = ServingEngine(params, cfg, FCFG, n_slots=2, max_seq=32,
-                           cache_kind="paged", page_size=8, n_pages=4)
+                           page_size=8, n_pages=4)
     engine.add_request(Request(rid=0, tokens=list(range(1, 21)), max_new=2))
     with pytest.raises(PagePoolExhausted):
         engine.add_request(Request(rid=1, tokens=list(range(1, 10)), max_new=2))
@@ -531,19 +623,19 @@ def test_engine_admission_control():
     assert engine.alloc.free_pages == 3  # retirement returned every page
 
 
-def test_engine_preemption_resumes_token_identically():
+def test_engine_preemption_resumes_token_identically(greedy_reference):
     """Two sequences whose decode-time growth collides on the last free
     page: the younger is preempted mid-generation, resumed after the elder
-    retires, and still produces exactly the contiguous-engine tokens."""
+    retires, and still produces exactly the forward's tokens."""
     cfg = shrink(get_config("qwen2-7b"))
     params = _params(cfg)
     rng = np.random.default_rng(5)
     prompts = [list(rng.integers(0, cfg.vocab_size, size=7)) for _ in range(2)]
-    base = _engine_outputs(params, cfg, prompts, 8, n_slots=2, max_seq=32)
+    base = [greedy_reference(params, cfg, p, 8) for p in prompts]
     # 5 allocatable pages of 4: both prompts admit (2 pages each), the first
     # boundary crossing takes the last page, the second forces a preemption
     paged = _engine_outputs(params, cfg, prompts, 8, n_slots=2, max_seq=32,
-                            cache_kind="paged", page_size=4, n_pages=6)
+                            page_size=4, n_pages=6)
     assert base == paged
 
 
@@ -558,7 +650,7 @@ def test_engine_impossible_request_fails_cleanly():
     huge = Request(rid=1, tokens=list(rng.integers(0, cfg.vocab_size, size=30)),
                    max_new=3)  # needs 4 pages, pool only has 3
     engine = ServingEngine(params, cfg, FCFG, n_slots=2, max_seq=32,
-                           cache_kind="paged", page_size=8, n_pages=4)
+                           page_size=8, n_pages=4)
     done = sorted(engine.run([ok, huge]), key=lambda r: r.rid)
     assert len(done) == 2
     assert done[0].error is None and len(done[0].out) == 3
@@ -566,7 +658,7 @@ def test_engine_impossible_request_fails_cleanly():
 
 
 def test_engine_oversubscribed_pool_drains_queue():
-    """A pool half the contiguous footprint still serves every request —
+    """A pool half the full footprint still serves every request —
     admission simply waits for pages to free (the scale story: memory
     follows live tokens, not n_slots x max_seq)."""
     cfg = shrink(get_config("qwen2-7b"))
@@ -574,7 +666,7 @@ def test_engine_oversubscribed_pool_drains_queue():
     rng = np.random.default_rng(4)
     prompts = [list(rng.integers(0, cfg.vocab_size, size=n))
                for n in (9, 5, 13, 7)]
-    # contiguous-equivalent would need 2 slots x 8 pages; give it 5 (+null)
+    # a full pool would hold 2 slots x 8 pages; give it 5 (+null)
     outs = _engine_outputs(params, cfg, prompts, 4, n_slots=2, max_seq=64,
-                           cache_kind="paged", page_size=8, n_pages=6)
+                           page_size=8, n_pages=6)
     assert all(len(o) == 4 for o in outs)

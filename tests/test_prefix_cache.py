@@ -15,7 +15,7 @@ from repro.configs.base import get_config, shrink
 from repro.core.famous import FamousConfig
 from repro.models import module, transformer
 from repro.serve.engine import Request, ServingEngine
-from repro.serve.paged import (NULL_PAGE, PageAllocator, PagedCacheConfig,
+from repro.serve.paged import (PageAllocator, PagedCacheConfig,
                                PagePoolExhausted, block_hashes)
 
 FCFG = FamousConfig(impl="xla")
@@ -133,7 +133,7 @@ def test_can_admit_discounts_lru_hits():
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
 def test_warm_hits_token_identical_greedy(impl):
     """Shared-prefix workload served cold, then warm through the same
-    engine: outputs token-identical to the uncached paged engine, pages
+    engine: outputs token-identical to the uncached engine, pages
     actually aliased, executables still O(1).  Prompt lengths straddle
     page boundaries (partial last block stays private: the COW rule)."""
     cfg = shrink(get_config("qwen2-7b"))
@@ -144,10 +144,10 @@ def test_warm_hits_token_identical_greedy(impl):
     prompts = [shared + list(rng.integers(0, cfg.vocab_size, size=k))
                for k in (1, 5, 13)]     # lens 20, 24, 32 over pages of 8
     cold_eng = ServingEngine(params, cfg, fcfg, n_slots=2, max_seq=64,
-                             cache_kind="paged", page_size=8)
+                             page_size=8)
     cold = _run(cold_eng, prompts)
     eng = ServingEngine(params, cfg, fcfg, n_slots=2, max_seq=64,
-                        cache_kind="paged", page_size=8, prefix_cache=True)
+                        page_size=8, prefix_cache=True)
     first = _run(eng, prompts)
     hits_first = eng.prefix_hit_pages
     with retrace_guard(eng, label="warm prefix-cache run"):
@@ -173,11 +173,11 @@ def test_warm_hits_token_identical_greedy_int8(impl):
     prompts = [shared + list(rng.integers(0, cfg.vocab_size, size=k))
                for k in (1, 5, 13)]
     cold_eng = ServingEngine(params, cfg, fcfg, n_slots=2, max_seq=64,
-                             cache_kind="paged", page_size=8,
+                             page_size=8,
                              kv_dtype="int8")
     cold = _run(cold_eng, prompts)
     eng = ServingEngine(params, cfg, fcfg, n_slots=2, max_seq=64,
-                        cache_kind="paged", page_size=8, prefix_cache=True,
+                        page_size=8, prefix_cache=True,
                         kv_dtype="int8")
     first = _run(eng, prompts)
     hits_first = eng.prefix_hit_pages
@@ -203,17 +203,17 @@ def test_warm_hits_token_identical_seeded_sampling():
                for k in (2, 9)]
     kw = dict(temperature=0.8, top_k=5, seed=42)
     cold_eng = ServingEngine(params, cfg, FCFG, n_slots=2, max_seq=64,
-                             cache_kind="paged", page_size=8)
+                             page_size=8)
     cold = _run(cold_eng, prompts, max_new=6, **kw)
     eng = ServingEngine(params, cfg, FCFG, n_slots=2, max_seq=64,
-                        cache_kind="paged", page_size=8, prefix_cache=True)
+                        page_size=8, prefix_cache=True)
     first = _run(eng, prompts, max_new=6, **kw)
     warm = _run(eng, prompts, rid0=10, max_new=6, **kw)
     assert cold == first == warm
     assert eng.prefix_hit_pages > 0
 
 
-def test_fully_cached_prompt_skips_prefill():
+def test_fully_cached_prompt_skips_prefill(greedy_reference):
     """A repeated prompt whose cacheable head covers everything but the
     last token admits straight to DECODE — and the page holding position
     n-1 is still private (decode writes it)."""
@@ -221,9 +221,9 @@ def test_fully_cached_prompt_skips_prefill():
     params = _params(cfg)
     rng = np.random.default_rng(2)
     prompt = [list(rng.integers(0, cfg.vocab_size, size=17))]  # target 16 = 2*8
-    base = _run(ServingEngine(params, cfg, FCFG, n_slots=2, max_seq=64), prompt)
+    base = [greedy_reference(params, cfg, p, 4) for p in prompt]
     eng = ServingEngine(params, cfg, FCFG, n_slots=2, max_seq=64,
-                        cache_kind="paged", page_size=8, prefix_cache=True)
+                        page_size=8, prefix_cache=True)
     a = _run(eng, prompt)
     b = _run(eng, prompt, rid0=1)
     assert base == a == b
@@ -238,22 +238,20 @@ def test_fully_cached_prompt_skips_prefill():
 # ---------------------------------------------------------------------------
 
 
-def test_refcounts_under_preempt_and_readmit():
+def test_refcounts_under_preempt_and_readmit(greedy_reference):
     """Decode-time growth on a tiny pool forces preemption of slots that
     hold aliased prefix pages; resume re-maps the (still-indexed) prefix
-    and the whole run stays token-identical to contiguous serving."""
+    and the whole run stays token-identical to the forward's tokens."""
     cfg = shrink(get_config("qwen2-7b"))
     params = _params(cfg)
     rng = np.random.default_rng(3)
     shared = list(rng.integers(0, cfg.vocab_size, size=4))
     prompts = [shared + list(rng.integers(0, cfg.vocab_size, size=3))
                for _ in range(2)]
-    base = _run(ServingEngine(params, cfg, FCFG, n_slots=2, max_seq=32),
-                prompts, max_new=8)
+    base = [greedy_reference(params, cfg, p, 8) for p in prompts]
     # 5 allocatable pages of 4: both admit (2 pages each), growth collides
     eng = ServingEngine(params, cfg, FCFG, n_slots=2, max_seq=32,
-                        cache_kind="paged", page_size=4, n_pages=6,
-                        prefix_cache=True)
+                        page_size=4, n_pages=6, prefix_cache=True)
     w1 = _run(eng, prompts, max_new=8)
     w2 = _run(eng, prompts, rid0=10, max_new=8)
     assert base == w1 == w2
@@ -262,56 +260,41 @@ def test_refcounts_under_preempt_and_readmit():
     assert eng.alloc.free_pages == 5
 
 
-def test_lru_reclaim_engine_oversubscribed():
+def test_lru_reclaim_engine_oversubscribed(greedy_reference):
     """More distinct prefixes than the pool can keep warm: old index
     entries are reclaimed on demand and every request still completes,
-    token-identically to the uncached engine."""
+    token-identically to the forward's tokens."""
     cfg = shrink(get_config("qwen2-7b"))
     params = _params(cfg)
     rng = np.random.default_rng(4)
     prompts = [list(rng.integers(0, cfg.vocab_size, size=n))
                for n in (9, 17, 12, 21, 8, 15)]
-    base = _run(ServingEngine(params, cfg, FCFG, n_slots=2, max_seq=64),
-                prompts)
+    base = [greedy_reference(params, cfg, p, 4) for p in prompts]
     # pool of 7 allocatable pages of 8 — fewer than the 8 block hashes the
     # six prompts publish plus live growth; the LRU must cycle
     eng = ServingEngine(params, cfg, FCFG, n_slots=2, max_seq=64,
-                        cache_kind="paged", page_size=8, n_pages=8,
-                        prefix_cache=True)
+                        page_size=8, n_pages=8, prefix_cache=True)
     w1 = _run(eng, prompts)
     w2 = _run(eng, prompts, rid0=10)
     assert base == w1 == w2
     eng.alloc.assert_invariants()
 
 
-def test_hybrid_arch_falls_back_to_cold_prefill():
+def test_hybrid_arch_falls_back_to_cold_prefill(greedy_reference):
     """Per-slot recurrent/ring state is not prefix-shareable: the engine
     explicitly disables sharing (prefix_cache_active False) and serves
-    every request cold — token-identical, zero hits."""
+    every request cold — the forward's tokens, zero hits."""
     cfg = shrink(get_config("recurrentgemma-2b"))
     params = _params(cfg)
     rng = np.random.default_rng(5)
     shared = list(rng.integers(0, cfg.vocab_size, size=16))
     prompts = [shared + list(rng.integers(0, cfg.vocab_size, size=k))
                for k in (3, 7)]
-    base = _run(ServingEngine(params, cfg, FCFG, n_slots=2, max_seq=64),
-                prompts)
+    base = [greedy_reference(params, cfg, p, 4) for p in prompts]
     eng = ServingEngine(params, cfg, FCFG, n_slots=2, max_seq=64,
-                        cache_kind="paged", page_size=16, prefix_cache=True)
+                        page_size=16, prefix_cache=True)
     assert not eng.prefix_cache_active and eng.prefix_shareable is False
     w1 = _run(eng, prompts)
     w2 = _run(eng, prompts, rid0=10)
     assert base == w1 == w2
     assert eng.prefix_hit_pages == 0 and eng.prefix_lookups == 0
-
-
-def test_prefix_cache_requires_paged_chunked():
-    cfg = shrink(get_config("qwen2-7b"))
-    params = _params(cfg)
-    with pytest.raises(AssertionError):
-        ServingEngine(params, cfg, FCFG, n_slots=2, max_seq=64,
-                      prefix_cache=True)                    # contiguous
-    with pytest.raises(AssertionError):
-        ServingEngine(params, cfg, FCFG, n_slots=2, max_seq=64,
-                      cache_kind="paged", page_size=8,
-                      prefill_mode="monolithic", prefix_cache=True)

@@ -5,7 +5,6 @@ and the O(1)-compilation guarantee the chunked runtime exists for."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.analysis import retrace_guard
 from repro.configs.base import get_config, shrink
@@ -32,6 +31,12 @@ def _req(rid, n):
     return Request(rid=rid, tokens=list(range(1, n + 1)))
 
 
+def _prefilled(s, slot):
+    """Prefill the slot's whole prompt in one chunk: it decodes next."""
+    info = s.slots[slot]
+    assert s.on_chunk(slot, info.target - info.done)
+
+
 def test_bind_and_chunk_lifecycle():
     s = Scheduler(2, SchedulerConfig(chunk=8))
     assert s.bind(0, _req(0, 20), 20) == PREFILL
@@ -49,7 +54,7 @@ def test_plan_budget_one_chunk_while_decoding():
     s = Scheduler(4, SchedulerConfig(chunk=8))
     for i in range(3):
         s.bind(i, _req(i, 2), 2)
-        s.mark_prefilled(i)
+        _prefilled(s, i)
     s.bind(3, _req(3, 65), 65)        # long prompt: 64 tokens to prefill
     plan = s.plan()
     assert plan.decode_slots == [0, 1, 2]
@@ -66,7 +71,7 @@ def test_plan_decode_width_charges_verify_cost():
     s = Scheduler(4, SchedulerConfig(chunk=8, decode_width=4))
     for i in range(3):
         s.bind(i, _req(i, 2), 2)
-        s.mark_prefilled(i)
+        _prefilled(s, i)
     s.bind(3, _req(3, 65), 65)
     plan = s.plan()                   # default budget 4*4 + 8 = 24
     assert plan.decode_slots == [0, 1, 2]
@@ -78,13 +83,13 @@ def test_plan_decode_width_charges_verify_cost():
                                       decode_width=4))
     for i in range(3):
         s2.bind(i, _req(i, 2), 2)
-        s2.mark_prefilled(i)
+        _prefilled(s2, i)
     s2.bind(3, _req(3, 65), 65)
     assert len(s2.plan().chunks) == 1
     s3 = Scheduler(4, SchedulerConfig(chunk=8, token_budget=24))
     for i in range(3):
         s3.bind(i, _req(i, 2), 2)
-        s3.mark_prefilled(i)
+        _prefilled(s3, i)
     s3.bind(3, _req(3, 65), 65)
     assert len(s3.plan().chunks) == 2
 
@@ -92,7 +97,7 @@ def test_plan_decode_width_charges_verify_cost():
 def test_on_draft_accounting_reaches_fairness():
     s = Scheduler(1, SchedulerConfig(chunk=8))
     s.bind(0, _req(0, 2), 2)
-    s.mark_prefilled(0)
+    _prefilled(s, 0)
     s.on_draft(0, drafted=4, accepted=2)
     s.on_draft(0, drafted=3, accepted=0)
     st = s.fairness(0)
@@ -114,7 +119,7 @@ def test_plan_grants_minimum_one_chunk():
     s = Scheduler(4, SchedulerConfig(chunk=8, token_budget=2))
     for i in range(3):
         s.bind(i, _req(i, 2), 2)
-        s.mark_prefilled(i)
+        _prefilled(s, i)
     s.bind(3, _req(3, 30), 30)
     plan = s.plan()
     assert len(plan.chunks) == 1
@@ -138,9 +143,9 @@ def test_final_chunk_is_partial():
 def test_preempt_victim_youngest_including_prefilling():
     s = Scheduler(3, SchedulerConfig(chunk=8))
     s.bind(0, _req(0, 5), 5)
-    s.mark_prefilled(0)
+    _prefilled(s, 0)
     s.bind(1, _req(1, 5), 5)
-    s.mark_prefilled(1)
+    _prefilled(s, 1)
     s.bind(2, _req(2, 30), 30)        # youngest, still prefilling
     assert s.preempt_victim() == 2
     assert s.preempt_victim(exclude=(2,)) == 1

@@ -1,8 +1,9 @@
-"""Serving-engine integration: continuous batching produces exactly the
-tokens a sequential prefill+decode loop would, chunked prefill is
-token-identical to the monolithic baseline (contiguous + paged, xla +
-pallas, attention/hybrid/recurrent archs), long prompts prefill across
-many chunks, and per-request sampling is reproducible."""
+"""Serving-engine integration: continuous batching with chunked prefill
+into the paged pool produces exactly the greedy tokens of the cache-free
+full-sequence forward (xla + pallas, attention/hybrid/recurrent archs,
+prompts of partial, exact and multi-chunk lengths), one prefill executable
+serves every prompt-length mix, long prompts prefill across many chunks,
+and per-request sampling is reproducible."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,25 +22,6 @@ def _params(cfg):
                               jax.random.PRNGKey(0), jnp.float32)
 
 
-def _greedy_reference(params, cfg, tokens, max_new):
-    """Sequential single-request generation via raw decode steps."""
-    caches = transformer.make_caches(cfg, 1, 128, jnp.float32)
-    toks = list(tokens)
-    if len(toks) > 1:
-        _, caches = transformer.prefill(
-            params, jnp.asarray([toks[:-1]], jnp.int32), caches, cfg, FCFG)
-    clen = jnp.asarray([len(toks) - 1], jnp.int32)
-    out = []
-    cur = toks[-1]
-    for _ in range(max_new):
-        logits, caches = transformer.decode_step(
-            params, jnp.asarray([cur], jnp.int32), caches, clen, cfg, FCFG)
-        cur = int(jnp.argmax(logits[0]))
-        out.append(cur)
-        clen = clen + 1
-    return out
-
-
 def _serve(params, cfg, prompts, max_new, **kw):
     engine = ServingEngine(params, cfg, kw.pop("fcfg", FCFG), **kw)
     reqs = [Request(rid=i, tokens=list(p), max_new=max_new)
@@ -52,13 +34,13 @@ def _serve(params, cfg, prompts, max_new, **kw):
 
 @pytest.mark.parametrize("arch", ["qwen2-7b", "recurrentgemma-2b",
                                   "rwkv6-1.6b"])
-def test_engine_matches_sequential_reference(arch):
+def test_engine_matches_sequential_reference(arch, greedy_reference):
     cfg = shrink(get_config(arch))
     params = _params(cfg)
     rng = np.random.default_rng(0)
     prompts = [list(rng.integers(0, cfg.vocab_size, size=n))
                for n in (5, 9, 17, 3)]
-    refs = [_greedy_reference(params, cfg, p, 6) for p in prompts]
+    refs = [greedy_reference(params, cfg, p, 6) for p in prompts]
     engine = ServingEngine(params, cfg, FCFG, n_slots=2, max_seq=128)
     reqs = [Request(rid=i, tokens=p, max_new=6) for i, p in enumerate(prompts)]
     done = sorted(engine.run(reqs), key=lambda r: r.rid)
@@ -67,43 +49,40 @@ def test_engine_matches_sequential_reference(arch):
 
 
 # ---------------------------------------------------------------------------
-# chunked vs monolithic prefill parity
+# chunked prefill against the forward oracle
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("arch", ["qwen2-7b", "recurrentgemma-2b",
                                   "rwkv6-1.6b"])
-def test_chunked_matches_monolithic(arch):
-    """Token-identical output whether the prompt is prefilled in one
-    monolithic call or in fixed-shape chunks between decode steps —
-    global-attention, hybrid recurrent/local-attention and pure-recurrent
-    stacks, with prompts spanning partial, exact and multi-chunk lengths."""
+def test_chunked_matches_monolithic(arch, greedy_reference):
+    """The forward's greedy tokens whether a prompt fits one chunk or
+    spans several, between other requests' decode steps — global-attention,
+    hybrid recurrent/local-attention and pure-recurrent stacks, with
+    prompts spanning partial, exact and multi-chunk lengths."""
     cfg = shrink(get_config(arch))
     params = _params(cfg)
     rng = np.random.default_rng(1)
     prompts = [list(rng.integers(0, cfg.vocab_size, size=n))
                for n in (3, 9, 17, 33, 1)]
-    mono, _ = _serve(params, cfg, prompts, 5, n_slots=2, max_seq=64,
-                     prefill_mode="monolithic")
+    refs = [greedy_reference(params, cfg, p, 5) for p in prompts]
     chunked, engine = _serve(params, cfg, prompts, 5, n_slots=2, max_seq=64,
-                             prefill_mode="chunked", chunk=8)
-    assert mono == chunked, arch
+                             chunk=8)
+    assert chunked == refs, arch
     assert engine.prefill_compilations == 1
 
 
-def test_chunked_matches_monolithic_paged():
+def test_chunked_matches_monolithic_paged(greedy_reference):
+    """Chunks of 16 over pages of 8: a chunk fills two pages at once."""
     cfg = shrink(get_config("qwen2-7b"))
     params = _params(cfg)
     rng = np.random.default_rng(2)
     prompts = [list(rng.integers(0, cfg.vocab_size, size=n))
                for n in (5, 21, 12)]
-    mono, _ = _serve(params, cfg, prompts, 4, n_slots=2, max_seq=64,
-                     prefill_mode="monolithic", cache_kind="paged",
-                     page_size=8)
+    refs = [greedy_reference(params, cfg, p, 4) for p in prompts]
     chunked, _ = _serve(params, cfg, prompts, 4, n_slots=2, max_seq=64,
-                        prefill_mode="chunked", chunk=16, cache_kind="paged",
-                        page_size=8)
-    assert mono == chunked
+                        chunk=16, page_size=8)
+    assert chunked == refs
 
 
 def test_chunked_pallas_matches_xla():
@@ -117,46 +96,50 @@ def test_chunked_pallas_matches_xla():
     assert xla == pallas
 
 
-def test_long_prompt_spans_many_chunks():
-    """A prompt far beyond any single prefill call (> the old engine's
-    largest sub-max_seq pow-2 bucket) prefills as a sequence of fixed
-    chunks and still matches the monolithic oracle token for token."""
+def test_long_prompt_spans_many_chunks(greedy_reference):
+    """A prompt far beyond any single prefill call prefills as a sequence
+    of fixed chunks and still gives the forward's tokens, token for
+    token."""
     cfg = shrink(get_config("qwen2-7b"))
     params = _params(cfg)
     rng = np.random.default_rng(4)
     prompts = [list(rng.integers(0, cfg.vocab_size, size=100))]
-    mono, _ = _serve(params, cfg, prompts, 4, n_slots=2, max_seq=128,
-                     prefill_mode="monolithic")
+    refs = [greedy_reference(params, cfg, p, 4) for p in prompts]
     chunked, engine = _serve(params, cfg, prompts, 4, n_slots=2, max_seq=128,
-                             prefill_mode="chunked", chunk=16)
-    assert mono == chunked
+                             chunk=16)
+    assert chunked == refs
     assert engine.prefill_compilations == 1  # 7 chunk calls, one executable
 
 
-# ---------------------------------------------------------------------------
-# legacy monolithic path (kept as the comparison baseline)
-# ---------------------------------------------------------------------------
-
-
-def test_monolithic_bucketing_reuses_executables():
-    cfg = shrink(get_config("qwen2-7b"))
-    engine = ServingEngine(_params(cfg), cfg, FCFG, n_slots=4, max_seq=64,
-                           prefill_mode="monolithic")
-    assert engine.bucketed
-    rng = np.random.default_rng(1)
-    lens = [3, 5, 7, 9, 12, 15, 17, 30]  # -> buckets {2,4,8,16,32}
+@pytest.mark.parametrize("arch", ["qwen2-7b", "recurrentgemma-2b",
+                                  "rwkv6-1.6b"])
+def test_one_prefill_executable_for_any_prompt_mix(arch):
+    """20 prompts of 20 distinct lengths — below, at and across chunk and
+    page boundaries — compile exactly one prefill executable, on the
+    attention, hybrid and pure-recurrent stacks."""
+    cfg = shrink(get_config(arch))
+    rng = np.random.default_rng(10)
+    lens = [1, 2, 7, 8, 9, 15, 16, 17, 23, 24, 25, 31, 32, 33, 40, 47, 48,
+            49, 55, 60]
     reqs = [Request(rid=i, tokens=list(rng.integers(0, cfg.vocab_size, n)),
                     max_new=2) for i, n in enumerate(lens)]
+    engine = ServingEngine(_params(cfg), cfg, FCFG, n_slots=4, max_seq=64,
+                           chunk=8)
     done = engine.run(reqs)
-    assert len(done) == len(lens)
-    assert engine.prefill_compilations <= 5  # pow-2 buckets, not per-length
+    assert len(done) == 20 and all(r.error is None for r in done)
+    assert engine.prefill_compilations == 1
 
 
-def test_monolithic_recurrent_uses_exact_length():
-    cfg = shrink(get_config("rwkv6-1.6b"))
-    engine = ServingEngine(_params(cfg), cfg, FCFG, n_slots=2, max_seq=64,
-                           prefill_mode="monolithic")
-    assert not engine.bucketed
+def test_engine_refuses_a_contiguous_cache():
+    """The paged pool is the only cache layout: no ``cache_kind`` builds
+    it, and any other value is refused."""
+    cfg = shrink(get_config("qwen2-7b"))
+    params = _params(cfg)
+    engine = ServingEngine(params, cfg, FCFG, n_slots=2, max_seq=32)
+    assert engine.alloc.pages_per_slot == 2       # page 16 by default
+    with pytest.raises(ValueError, match="cache_kind='contiguous'"):
+        ServingEngine(params, cfg, FCFG, n_slots=2, max_seq=32,
+                      cache_kind="contiguous")
 
 
 # ---------------------------------------------------------------------------
@@ -164,14 +147,14 @@ def test_monolithic_recurrent_uses_exact_length():
 # ---------------------------------------------------------------------------
 
 
-def test_greedy_default_unchanged():
+def test_greedy_default_unchanged(greedy_reference):
     """temperature=0 (the default) is plain argmax — identical to the
     sequential greedy reference."""
     cfg = shrink(get_config("qwen2-7b"))
     params = _params(cfg)
     rng = np.random.default_rng(5)
     prompt = list(rng.integers(0, cfg.vocab_size, size=9))
-    ref = _greedy_reference(params, cfg, prompt, 5)
+    ref = greedy_reference(params, cfg, prompt, 5)
     engine = ServingEngine(params, cfg, FCFG, n_slots=2, max_seq=128)
     done = engine.run([Request(rid=0, tokens=prompt, max_new=5)])
     assert done[0].out == ref

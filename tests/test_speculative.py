@@ -1,7 +1,7 @@
 """Speculative decoding: prompt-lookup drafting + batched verification.
 
 The gate is a randomized parity/property harness: ~50 seeded mixes of
-(architecture, cache kind, prefix cache, kernel impl, per-request
+(architecture, page size and pool, prefix cache, kernel impl, per-request
 sampling params, preemption-inducing tiny page pools) must produce token
 streams *identical* to ``speculative=False`` — acceptance/rollback may
 only change *when* tokens appear, never *which* tokens.  Around it:
@@ -94,12 +94,13 @@ def test_drafter_truncates_at_sequence_end():
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-@pytest.mark.parametrize("cache", ["contiguous", "paged", "paged_int8"])
+@pytest.mark.parametrize("cache", ["paged", "paged_int8", "paged_ps4"])
 def test_verify_step_matches_sequential_decode(impl, cache):
     """verify_step's row j must equal the logits of the j+1-th sequential
     decode_step over the same tokens (causality makes the parallel and
     sequential activations identical) — the foundation the engine's
-    accept rule stands on.  The int8 axis holds because BOTH paths write
+    accept rule stands on.  The verified draft crosses a page boundary,
+    at page size 8 and 4.  The int8 axis holds because BOTH paths write
     the same quantized values before attending: quantization is lossy vs
     fp, but deterministic, so verify-vs-sequential stays exact."""
     cfg, params = _cfg_params("qwen2-7b")
@@ -107,17 +108,13 @@ def test_verify_step_matches_sequential_decode(impl, cache):
     rng = np.random.default_rng(0)
     toks = rng.integers(0, cfg.vocab_size, size=9)
     W = 4
-    ps, n_p = 8, MAX_SEQ // 8
-    kw = {}
-    if cache.startswith("paged"):
-        caches = transformer.make_caches(
-            cfg, 1, MAX_SEQ, jnp.float32, cache_kind="paged", page_size=ps,
-            n_pages=n_p + 1,
-            kv_dtype="int8" if cache == "paged_int8" else "fp")
-        # pages 1..n_p back the single slot (page 0 is the null page)
-        kw["page_table"] = jnp.arange(1, n_p + 1, dtype=jnp.int32)[None]
-    else:
-        caches = transformer.make_caches(cfg, 1, MAX_SEQ, jnp.float32)
+    ps = 4 if cache == "paged_ps4" else 8
+    n_p = MAX_SEQ // ps
+    caches = transformer.make_caches(
+        cfg, 1, MAX_SEQ, jnp.float32, page_size=ps, n_pages=n_p + 1,
+        kv_dtype="int8" if cache == "paged_int8" else "fp")
+    # pages 1..n_p back the single slot (page 0 is the null page)
+    kw = {"page_table": jnp.arange(1, n_p + 1, dtype=jnp.int32)[None]}
     seq_caches = caches
     seq_logits = []
     for j, t in enumerate(toks):
@@ -129,6 +126,7 @@ def test_verify_step_matches_sequential_decode(impl, cache):
     # verify the last W tokens in one shot, on top of the first 9 - W
     ver_caches = caches
     L = len(toks) - W
+    assert L // ps != (L + W - 1) // ps      # the draft crosses a page
     for j, t in enumerate(toks[:L]):
         _, ver_caches = transformer.decode_step(
             params, jnp.asarray([t], jnp.int32), ver_caches,
@@ -144,11 +142,14 @@ def test_verify_step_matches_sequential_decode(impl, cache):
 
 def test_verify_step_rejects_non_attention_stacks():
     cfg, params = _cfg_params("recurrentgemma-2b")
-    caches = transformer.make_caches(cfg, 1, MAX_SEQ, jnp.float32)
+    caches = transformer.make_caches(cfg, 1, MAX_SEQ, jnp.float32,
+                                     page_size=8, n_pages=1 + MAX_SEQ // 8)
     with pytest.raises(ValueError, match="global-attention"):
         transformer.verify_step(params, jnp.zeros((1, 3), jnp.int32), caches,
                                 jnp.zeros((1,), jnp.int32), cfg,
-                                FamousConfig(impl="xla"))
+                                FamousConfig(impl="xla"),
+                                page_table=jnp.zeros((1, MAX_SEQ // 8),
+                                                     jnp.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -167,7 +168,7 @@ def _random_mix(mix_seed):
           "draft_k": int(rng.integers(1, 6))}
     if rng.random() < 0.5:
         ps = int(rng.choice([4, 8]))
-        kw.update(cache_kind="paged", page_size=ps)
+        kw.update(page_size=ps)
         if rng.random() < 0.4:
             # tiny pool: big enough to back any single request, small
             # enough that concurrent slots fight over pages (preemption)
@@ -216,8 +217,7 @@ def test_speculative_parity_random_mix(mix_seed):
     assert all(r.error is None and r.done for r in ref + spec), \
         [(r.rid, r.error) for r in ref + spec]
     assert [r.out for r in spec] == [r.out for r in ref], (arch, kw)
-    if kw.get("cache_kind") == "paged":
-        eng.alloc.assert_invariants()
+    eng.alloc.assert_invariants()
     if arch == "qwen2-7b":
         assert eng.speculative_active
         # verify REPLACED decode: the decode executable never compiled
@@ -280,11 +280,11 @@ def test_rejected_draft_at_page_boundary_frees_pages(kv_dtype):
     prompt = list(map(int, rng.integers(0, cfg.vocab_size, 6)))
     # the reference comes from a plain engine with the SAME cache dtype:
     # int8 greedy may lawfully diverge from fp greedy, rejection must not
-    ref = _ref_out(params, cfg, prompt, 12, cache_kind="paged", page_size=4,
+    ref = _ref_out(params, cfg, prompt, 12, page_size=4,
                    kv_dtype=kv_dtype)
     drafter = ScriptedDrafter(len(prompt), ref, cfg.vocab_size, delta=1)
     eng = ServingEngine(params, cfg, FamousConfig(impl="xla"), n_slots=2,
-                        max_seq=MAX_SEQ, chunk=CHUNK, cache_kind="paged",
+                        max_seq=MAX_SEQ, chunk=CHUNK,
                         page_size=4, speculative=True, draft_k=5,
                         drafter=drafter, kv_dtype=kv_dtype)
     req = Request(rid=0, tokens=list(prompt), max_new=12)
@@ -346,7 +346,7 @@ def test_preemption_mid_speculation_stays_token_identical():
         return [Request(rid=i, tokens=list(p), max_new=8)
                 for i, p in enumerate(prompts)]
 
-    kw = dict(cache_kind="paged", page_size=4,
+    kw = dict(page_size=4,
               n_pages=PagedCacheConfig(page_size=4, n_pages=2)
               .pages_for(MAX_SEQ) + 2)
     ref, _ = _serve(params, cfg, reqs(), **dict(kw))
@@ -398,7 +398,7 @@ def test_speculative_census_and_retrace():
                 for i in range(3)]
 
     eng = ServingEngine(params, cfg, FamousConfig(impl="xla"), n_slots=2,
-                        max_seq=MAX_SEQ, chunk=CHUNK, cache_kind="paged",
+                        max_seq=MAX_SEQ, chunk=CHUNK,
                         page_size=8, prefix_cache=True, speculative=True,
                         draft_k=3)
     eng.run(reqs(0))

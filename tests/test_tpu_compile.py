@@ -111,8 +111,7 @@ def _step_memory(sharding, model, layers, kv_dtype, step, n_pages):
                                         jnp.bfloat16))
     caches = placed(transformer.make_caches(
         cfg, STEP_SLOTS, STEP_MAX_SEQ, jnp.bfloat16, shapes_only=True,
-        cache_kind="paged", page_size=catalog.PAGE, n_pages=n_pages,
-        kv_dtype=kv_dtype))
+        page_size=catalog.PAGE, n_pages=n_pages, kv_dtype=kv_dtype))
     table = ints(STEP_SLOTS, STEP_MAX_SEQ // catalog.PAGE)
     if step == "decode":
         fn = functools.partial(transformer.decode_step, cfg=cfg, fcfg=fcfg)
